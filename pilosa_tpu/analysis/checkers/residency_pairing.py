@@ -7,7 +7,7 @@ in ``REPR_CLASSES`` without a kernel variant for every op the dense
 class supports is a latent plan-time KeyError — it only fires when a
 query shape first routes that op at that class, i.e. in production,
 not in the unit tests that exercised the class's happy path. The
-reference has the same pairing discipline in its container taxonomy
+reference has the same pairing discipline in its container classes
 (roaring.go: every container type implements every op in the
 binary-op matrix); this rule keeps the HBM port honest as classes are
 added.

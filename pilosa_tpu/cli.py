@@ -104,9 +104,11 @@ _DEFAULTS = {
     # port inject per-query latency, so it must never ship armed.
     "chaos_faults": False,
     # Persistent XLA compilation cache directory. "" resolves to
-    # <data-dir>/compile-cache (memory-only when no data dir); "off"
-    # disables. A restarted node reloads every kernel compiled by
-    # prior runs instead of paying the cold trace+compile.
+    # <checkout>/.jax_cache, one fixed path (the directory is part of
+    # the cache key, so it must not move with the data dir); "off"
+    # disables; JAX_COMPILATION_CACHE_DIR, where set, overrides any
+    # path. A restarted node reloads every kernel compiled by prior
+    # runs instead of paying the cold trace+compile.
     "compile_cache_dir": "",
     # Plan-shape bucketing policy: "pow2" rounds stack heights up to
     # power-of-two buckets (zero-padded, bit-identical results) so a
@@ -794,8 +796,8 @@ def cmd_generate_config(args) -> int:
           'hedge-budget-pct = 5.0\n'
           '# chaos fault injection route (tests only; never production)\n'
           '# chaos-faults = false\n'
-          '# persistent XLA compile cache ("" = <data-dir>/compile-cache,\n'
-          '# "off" disables)\n'
+          '# persistent XLA compile cache ("" = <checkout>/.jax_cache,\n'
+          '# "off" disables; JAX_COMPILATION_CACHE_DIR overrides a path)\n'
           'compile-cache-dir = ""\n'
           '# plan-shape bucketing: "pow2" reuses compiled kernels across\n'
           '# shard counts, "none" pads only to the device mesh\n'
@@ -919,7 +921,8 @@ def main(argv: list[str] | None = None) -> int:
                    help="OTLP/HTTP collector URL for trace export")
     s.add_argument("--compile-cache-dir", default=None,
                    help="persistent XLA compile cache directory "
-                        '("" = <data-dir>/compile-cache, "off" disables)')
+                        '("" = <checkout>/.jax_cache, "off" disables; '
+                        'JAX_COMPILATION_CACHE_DIR overrides a path)')
     s.add_argument("--plan-buckets", choices=("pow2", "none"), default=None,
                    help="plan-shape bucketing policy: pow2 rounds stack "
                         "heights to power-of-two buckets so new shard "

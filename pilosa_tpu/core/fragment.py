@@ -532,7 +532,10 @@ class Fragment:
         fragments resolve every part in one transfer wave instead of one
         sync per fragment (the r2 filtered-TopN latency). Pass
         ``seg_host`` when the filter already exists host-side so the
-        sparse tier never pulls it off the device."""
+        sparse tier never pulls it off the device. ``seg`` is a
+        single-device array (or host words): the dense tier is a
+        single-device program, and a caller whose stacks span a mesh
+        co-locates the segment first (MeshPlanner.execute_topn_counts)."""
         ids = [int(r) for r in row_ids]
         if not ids:
             return np.empty(0, dtype=np.int64), []

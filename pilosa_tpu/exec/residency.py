@@ -1,7 +1,7 @@
 """Container-classed device residency: packed vs dense leaf stacks.
 
 The reference resists memory pressure with its roaring container
-taxonomy (roaring.go: array/run/bitmap containers chosen per container
+classes (roaring.go: array/run/bitmap containers chosen per container
 by cardinality). This module ports that idea to HBM: a planner leaf
 stack has a *representation class* chosen by measured row cardinality —
 

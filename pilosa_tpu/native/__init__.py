@@ -1,14 +1,20 @@
 """ctypes bindings for the native C++ runtime (native/roaring_codec.cpp).
 
-The native library is built on first use (``make -C native``) and cached;
-every entry point falls back to the pure-numpy implementation
+The native library is built on first use (``make -C native``) and
+rebuilt whenever it is not a product of the committed sources on THIS
+host: the Makefile compiles with ``-march=native``, so a library copied
+over from another machine may not even load (``ensure_built``). Every
+entry point falls back to the pure-numpy implementation
 (pilosa_tpu.roaring / ops.bitops) when the toolchain or library is
-unavailable, so the package never hard-depends on the build.
+unavailable, so the package never hard-depends on the build;
+``available()`` (the ``runtime.nativeLoaded`` gauge) says which it is.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,10 +25,56 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libpilosa_native.so")
+_SOURCES = ("Makefile", "roaring_codec.cpp", "fuzz_roaring.cpp")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
+
+
+def _build_stamp() -> str:
+    """What a build depends on: the committed sources and the CPU that
+    ``-march=native`` compiled for."""
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    flags = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            flags = next((ln.strip() for ln in f if ln.startswith("flags")),
+                         "")
+    except OSError:
+        pass
+    return f"{h.hexdigest()}\n{flags}\n"
+
+
+def ensure_built(target: str) -> bool:
+    """Make ``native/<target>`` a build of the committed sources on this
+    host: rebuilt from scratch unless the stamp file beside it records
+    the same source hash and CPU flags. False when it cannot be built.
+    Safe across processes (xdist workers, server + client): one builds
+    under a file lock, the rest find its stamp."""
+    out = os.path.join(_NATIVE_DIR, target)
+    stamp_path = out + ".stamp"
+    try:
+        want = _build_stamp()
+        with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            try:
+                with open(stamp_path) as f:
+                    have = f.read()
+            except OSError:
+                have = ""
+            if have == want and os.path.exists(out):
+                return True
+            subprocess.run(["make", "-C", _NATIVE_DIR, "-s", "-B", target],
+                           check=True, capture_output=True, timeout=300)
+            with open(stamp_path, "w") as f:
+                f.write(want)
+            return True
+    except (OSError, subprocess.SubprocessError):
+        return False
 
 
 def _load() -> ctypes.CDLL | None:
@@ -33,12 +85,8 @@ def _load() -> ctypes.CDLL | None:
         _tried = True
         if os.environ.get("PILOSA_TPU_NO_NATIVE") == "1":
             return None
-        if not os.path.exists(_LIB_PATH):
-            try:
-                subprocess.run(["make", "-C", _NATIVE_DIR, "-s"],
-                               check=True, capture_output=True, timeout=120)
-            except Exception:
-                return None
+        if not ensure_built("libpilosa_native.so"):
+            return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
         except OSError:

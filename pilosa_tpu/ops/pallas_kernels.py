@@ -10,9 +10,12 @@ XLA usually fuses `popcount(a & b).sum()` on its own; these kernels pin the
 fusion and the tiling for the benchmark path and give us a place to fold in
 multi-op trees (e.g. popcount((a & b) &~ c)) that XLA sometimes splits.
 
-On non-TPU backends (the CPU test mesh) the same kernels run with
-``interpret=True``; callers can also force the pure-XLA path with
-PILOSA_TPU_NO_PALLAS=1.
+On the CPU backend (the test mesh) the same kernels run with
+``interpret=True``: the choice is a pure function of the platform, made
+once per call in ``pair_count``/``row_counts``, so on a TPU a kernel the
+chip's compiler refuses raises instead of being answered some other
+way. PILOSA_TPU_NO_PALLAS=1 is the one, explicit, way to the pure-XLA
+expression.
 """
 
 from __future__ import annotations
@@ -22,16 +25,9 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from pilosa_tpu.ops import bitops
-
-try:  # pallas is part of jax, but guard anyway for exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
 
 _DISABLED = os.environ.get("PILOSA_TPU_NO_PALLAS", "") == "1"
 
@@ -85,8 +81,8 @@ def _pad2d(x, tm, tw):
     return x
 
 
-@functools.partial(jax.jit, static_argnames=("op",))
-def _pallas_pair_count(a, b, op: str):
+@functools.partial(jax.jit, static_argnames=("op", "interpret"))
+def _pallas_pair_count(a, b, op: str, interpret: bool):
     """counts[...] = popcount(op(a, b)) per row; a, b broadcastable [..., W].
 
     Broadcast happens inside the jit so XLA elides the copy — a single
@@ -110,13 +106,13 @@ def _pallas_pair_count(a, b, op: str):
         ],
         out_specs=pl.BlockSpec((_TILE_M, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.int32),
-        interpret=_interpret(),
+        interpret=interpret,
     )(a, b)
     return out[:m0, 0]
 
 
-@jax.jit
-def _pallas_row_counts(a):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pallas_row_counts(a, interpret: bool):
     m0 = a.shape[0]
     a = _pad2d(a, _TILE_M, _TILE_W)
     m, w = a.shape
@@ -127,21 +123,19 @@ def _pallas_row_counts(a):
         in_specs=[pl.BlockSpec((_TILE_M, _TILE_W), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((_TILE_M, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.int32),
-        interpret=_interpret(),
+        interpret=interpret,
     )(a)
     return out[:m0, 0]
 
 
 def available() -> bool:
-    return _HAVE_PALLAS and not _DISABLED
+    return not _DISABLED
 
 
 def pair_count(a, b, op: str = "and"):
-    """Fused ``popcount(op(a, b))`` per row over [..., W] arrays.
-
-    Falls back to the XLA expression when pallas is unavailable.
-    """
-    if not available():
+    """Fused ``popcount(op(a, b))`` per row over [..., W] arrays; the
+    XLA expression only under PILOSA_TPU_NO_PALLAS=1."""
+    if _DISABLED:
         return {
             "and": bitops.intersection_count,
             "or": bitops.union_count,
@@ -149,14 +143,14 @@ def pair_count(a, b, op: str = "and"):
             "andnot": bitops.difference_count,
         }[op](a, b)
     shape = jnp.broadcast_shapes(a.shape, b.shape)[:-1]
-    return _pallas_pair_count(a, b, op).reshape(shape)
+    return _pallas_pair_count(a, b, op, _interpret()).reshape(shape)
 
 
 def row_counts(a):
     """Per-row popcount over [..., W] — feeds TopN/Rows (the device-side
     replacement for the reference's rankCache, cache.go:136)."""
-    if not available():
+    if _DISABLED:
         return bitops.count(a)
     shape = a.shape[:-1]
-    out = _pallas_row_counts(a.reshape((-1, a.shape[-1])))
+    out = _pallas_row_counts(a.reshape((-1, a.shape[-1])), _interpret())
     return out.reshape(shape)

@@ -129,6 +129,9 @@ class WarmupService:
             if self._stats is not None:
                 self._stats.count("qos.warmupRuns", 1)
                 self._stats.count("qos.warmupPrograms", self.programs_compiled)
+                # Failures above are caught and logged so a node still
+                # boots; the counter is how a caller sees them.
+                self._stats.count("qos.warmupErrors", self.errors)
                 if self.replayed:
                     self._stats.count("qos.warmupReplayed", self.replayed)
                 if self.cache_hits:

@@ -9,7 +9,6 @@ runs the anti-entropy ticker.
 
 from __future__ import annotations
 
-import os
 import threading
 
 from pilosa_tpu.cluster.cluster import STATE_NORMAL, Cluster
@@ -174,28 +173,32 @@ class ServerNode:
         # Persistent XLA compilation cache: pointed at disk BEFORE the
         # planner exists, so its very first jit compile already reads
         # through the cache — a restarted node reuses every kernel
-        # prior runs compiled. None/"" resolves to <data-dir>/
-        # compile-cache (nodes without a data dir stay memory-only);
-        # "off" disables explicitly.
-        if not compile_cache_dir:
-            compile_cache_dir = (os.path.join(data_dir, "compile-cache")
-                                 if data_dir else "")
-        self.compile_cache_dir = "" if compile_cache_dir == "off" \
-            else compile_cache_dir
+        # prior runs compiled. The directory is part of the cache key,
+        # so it must not move between boots: JAX_COMPILATION_CACHE_DIR
+        # wins where the caller set it, then an explicit
+        # compile_cache_dir, then one fixed path in the checkout
+        # (compile_cache.resolve_dir). "off" disables explicitly.
+        self.compile_cache_dir = ""
         planner = None
         if use_planner:
-            if self.compile_cache_dir:
-                from pilosa_tpu.parallel import compile_cache
-                compile_cache.enable(self.compile_cache_dir,
-                                     stats=self.stats)
+            from pilosa_tpu.parallel import compile_cache
+            self.compile_cache_dir = compile_cache.resolve_dir(
+                compile_cache_dir)
+            compile_cache.enable(self.compile_cache_dir, stats=self.stats)
+            # A planner that was asked for and cannot be built is a
+            # start-up error: serving every query from the per-shard
+            # host path instead would hide that the device is gone.
+            # use_planner=False (--no-planner) is the explicit way to
+            # run without one.
+            from pilosa_tpu.parallel import MeshPlanner
             try:
-                from pilosa_tpu.parallel import MeshPlanner
                 planner = MeshPlanner(self.holder,
                                       bucket_policy=plan_buckets,
                                       stats=self.stats,
                                       coalesce_window_us=dispatch_coalesce_us)
-            except Exception:
-                planner = None
+            except Exception as e:
+                self.logger.printf("planner start-up failed: %r", e)
+                raise
         # Plan-keyed result cache (pilosa_tpu.cache): byte-bounded,
         # tenant-partitioned, shared by every consumer on this node.
         # <= 0 MB disables (the executor then runs every query).

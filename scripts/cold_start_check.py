@@ -6,7 +6,7 @@ traffic shapes through warmup.
 Boots a real server twice over the same data dir:
 
   boot 1: warmup runs, every compiled program is persisted under
-          <data-dir>/compile-cache, a query is served (so its shape is
+          the --compile-cache-dir given, a query is served (so its shape is
           recorded in warmup.json at graceful shutdown).
   boot 2: warmup replays, and the planner's re-traced kernels must
           load from disk — asserted via the compileCache.hits counter
@@ -39,12 +39,18 @@ def free_port() -> int:
 
 
 class Node:
-    def __init__(self, port: int, data_dir: str):
+    def __init__(self, port: int, data_dir: str, cache_dir: str):
         self.base = f"http://127.0.0.1:{port}"
+        # The check owns its cache directory: passed explicitly, and a
+        # caller's JAX_COMPILATION_CACHE_DIR (which would override it)
+        # is kept out of the child.
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
         self.proc = subprocess.Popen(
             [sys.executable, "-m", "pilosa_tpu.cli", "server",
-             "--bind", f"127.0.0.1:{port}", "--data-dir", data_dir],
-            env=dict(os.environ, JAX_PLATFORMS="cpu"))
+             "--bind", f"127.0.0.1:{port}", "--data-dir", data_dir,
+             "--compile-cache-dir", cache_dir],
+            env=env)
 
     def get(self, path: str) -> dict:
         data = urllib.request.urlopen(self.base + path, timeout=10).read()
@@ -100,7 +106,7 @@ def main() -> None:
     cache_dir = os.path.join(data_dir, "compile-cache")
 
     # ---- boot 1: compile, persist, observe traffic ----
-    node = Node(port, data_dir)
+    node = Node(port, data_dir, cache_dir)
     try:
         node.wait_up()
         counters = node.wait_warmup()
@@ -122,7 +128,7 @@ def main() -> None:
           "boot 1 saved observed traffic for replay", data_dir)
 
     # ---- boot 2: same data dir; kernels must come from disk ----
-    node = Node(port, data_dir)
+    node = Node(port, data_dir, cache_dir)
     try:
         node.wait_up()
         counters = node.wait_warmup()

@@ -91,8 +91,8 @@ def test_stats_sink_fanout(cache_dir):
 
 
 def test_enable_without_dir_is_noop_query(cache_dir):
-    # Passing an empty dir never flips state; it just answers whether
-    # the cache is already on.
+    # An empty dir ("off") never flips a live cache (first caller wins,
+    # like the directory); it just answers whether the cache is on.
     assert compile_cache.enable("") is True
 
 
@@ -121,3 +121,104 @@ def test_planner_second_boot_reuses_programs(cache_dir):
     after = compile_cache.stats()
     assert second == first == [6]
     assert after["hits"] > before["hits"], (before, after)
+
+
+# ------------------------------------------------------------------
+# where the cache lives: placed from outside, or one fixed path
+# ------------------------------------------------------------------
+
+
+_ENV_DIR_CHECKS = {
+    # a path given by flag: the variable wins, the program sets nothing
+    "path": (
+        "assert n.compile_cache_dir == outside, n.compile_cache_dir\n"
+        "st = compile_cache.stats()\n"
+        "assert st['enabled'] and st['dir'] == outside, st\n"
+        "assert jax.config.jax_compilation_cache_dir == outside\n"
+        "jax.jit(lambda x: x * 3)(jnp.arange(8)).block_until_ready()\n"
+        "assert compile_cache.stats()['requests'] > 0\n"
+        "assert os.listdir(outside), 'nothing persisted where asked'\n"),
+    # "off" disables even there: JAX alone would still persist to it
+    "off": (
+        "assert n.compile_cache_dir == '', n.compile_cache_dir\n"
+        "assert not compile_cache.stats()['enabled']\n"
+        "ls = lambda: (os.path.exists(outside)\n"
+        "              and sorted(os.listdir(outside)))\n"
+        "before = ls()  # what an import compiled before 'off' was said\n"
+        "jax.jit(lambda x: x * 3)(jnp.arange(8)).block_until_ready()\n"
+        "assert ls() == before, (before, ls())\n"),
+}
+
+
+@pytest.mark.parametrize("flag", ["path", "off"])
+def test_env_dir_wins_and_program_sets_no_other(tmp_path, flag):
+    """With JAX_COMPILATION_CACHE_DIR set by the caller, neither an
+    explicit compile_cache_dir nor the node default overrides it: JAX
+    reads the variable itself and the program sets no directory of its
+    own; only "off" switches the cache off. Process-global state, so a
+    fresh interpreter."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    outside = tmp_path / "placed-from-outside"
+    other = tmp_path / "explicit-flag"
+    code = (
+        "import os, sys\n"
+        "import jax, jax.numpy as jnp\n"
+        "from pilosa_tpu.parallel import compile_cache\n"
+        "from pilosa_tpu.server.node import ServerNode\n"
+        "outside, other, data = sys.argv[1:4]\n"
+        "n = ServerNode(bind='127.0.0.1:0', data_dir=data,\n"
+        "               compile_cache_dir=other)\n"
+        "try:\n"
+        + textwrap.indent(_ENV_DIR_CHECKS[flag], "    ") +
+        "    assert not os.path.exists(other)\n"
+        "    assert not os.path.exists(os.path.join(data, 'compile-cache'))\n"
+        "finally:\n"
+        "    n.close()\n"
+        "print('env-dir-ok')\n")
+    proc = subprocess.run(
+        [sys.executable, "-c", code, str(outside),
+         "off" if flag == "off" else str(other), str(tmp_path / "data")],
+        # Thresholds at which JAX alone would persist the tiny program,
+        # so an empty directory under "off" means the cache is off.
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 JAX_COMPILATION_CACHE_DIR=str(outside),
+                 JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+                 JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1"),
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        capture_output=True, text=True, timeout=300, check=False)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "env-dir-ok" in proc.stdout
+
+
+def test_default_dir_is_one_fixed_path_in_the_checkout(tmp_path,
+                                                       monkeypatch):
+    """Without the variable and without a flag, the cache lives at
+    <checkout>/.jax_cache: never under the data dir (a fresh mkdtemp in
+    most runs), because a directory that moves never hits."""
+    import os
+
+    from pilosa_tpu.server.node import ServerNode
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    fixed = os.path.join(repo, ".jax_cache")
+    assert compile_cache.DEFAULT_DIR == fixed
+    assert compile_cache.resolve_dir(None) == fixed
+    assert compile_cache.resolve_dir("") == fixed
+    assert compile_cache.resolve_dir("off") == ""
+    assert compile_cache.resolve_dir(str(tmp_path)) == str(tmp_path)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+    assert compile_cache.resolve_dir(str(tmp_path)) == "/placed/outside"
+    assert compile_cache.resolve_dir("off") == ""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    for data_dir in (None, str(tmp_path / "data")):
+        n = ServerNode(bind="127.0.0.1:0", data_dir=data_dir)
+        try:
+            assert n.compile_cache_dir == fixed
+        finally:
+            n.close()
+    assert not (tmp_path / "data" / "compile-cache").exists()

@@ -272,6 +272,39 @@ def test_planner_topn_streams_tiles(env, rng, monkeypatch):
     assert [(p.id, p.count) for p in got] == [(p.id, p.count) for p in want]
 
 
+def test_planner_topn_hands_fragments_single_device_segments(
+        env, monkeypatch):
+    """The filter stack is sharded over the mesh, so a slice of it spans
+    every device; the planner must hand each fragment's Pallas sweep a
+    segment on ONE device (on TPU chips a kernel over a mesh-spanning
+    operand does not compile; tests/test_tpu_compile.py pins that
+    refusal)."""
+    from pilosa_tpu.ops import pallas_kernels
+    h, idx, plain, fast = env
+    assert fast.planner.n_devices > 1
+    f = idx.create_field("f")
+    g = idx.create_field("g")
+    n_shards = 3
+    cols = np.arange(0, n_shards * SHARD_WIDTH, 3)  # dense rows
+    f.import_bits(np.repeat([1, 2], len(cols)), np.tile(cols, 2))
+    g.import_bits(np.ones(len(cols) // 2, dtype=np.int64), cols[::2])
+    seen = []
+    real = pallas_kernels.pair_count
+
+    def spy(a, b, op="and"):
+        seen.append((len(a.sharding.device_set), len(b.sharding.device_set)))
+        return real(a, b, op)
+
+    monkeypatch.setattr(pallas_kernels, "pair_count", spy)
+    (got,) = fast.execute("i", "TopN(f, Row(g=1), n=2)")
+    assert seen and set(seen) == {(1, 1)}
+    monkeypatch.setattr(pallas_kernels, "pair_count", real)
+    (want,) = plain.execute("i", "TopN(f, Row(g=1), n=2)")
+    assert [(p.id, p.count) for p in got] == \
+        [(p.id, p.count) for p in want] == \
+        [(1, len(cols[::2])), (2, len(cols[::2]))]
+
+
 def test_prepared_count_fast_path_invalidation(mesh):
     """execute_async's prepared-query cache must never serve stale
     programs: a write (data epoch), a schema change, and a different

@@ -1,7 +1,7 @@
 """Device residency tests: container-classed stacks (exec/residency)
 and the pipelined prefetch miss path (parallel/prefetch).
 
-The contract mirrors the reference's roaring container taxonomy tests
+The contract mirrors the reference's roaring container-class tests
 (roaring_internal_test.go: array/bitmap conversions are bit-exact):
 the packed representation must be *bit-identical* to dense through
 every query family, proven generatively over seeded random data, while

@@ -248,11 +248,9 @@ def test_fuzz_loop_smoke():
     import os
     import subprocess
     root = os.path.join(os.path.dirname(__file__), "..", "native")
-    try:
-        subprocess.run(["make", "-C", root, "fuzz_roaring", "-s"],
-                       check=True, capture_output=True, timeout=120)
-    except (subprocess.CalledProcessError, FileNotFoundError):
-        import pytest
+    # Built from the committed sources on this host, never a binary a
+    # previous machine left in the tree.
+    if not native.ensure_built("fuzz_roaring"):
         pytest.skip("no sanitizer toolchain")
     res = subprocess.run([os.path.join(root, "fuzz_roaring"), "5000"],
                          capture_output=True, timeout=300, text=True,
