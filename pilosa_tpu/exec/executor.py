@@ -21,7 +21,6 @@ TPU-first departures (same semantics, different math):
 from __future__ import annotations
 
 import threading
-import time
 from collections import OrderedDict
 from concurrent.futures import Future
 from dataclasses import dataclass, field as dc_field, replace
@@ -45,6 +44,7 @@ from pilosa_tpu.errors import (
 )
 from pilosa_tpu.exec import fuse as _fuse
 from pilosa_tpu.obs import profile as _profile
+from pilosa_tpu.obs.tracing import start_span
 from pilosa_tpu.ops import bitops
 from pilosa_tpu import sketch as _sketch
 from pilosa_tpu.sketch import hll as _hll
@@ -175,13 +175,8 @@ class Executor:
         it) for this call — used by benchmarks to measure the cold path.
         """
         raw = query if isinstance(query, str) else None
-        prof = _profile.current()
         if raw is not None:
-            if prof is not None:
-                t0 = time.perf_counter()
-                query = self._parse_cached(raw)
-                prof.add_ms("parseMs", (time.perf_counter() - t0) * 1e3)
-            else:
+            with start_span("exec.parse", stats=self.stats):
                 query = self._parse_cached(raw)
         opt = opt or ExecOptions()
         if not opt.remote:
@@ -216,14 +211,13 @@ class Executor:
             # fresh; may conservatively recompute).
             sch = idx.schema_epoch.value
             loc = idx.epoch.max_shard_epoch(shards)
+            with start_span("exec.cache", stats=self.stats):
+                hit = self.result_cache.get(
+                    tenant, key,
+                    (sch, loc,
+                     self.remote_epochs.rows_for(idx.name, shards)))
+            prof = _profile.current()
             if prof is not None:
-                t0 = time.perf_counter()
-            hit = self.result_cache.get(
-                tenant, key,
-                (sch, loc, self.remote_epochs.rows_for(idx.name, shards)))
-            if prof is not None:
-                prof.add_ms("cacheLookupMs",
-                            (time.perf_counter() - t0) * 1e3)
                 prof.cache_hit = hit is not None
             if hit is not None:
                 return hit
@@ -483,8 +477,7 @@ class Executor:
         # Per-call stats, tagged by index (reference CountWithCustomTags,
         # executor.go:295 etc.).
         self.stats.with_tags(f"index:{idx.name}").count(name)
-        from pilosa_tpu.obs import start_span
-        with start_span(f"Executor.execute{name}") as span:
+        with start_span(f"Executor.execute{name}", stats=self.stats) as span:
             before = _fuse.fused_steps()
             try:
                 return self._execute_call_inner(idx, c, shards, opt)

@@ -46,6 +46,10 @@ class _OTLPSpan:
         self.tags: dict = {}
 
     def set_tag(self, key, value) -> None:
+        if key == "trace.id":
+            # A span that joins a propagated trace after it opened (the
+            # HTTP handler's, obs/tracing.py) moves with its children.
+            self.trace_id = _trace_id_hex(value)
         self.tags[key] = value
 
     def finish(self) -> None:

@@ -1,8 +1,10 @@
 """Per-query cost profiles: where did these 2.8 ms go?
 
 A QueryProfile is a contextvar-scoped ledger accumulated along the whole
-read path: admission wait, parse + plan, result-cache lookup, per-step
-dispatch count and device ms (fused vs stepped, coalesce batch width),
+read path: every span that closes while it is active (obs/tracing.py:
+count, wall, thread-CPU and self-CPU ms by name; the admission wait,
+parse and result-cache lookup timings are fed by their spans), per-step
+dispatch count and launch ms (fused vs stepped, coalesce batch width),
 TransferBatcher wave membership, and one entry per remote leg (wire
 bytes in/out, decode ms, rtt, hedge/breaker events) with the remote
 node's own profile nested inside — a cluster query returns a complete
@@ -30,9 +32,15 @@ import time
 _current_profile: contextvars.ContextVar["QueryProfile | None"] = \
     contextvars.ContextVar("pilosa_profile", default=None)
 
+#: spans whose wall time is also a named phase of ``timings``.
+_PHASE_OF_SPAN = {"qos.admit": "admissionWaitMs", "exec.parse": "parseMs",
+                  "exec.cache": "cacheLookupMs"}
+
 #: per-query bounded detail lists (dispatch widths, wave widths, legs):
 #: a pathological query cannot grow its own profile without bound.
 MAX_DETAIL = 128
+#: spans logged per query (a query of many calls opens six or so each).
+MAX_SPANS = 4096
 
 
 def current() -> "QueryProfile | None":
@@ -54,7 +62,7 @@ class QueryProfile:
 
     __slots__ = ("trace_id", "query", "index", "node", "qos_class",
                  "remote", "start", "timings", "cache_hit", "fused_steps",
-                 "dispatches", "dispatch_widths", "device_ms",
+                 "dispatches", "dispatch_widths", "launch_ms", "_span_log",
                  "transfer_waves", "wave_widths", "inline_steals",
                  "remote_legs", "events", "status", "_lock")
 
@@ -72,7 +80,12 @@ class QueryProfile:
         self.fused_steps = 0
         self.dispatches = 0
         self.dispatch_widths: list[int] = []
-        self.device_ms = 0.0
+        #: host time enqueueing device programs (the ``dispatch.launch``
+        #: spans); device time comes from the trace, not a host clock.
+        self.launch_ms = 0.0
+        #: (name, wall s, thread-CPU s or None, self-CPU s or None) of
+        #: every span that closed while this profile was active.
+        self._span_log: list[tuple] = []
         self.transfer_waves = 0
         self.wave_widths: list[int] = []
         self.inline_steals = 0
@@ -85,14 +98,41 @@ class QueryProfile:
 
     # -- recording hooks (each guarded by `current() is None` upstream) --
 
-    def add_ms(self, phase: str, ms: float) -> None:
-        with self._lock:
-            self.timings[phase] = self.timings.get(phase, 0.0) + ms
+    def add_span(self, name: str, wall: float, cpu: float | None,
+                 self_cpu: float | None) -> None:
+        """A span closed while this profile was active (seconds in; the
+        CPU pair is None where the span's tree did not read that clock:
+        tracing.CPU_SAMPLE_EVERY)."""
+        # On every span's path: one append (atomic, so the flusher's
+        # thread needs no lock either); finish() folds the log.
+        if len(self._span_log) < MAX_SPANS:
+            self._span_log.append((name, wall, cpu, self_cpu))
 
-    def add_dispatch(self, width: int, device_ms: float = 0.0) -> None:
+    def _fold_spans(self) -> dict:
+        """``spans`` of the document; adds the phases and the launch
+        time that the spans feed to ``timings`` / ``launch_ms``."""
+        spans: dict[str, dict] = {}
+        log, self._span_log = self._span_log, []
+        for name, wall, cpu, self_cpu in log:
+            wall_ms = wall * 1e3
+            e = spans.get(name)
+            if e is None:
+                e = spans[name] = {"count": 0, "wallMs": 0.0}
+            e["count"] += 1
+            e["wallMs"] += wall_ms
+            if cpu is not None:
+                e["cpuMs"] = e.get("cpuMs", 0.0) + cpu * 1e3
+                e["selfCpuMs"] = e.get("selfCpuMs", 0.0) + self_cpu * 1e3
+            phase = _PHASE_OF_SPAN.get(name)
+            if phase is not None:
+                self.timings[phase] = self.timings.get(phase, 0.0) + wall_ms
+            elif name == "dispatch.launch":
+                self.launch_ms += wall_ms
+        return spans
+
+    def add_dispatch(self, width: int) -> None:
         with self._lock:
             self.dispatches += 1
-            self.device_ms += device_ms
             if len(self.dispatch_widths) < MAX_DETAIL:
                 self.dispatch_widths.append(int(width))
 
@@ -142,6 +182,7 @@ class QueryProfile:
         with self._lock:
             total_ms = (time.perf_counter() - self.start) * 1000.0
             self.timings.setdefault("totalMs", round(total_ms, 4))
+            spans = self._fold_spans()
             doc = {
                 "traceId": self.trace_id,
                 "node": self.node,
@@ -154,7 +195,7 @@ class QueryProfile:
                 "fusedSteps": self.fused_steps,
                 "dispatch": {
                     "count": self.dispatches,
-                    "deviceMs": round(self.device_ms, 4),
+                    "launchMs": round(self.launch_ms, 4),
                     "widths": list(self.dispatch_widths),
                 },
                 "transfer": {
@@ -163,6 +204,8 @@ class QueryProfile:
                     "inlineSteals": self.inline_steals,
                 },
             }
+            if spans:
+                doc["spans"] = spans
             if self.events:
                 doc["events"] = dict(self.events)
             if self.remote_legs:

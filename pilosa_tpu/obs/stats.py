@@ -67,6 +67,16 @@ class MemoryStats:
             key = (name, self.tags)
             self.counters[key] = self.counters.get(key, 0) + value
 
+    def count_many(self, values: dict[str, float]) -> None:
+        """Add to several counters in ONE acquisition of the registry's
+        lock (the span ledger's fold, obs/tracing.py)."""
+        tags = self.tags
+        with self._lock:
+            counters = self.counters
+            for name, value in values.items():
+                key = (name, tags)
+                counters[key] = counters.get(key, 0) + value
+
     def gauge(self, name: str, value: float) -> None:
         with self._lock:
             self.gauges[(name, self.tags)] = value

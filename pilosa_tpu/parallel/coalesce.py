@@ -49,6 +49,7 @@ from typing import Any, Callable
 import numpy as np
 
 from pilosa_tpu.obs import profile as _profile
+from pilosa_tpu.obs.tracing import start_span
 
 _MODES = ("on", "off", "auto")
 
@@ -265,23 +266,18 @@ class DispatchCoalescer:
         import jax
 
         planner = self.planner
-        if prof is _CTX:
-            prof = _profile.current()
+        # On the caller's thread the span charges the active profile; on
+        # the flusher's, the one captured at dispatch() time.
+        profs = None if prof is _CTX else (prof,)
         try:
-            if prof is not None:
-                t0 = time.perf_counter()
-                out = fn(*args)
-                dev_ms = (time.perf_counter() - t0) * 1e3
-            else:
+            with start_span("dispatch.launch", stats=planner.stats,
+                            profiles=profs):
                 out = fn(*args)
         except Exception as e:
             fut: Future = Future()
             fut.set_exception(e)
             return fut
-        if prof is not None:
-            planner._record_dispatch(1, dev_ms, profs=(prof,))
-        else:
-            planner._record_dispatch(1, profs=())
+        planner._record_dispatch(1, profs=profs)
         self._note_inflight(key, +1)
         leaves, treedef = jax.tree_util.tree_flatten(out)
         _copy_async(leaves)
@@ -317,38 +313,38 @@ class DispatchCoalescer:
         b = len(entries)
         args0 = entries[0][0]
         profs = [e[3] for e in entries]
-        any_prof = any(p is not None for p in profs)
-        t0 = time.perf_counter() if any_prof else 0.0
         shared = all(_args_identical(e[0], args0) for e in entries[1:])
-        if shared:
-            # N callers, same plan, same leaf arrays (the cached-stack
-            # common case): one plain launch, output shared by every
-            # caller's own postproc.
-            out = batch.fn(*args0)
-            slot = None
-        else:
-            raw = planner.fn_raw(batch.fn)
-            if raw is None or not planner.coalesce_vmap_supported:
-                # No vmappable program (e.g. a Pallas kernel): launch
-                # per entry — still one trip through this thread, and
-                # the accounting stays honest (B launches recorded).
-                for args, post, fut, prof in entries:
-                    _chain(self._launch_one(batch.key, batch.fn, args,
-                                            post, prof=prof), fut)
-                return
-            # Same plan shape, different literals/leaves: stack each
-            # argument leaf to [B, ...] (padded to a pow2 bucket by
-            # repeating slot 0, so batch widths reuse compiled
-            # kernels) and launch ONE vmapped program.
-            import jax.numpy as jnp
-            b_pad = 1 << (b - 1).bit_length()
-            rows = [e[0] for e in entries] + [args0] * (b_pad - b)
-            stacked = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *rows)
-            out = planner.vmapped(batch.key, raw)(*stacked)
-            slot = True
-        dev_ms = (time.perf_counter() - t0) * 1e3 if any_prof else 0.0
-        planner._record_dispatch(b, dev_ms, profs=profs)
+        raw = None if shared else planner.fn_raw(batch.fn)
+        if not shared and (raw is None
+                           or not planner.coalesce_vmap_supported):
+            # No vmappable program (e.g. a Pallas kernel): launch
+            # per entry — still one trip through this thread, and
+            # the accounting stays honest (B launches recorded).
+            for args, post, fut, prof in entries:
+                _chain(self._launch_one(batch.key, batch.fn, args,
+                                        post, prof=prof), fut)
+            return
+        with start_span("dispatch.launch", stats=planner.stats,
+                        profiles=profs):
+            if shared:
+                # N callers, same plan, same leaf arrays (the
+                # cached-stack common case): one plain launch, output
+                # shared by every caller's own postproc.
+                out = batch.fn(*args0)
+                slot = None
+            else:
+                # Same plan shape, different literals/leaves: stack each
+                # argument leaf to [B, ...] (padded to a pow2 bucket by
+                # repeating slot 0, so batch widths reuse compiled
+                # kernels) and launch ONE vmapped program.
+                import jax.numpy as jnp
+                b_pad = 1 << (b - 1).bit_length()
+                rows = [e[0] for e in entries] + [args0] * (b_pad - b)
+                stacked = jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs), *rows)
+                out = planner.vmapped(batch.key, raw)(*stacked)
+                slot = True
+        planner._record_dispatch(b, profs=profs)
         self._note_inflight(batch.key, +1)
         leaves, treedef = jax.tree_util.tree_flatten(out)
         _copy_async(leaves)

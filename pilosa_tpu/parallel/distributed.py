@@ -53,7 +53,7 @@ from pilosa_tpu.core.view import VIEW_STANDARD
 from pilosa_tpu.errors import QueryError
 from pilosa_tpu.exec.executor import Executor
 from pilosa_tpu.parallel.mesh import SHARD_AXIS
-from pilosa_tpu.parallel.planner import MeshPlanner
+from pilosa_tpu.parallel.planner import MeshPlanner, _named
 from pilosa_tpu.pql import Call
 
 
@@ -165,11 +165,14 @@ class DistributedMeshPlanner(MeshPlanner):
 
         from pilosa_tpu.ops import bitops
         self._replicate_jit = jax.jit(
-            lambda *xs: xs, out_shardings=self._replicated)
-        self._count_jit = jax.jit(bitops.count,
-                                  out_shardings=self._replicated)
+            _named(lambda *xs: xs, "replicate_small"),
+            out_shardings=self._replicated)
+        self._count_jit = jax.jit(
+            _named(lambda x: bitops.count(x), "groupby_count"),
+            out_shardings=self._replicated)
         self._and_count_jit = jax.jit(
-            lambda x, y: bitops.count(jnp.bitwise_and(x, y)),
+            _named(lambda x, y: bitops.count(jnp.bitwise_and(x, y)),
+                   "groupby_and_count"),
             out_shardings=self._replicated)
 
     # -- ownership ------------------------------------------------------
@@ -217,7 +220,7 @@ class DistributedMeshPlanner(MeshPlanner):
                     f"shard {shard} has a local fragment on process "
                     f"{self._pid} but is not owned — ownership "
                     f"discipline violated")
-        pieces = []
+        blocks = []
         for dev, lo, hi in self._local_rows(s_pad):
             block = np.zeros((hi - lo, WORDS_PER_SHARD), dtype=np.uint32)
             for i in range(lo, min(hi, len(shards))):
@@ -229,10 +232,14 @@ class DistributedMeshPlanner(MeshPlanner):
                                             shard)
                 if frag is not None:
                     block[i - lo] = frag.row_words(row_id)
-            pieces.append(jax.device_put(block, dev))
-        arr = jax.make_array_from_single_device_arrays(
-            (s_pad, WORDS_PER_SHARD), self._sharded, pieces)
-        return arr, int(sum(p.nbytes for p in pieces))
+            blocks.append((block, dev))
+
+        def upload():
+            return jax.make_array_from_single_device_arrays(
+                (s_pad, WORDS_PER_SHARD), self._sharded,
+                [jax.device_put(block, dev) for block, dev in blocks])
+
+        return upload, int(sum(block.nbytes for block, _ in blocks))
 
     def _zeros_stack(self, n_shards: int):
         s_pad = self._pad(n_shards)

@@ -44,6 +44,7 @@ from pilosa_tpu.errors import (
 from pilosa_tpu.exec import fuse as _fuse
 from pilosa_tpu.exec import residency as _residency
 from pilosa_tpu.obs import profile as _profile
+from pilosa_tpu.obs.tracing import start_span
 from pilosa_tpu.obs.histogram import WIDTH_BOUNDS, LogHistogram
 from pilosa_tpu.ops import bitops, bsi as bsi_ops
 from pilosa_tpu.parallel import compile_cache
@@ -113,7 +114,8 @@ class MeshPlanner:
         #: sparse-upload assembler, jitted per mesh so the scatter
         #: output lands sharded (see _build_stack).
         self._assemble_jit = jax.jit(
-            _assemble_stack, static_argnames=("s_pad",),
+            _named(_assemble_stack, "stack_assemble"),
+            static_argnames=("s_pad",),
             out_shardings=shard_spec(self.mesh))
         #: cross-query transfer coalescing (parallel.batcher): every
         #: Count pull goes through it, so concurrent queries share one
@@ -223,8 +225,8 @@ class MeshPlanner:
         """Count(tree) as one device program (per-shard popcounts,
         summed on the host); the result transfer rides the shared
         batcher wave."""
-        return self.execute_count_async(idx, c, shards,
-                                        const_rows=const_rows).result()
+        return self._wait(self.execute_count_async(
+            idx, c, shards, const_rows=const_rows))
 
     def execute_count_async(self, idx: Index, c: Call, shards: list[int],
                             const_rows: list | None = None):
@@ -249,50 +251,83 @@ class MeshPlanner:
         without dispatching — the executor's prepared-query fast path
         caches the pair and re-dispatches with zero per-query planning
         as long as the index epochs stand still."""
-        # schema_epoch: plans bake field STRUCTURE (a BSI comparator's
-        # bit-depth, sign-class branches, base folds), so any schema
-        # change — field create/delete, bit-depth growth — must miss.
         # Const-leaf plans (partial fusion of a mixed tree) bypass the
         # text-keyed plan cache: their __const__ slots print identically
         # while holding per-query host rows. The structural _fn_cache
         # still shares the compiled program across const values.
-        hit = None
+        shards = tuple(shards)
+
+        def build(leaves):
+            sig = self._signature(idx, c, leaves, shards)
+            return self._compiled(("count",) + sig, sig, len(leaves),
+                                  reduce="per_shard")
+
         if const_rows is None:
+            # Observed as the executable form (with the Count wrapper):
+            # warmup replays these strings through the Executor, and
+            # only a Count() reaches prepare_count again.
+            leaves, fn = self._plan_cached(idx, str(c), shards, build,
+                                           observed=f"Count({c})")
+        else:
+            leaves = []
+            with start_span("plan.prepare", stats=self.stats):
+                fn = build(leaves)
+        return fn, self._fetch_leaves(idx, leaves, shards,
+                                      const_rows=const_rows)
+
+    def _plan_cached(self, idx: Index, text: str, shards: tuple,
+                     build: Callable[[list], Callable],
+                     observed: str | None = None):
+        """(leaf descriptors, jitted fn) through the prepared-plan cache;
+        on a miss ``build(leaves)`` fills the leaf list and returns the
+        compiled program, and ``observed`` (the query's executable text)
+        joins the warm-up seed list."""
+        with start_span("plan.prepare", stats=self.stats):
+            # schema_epoch: plans bake field STRUCTURE (a BSI
+            # comparator's bit-depth, sign-class branches, base folds),
+            # so any schema change — field create/delete, bit-depth
+            # growth — must miss.
             plan_key = (idx.name, idx.instance_id, idx.schema_epoch.value,
-                        str(c), tuple(shards))
+                        text, shards)
             with self._cache_lock:
                 hit = self._plan_cache.get(plan_key)
                 if hit is not None:
                     self._plan_cache.move_to_end(plan_key)
             if hit is not None:
-                hit = self._revalidate_plan(idx, plan_key, hit, tuple(shards))
-        if hit is not None:
-            leaves, fn = hit[0], hit[1]
-        else:
-            leaves = []
-            sig = self._signature(idx, c, leaves, tuple(shards))
-            fn = self._compiled(("count",) + sig, sig,
-                                reduce="per_shard")
-            if const_rows is None:
-                with self._cache_lock:
-                    self._plan_cache[plan_key] = (leaves, fn,
-                                                  idx.epoch.value)
-                    while len(self._plan_cache) > self.PLAN_CACHE_SIZE:
-                        self._plan_cache.popitem(last=False)
-                    # Record the executable form (with the Count
-                    # wrapper): warmup replays these strings through the
-                    # Executor, and only a Count() reaches prepare_count
-                    # again.
-                    okey = (idx.name, f"Count({c})", len(shards))
+                hit = self._revalidate_plan(idx, plan_key, hit, shards)
+            if hit is not None:
+                return hit[0], hit[1]
+            leaves: list[tuple] = []
+            fn = build(leaves)
+            with self._cache_lock:
+                self._plan_cache[plan_key] = (leaves, fn, idx.epoch.value)
+                while len(self._plan_cache) > self.PLAN_CACHE_SIZE:
+                    self._plan_cache.popitem(last=False)
+                if observed is not None:
+                    okey = (idx.name, observed, len(shards))
                     self._observed[okey] = self._observed.get(okey, 0) + 1
                     self._observed.move_to_end(okey)
                     while len(self._observed) > self.OBSERVED_SIZE:
                         self._observed.popitem(last=False)
-        self._prefetch_leaves(idx, leaves, tuple(shards))
-        arrays = [self._fetch_leaf(idx, leaf, tuple(shards),
-                                   const_rows=const_rows)
-                  for leaf in leaves]
-        return fn, arrays
+            return leaves, fn
+
+    def _fetch_leaves(self, idx: Index, leaves: list, shards: tuple,
+                      const_rows: list | None = None) -> list:
+        """A plan's leaf arrays: residency as the request sees it (~0
+        when every stack is resident; waits and synchronous builds
+        otherwise)."""
+        with start_span("stack.fetch", stats=self.stats):
+            self._prefetch_leaves(idx, leaves, shards)
+            return [self._fetch_leaf(idx, leaf, shards,
+                                     const_rows=const_rows)
+                    for leaf in leaves]
+
+    def _wait(self, fut):
+        """Block the request's thread on a dispatch future: device run,
+        device->host copy, resolver hand-off and the wait to be
+        scheduled again."""
+        with start_span("transfer.wait", stats=self.stats):
+            return fut.result()
 
     def _revalidate_plan(self, idx: Index, plan_key: tuple, hit: tuple,
                          shards: tuple):
@@ -338,8 +373,7 @@ class MeshPlanner:
 
     # -- launch accounting / program registry --------------------------
 
-    def _record_dispatch(self, width: int = 1, device_ms: float = 0.0,
-                         profs=None) -> None:
+    def _record_dispatch(self, width: int = 1, profs=None) -> None:
         """One device-program launch answering ``width`` queries.
 
         ``planner.dispatchCount`` sums the query programs of every
@@ -369,11 +403,11 @@ class MeshPlanner:
         if profs is None:
             p = _profile.current()
             if p is not None:
-                p.add_dispatch(width, device_ms)
+                p.add_dispatch(width)
             return
         for p in profs:
             if p is not None:
-                p.add_dispatch(width, device_ms)
+                p.add_dispatch(width)
 
     def batch_widths(self) -> list[int]:
         """Recent per-launch batch widths (bench's coalesce p50)."""
@@ -400,7 +434,7 @@ class MeshPlanner:
         with self._cache_lock:
             vfn = self._vmap_cache.get(full_sig)
         if vfn is None:
-            vfn = jax.jit(jax.vmap(raw))
+            vfn = jax.jit(_named(jax.vmap(raw), raw.__name__ + "_wave"))
             with self._cache_lock:
                 self._vmap_cache[full_sig] = vfn
         return vfn
@@ -409,12 +443,12 @@ class MeshPlanner:
                     const_rows: list | None = None) -> jax.Array:
         """Evaluate a bitmap tree to its stacked [S_pad, W] device array."""
         leaves: list[tuple] = []
-        sig = self._signature(idx, c, leaves, tuple(shards))
-        self._prefetch_leaves(idx, leaves, tuple(shards))
-        arrays = [self._fetch_leaf(idx, leaf, tuple(shards),
-                                   const_rows=const_rows)
-                  for leaf in leaves]
-        fn = self._compiled(("row",) + sig, sig, reduce=None)
+        with start_span("plan.prepare", stats=self.stats):
+            sig = self._signature(idx, c, leaves, tuple(shards))
+            fn = self._compiled(("row",) + sig, sig, len(leaves),
+                                reduce=None)
+        arrays = self._fetch_leaves(idx, leaves, tuple(shards),
+                                    const_rows=const_rows)
         out = fn(*arrays)
         self._record_dispatch(1)
         _fuse.add_fused_steps(_fuse.call_steps(c))
@@ -478,32 +512,18 @@ class MeshPlanner:
         field_name, _ = c.string_arg("field")
         f = idx.field(field_name)
         depth = f.bsi_group.bit_depth
-        plan_key = (idx.name, idx.instance_id, idx.schema_epoch.value,
-                    f"{kind}{int(is_min)}:{c}", tuple(shards))
-        with self._cache_lock:
-            hit = self._plan_cache.get(plan_key)
-            if hit is not None:
-                self._plan_cache.move_to_end(plan_key)
-        if hit is not None:
-            hit = self._revalidate_plan(idx, plan_key, hit, tuple(shards))
-        if hit is not None:
-            leaves, fn = hit[0], hit[1]
-        else:
-            leaves = [("bsiagg", field_name, depth)]
+
+        def build(leaves):
+            leaves.append(("bsiagg", field_name, depth))
             filt_sig = (self._signature(idx, c.children[0], leaves,
                                         tuple(shards))
                         if c.children else None)
-            full_sig = (kind, is_min, depth, filt_sig)
-            fn = self._compiled_agg(full_sig, kind, depth, filt_sig,
-                                    is_min)
-            with self._cache_lock:
-                self._plan_cache[plan_key] = (leaves, fn, idx.epoch.value)
-                while len(self._plan_cache) > self.PLAN_CACHE_SIZE:
-                    self._plan_cache.popitem(last=False)
-        self._prefetch_leaves(idx, leaves, tuple(shards))
-        arrays = [self._fetch_leaf(idx, leaf, tuple(shards))
-                  for leaf in leaves]
-        return fn, arrays, depth
+            return self._compiled_agg((kind, is_min, depth, filt_sig),
+                                      kind, depth, filt_sig, is_min)
+
+        leaves, fn = self._plan_cached(
+            idx, f"{kind}{int(is_min)}:{c}", tuple(shards), build)
+        return fn, self._fetch_leaves(idx, leaves, tuple(shards)), depth
 
     def _compiled_agg(self, full_sig: tuple, kind: str, depth: int,
                       filt_sig, is_min: bool) -> Callable:
@@ -531,7 +551,9 @@ class MeshPlanner:
                                           depth)
             return _agg_min_max(exists, sign, stack, filt, depth, is_min)
 
-        fn = self._jit_program(program, None)
+        name = "bsi_sum" if kind == "sum" else \
+            "bsi_min" if is_min else "bsi_max"
+        fn = self._jit_program(_named(program, name), None)
         self._fn_cache[full_sig] = fn
         self._register_fn(fn, full_sig, program)
         return fn
@@ -540,7 +562,7 @@ class MeshPlanner:
         """Global (sum-of-base-offsets, count) in one device program; the
         executor applies the BSI base (reference fragment.sum :1111 under
         executeSum :406)."""
-        return self.dispatch_sum(idx, c, shards).result()
+        return self._wait(self.dispatch_sum(idx, c, shards))
 
     def _fuse_agg_ok(self, c: Call) -> bool:
         """Fused-aggregate gate. Unfiltered aggregates fuse everywhere:
@@ -613,7 +635,7 @@ class MeshPlanner:
         """Global (value, count) pre-base: every shard's extremum computed
         in one stacked program (the shape-polymorphic bit-serial descent of
         ops.bsi), host-folded with the reference's smaller/larger rule."""
-        return self.dispatch_min_max(idx, c, shards, is_min).result()
+        return self._wait(self.dispatch_min_max(idx, c, shards, is_min))
 
     def dispatch_min_max(self, idx: Index, c: Call, shards: list[int],
                          is_min: bool):
@@ -685,7 +707,7 @@ class MeshPlanner:
                                    shards: list[int], p: int) -> np.ndarray:
         """Merged uint8[2^p] HLL registers of the filtered column set
         across ``shards`` — one device dispatch."""
-        return self.dispatch_distinct(idx, c, shards, p).result()
+        return self._wait(self.dispatch_distinct(idx, c, shards, p))
 
     def dispatch_distinct(self, idx: Index, c: Call, shards: list[int],
                           p: int):
@@ -712,34 +734,21 @@ class MeshPlanner:
         field_name, _ = c.string_arg("field")
         f = idx.field(field_name)
         depth = f.bsi_group.bit_depth
-        plan_key = (idx.name, idx.instance_id, idx.schema_epoch.value,
-                    f"distinct{p}:{c}", tuple(shards))
-        with self._cache_lock:
-            hit = self._plan_cache.get(plan_key)
-            if hit is not None:
-                self._plan_cache.move_to_end(plan_key)
-        if hit is not None:
-            hit = self._revalidate_plan(idx, plan_key, hit, tuple(shards))
-        if hit is not None:
-            leaves, fn = hit[0], hit[1]
-        else:
+
+        def build(leaves):
             if c.children:
-                leaves = [("hll", field_name, depth, p)]
+                leaves.append(("hll", field_name, depth, p))
                 filt_sig = self._signature(idx, c.children[0], leaves,
                                            tuple(shards))
             else:
-                leaves = [("hllreg", field_name, depth, p)]
+                leaves.append(("hllreg", field_name, depth, p))
                 filt_sig = None
-            full_sig = ("distinct", p, depth, filt_sig)
-            fn = self._compiled_distinct(full_sig, p, filt_sig)
-            with self._cache_lock:
-                self._plan_cache[plan_key] = (leaves, fn, idx.epoch.value)
-                while len(self._plan_cache) > self.PLAN_CACHE_SIZE:
-                    self._plan_cache.popitem(last=False)
-        self._prefetch_leaves(idx, leaves, tuple(shards))
-        arrays = [self._fetch_leaf(idx, leaf, tuple(shards))
-                  for leaf in leaves]
-        return fn, arrays
+            return self._compiled_distinct(
+                ("distinct", p, depth, filt_sig), p, filt_sig)
+
+        leaves, fn = self._plan_cached(idx, f"distinct{p}:{c}",
+                                       tuple(shards), build)
+        return fn, self._fetch_leaves(idx, leaves, tuple(shards))
 
     def _compiled_distinct(self, full_sig: tuple, p: int,
                            filt_sig) -> Callable:
@@ -758,7 +767,7 @@ class MeshPlanner:
             filt = jax.lax.optimization_barrier(_eval_node(filt_sig, args))
             return jnp.max(hll_expand(args[0], filt, p), axis=0)
 
-        fn = self._jit_program(program, None)
+        fn = self._jit_program(_named(program, "hll_distinct"), None)
         self._fn_cache[full_sig] = fn
         self._register_fn(fn, full_sig, program)
         return fn
@@ -796,9 +805,7 @@ class MeshPlanner:
         filt_sig = self._signature(idx, filter_call, leaves, tuple(shards))
         full_sig = ("simtopn", r_pad, filt_sig)
         fn = self._compiled_similar(full_sig, r_pad, filt_sig)
-        self._prefetch_leaves(idx, leaves, tuple(shards))
-        arrays = [self._fetch_leaf(idx, leaf, tuple(shards))
-                  for leaf in leaves]
+        arrays = self._fetch_leaves(idx, leaves, tuple(shards))
         _fuse.add_fused_steps(_fuse.call_steps(filter_call) + 1)
         ids_arr = np.asarray(ids, dtype=np.uint64)
 
@@ -809,7 +816,7 @@ class MeshPlanner:
             return (ids_arr, inter, selfc, int(filtc),
                     np.asarray(order)[:r])
 
-        return self.coalescer.dispatch(fn, arrays, fold).result()
+        return self._wait(self.coalescer.dispatch(fn, arrays, fold))
 
     def _compiled_similar(self, full_sig: tuple, r_pad: int,
                           filt_sig) -> Callable:
@@ -823,7 +830,7 @@ class MeshPlanner:
             filt = jax.lax.optimization_barrier(_eval_node(filt_sig, args))
             return sim(args[0], filt)
 
-        fn = self._jit_program(program, None)
+        fn = self._jit_program(_named(program, "similar_topn"), None)
         self._fn_cache[full_sig] = fn
         self._register_fn(fn, full_sig, program)
         return fn
@@ -905,10 +912,12 @@ class MeshPlanner:
                     for slots, dev in parts]
             pending.append((shard, ids, counts, futs))
         # Resolve every shard's device tiles in one pipelined wave.
-        for shard, ids, counts, futs in pending:
-            for slots, fut in futs:
-                counts[slots] = np.asarray(fut.result(),
-                                           dtype=np.int64)[:len(slots)]
+        with start_span("transfer.wait", stats=self.stats):
+            for _, _, counts, futs in pending:
+                for slots, fut in futs:
+                    counts[slots] = np.asarray(fut.result(),
+                                               dtype=np.int64)[:len(slots)]
+        for shard, ids, counts, _ in pending:
             order = np.lexsort((ids, -counts))
             out[shard] = (ids[order], counts[order])
         return out
@@ -996,9 +1005,11 @@ class MeshPlanner:
                     rec(level + 1, nxt, prefix + (r,))
 
         rec(0, None, ())
+        with start_span("transfer.wait", stats=self.stats):
+            hosts = [fut.result() for _, fut in pending]
         out = []
-        for group, fut in pending:
-            cnt = int(np.asarray(fut.result(), dtype=np.int64).sum())
+        for (group, _), host in zip(pending, hosts):
+            cnt = int(np.asarray(host, dtype=np.int64).sum())
             if cnt > 0:
                 out.append((group, cnt))
         return out
@@ -1328,13 +1339,16 @@ class MeshPlanner:
         # to build the same stack; the second insert simply wins.
         if gens is None:
             gens = self._gens(idx.name, field_name, view, shards)
-        if klass == _residency.PACKED:
-            arr, nbytes = self._build_stack_packed(idx, field_name, view,
-                                                   row_id, shards)
-        else:
-            arr, nbytes = self._build_stack(idx, field_name, view, row_id,
-                                            shards)
-        self._insert_stack(key, epoch, gens, arr, nbytes)
+        build = (self._build_stack_packed if klass == _residency.PACKED
+                 else self._build_stack)
+        with start_span("stack.build", stats=self.stats):
+            upload, nbytes = build(idx, field_name, view, row_id, shards)
+        with start_span("stack.upload", stats=self.stats):
+            arr = upload()
+            # upload holds the host matrix (128 MiB for a dense stack):
+            # let go of it before the eviction work, not after.
+            del upload
+            self._insert_stack(key, epoch, gens, arr, nbytes)
         return arr
 
     def _insert_stack(self, key: tuple, epoch: int, gens: tuple, arr,
@@ -1388,9 +1402,12 @@ class MeshPlanner:
         return jax.default_backend() == "tpu"
 
     def _build_stack(self, idx: Index, field_name: str, view: str,
-                     row_id: int, shards: tuple) -> tuple[jax.Array, int]:
-        """Materialize one row across ``shards`` as a device-put
-        ``[S_pad, W]`` stack. Sparse rows (the common case for bitmap
+                     row_id: int,
+                     shards: tuple) -> tuple[Callable[[], jax.Array], int]:
+        """Materialize one row across ``shards`` on the host (the
+        fragment walk: ``stack.build``) and return (upload, nbytes):
+        ``upload()`` makes the transfer call that yields the device
+        ``[S_pad, W]`` stack (``stack.upload``). Sparse rows (the common case for bitmap
         workloads) ship as COO word triplets and scatter into zeros on
         device — ~8 B/set bit over the link instead of 128 KiB/row —
         when `_sparse_upload_enabled`. Overridden by the distributed
@@ -1405,7 +1422,7 @@ class MeshPlanner:
                                             shard)
                 if frag is not None:
                     mat[i] = frag.row_words(row_id)
-            return jax.device_put(mat, shard_spec(self.mesh)), nbytes
+            return self._put(mat), nbytes
         dense_idx: list[int] = []
         dense_rows: list[np.ndarray] = []
         coo_i: list[np.ndarray] = []
@@ -1442,7 +1459,7 @@ class MeshPlanner:
             mat = np.zeros((s_pad, WORDS_PER_SHARD), dtype=np.uint32)
             for i, row in zip(dense_idx, dense_rows):
                 mat[i] = row
-            return jax.device_put(mat, shard_spec(self.mesh)), nbytes
+            return self._put(mat), nbytes
         # Pad both inputs to pow2 buckets so the assemble program
         # compiles O(log) distinct shapes, not one per leaf; padding
         # lands in a sacrificial trash row the program slices off.
@@ -1466,12 +1483,17 @@ class MeshPlanner:
         # (out_shardings): materializing the whole stack on one device
         # and resharding would spike that device's HBM by the full
         # stack size.
-        arr = self._assemble_jit(didx, dmat, ci, cw, cv, s_pad=s_pad)
-        return arr, nbytes
+        return functools.partial(self._assemble_jit, didx, dmat, ci, cw, cv,
+                                 s_pad=s_pad), nbytes
 
-    def _build_stack_packed(self, idx: Index, field_name: str, view: str,
-                            row_id: int,
-                            shards: tuple) -> tuple[jax.Array, int]:
+    def _put(self, mat: np.ndarray) -> Callable[[], jax.Array]:
+        """The deferred ``device_put`` of a host stack with the shard
+        sharding."""
+        return functools.partial(jax.device_put, mat, shard_spec(self.mesh))
+
+    def _build_stack_packed(
+            self, idx: Index, field_name: str, view: str, row_id: int,
+            shards: tuple) -> tuple[Callable[[], jax.Array], int]:
         """Materialize one low-cardinality row as a packed [S_pad, K]
         int32 stack of sorted in-shard column indices, sentinel-padded
         (exec/residency): K is the pow2 bucket of the largest per-shard
@@ -1498,8 +1520,7 @@ class MeshPlanner:
         mat = np.full((s_pad, k), _residency.SENTINEL, dtype=np.int32)
         for i, pos in rows:
             mat[i, :len(pos)] = pos.astype(np.int32)
-        arr = jax.device_put(mat, shard_spec(self.mesh))
-        return arr, _residency.packed_nbytes(s_pad, k)
+        return self._put(mat), _residency.packed_nbytes(s_pad, k)
 
     def _leaf_stack_specs(self, idx: Index, leaves: list, shards: tuple):
         """Expand leaf descriptors to the (field, view, row_id, class)
@@ -1838,18 +1859,21 @@ class MeshPlanner:
     # compile: signature → jitted evaluator
     # ------------------------------------------------------------------
 
-    def _compiled(self, full_sig: tuple, sig: tuple,
+    def _compiled(self, full_sig: tuple, sig: tuple, n_leaves: int,
                   reduce: str | None) -> Callable:
         """Compile a signature to its jitted program. ``sig`` is the
         caller's already-walked signature — passing it (instead of
         re-walking the tree) keeps the program and the leaf list from
-        ever disagreeing about a leaf's representation class."""
+        ever disagreeing about a leaf's representation class. The
+        program is named ``count_tree_<leaves>`` / ``bitmap_tree_<leaves>``
+        (see _named)."""
         fn = self._fn_cache.get(full_sig)
         if fn is not None:
             return fn
 
         def evaluate(args):
-            return _eval_node(sig, args)
+            with jax.named_scope("tree_eval"):
+                return _eval_node(sig, args)
 
         is_pallas = False
         if reduce == "per_shard":
@@ -1859,12 +1883,16 @@ class MeshPlanner:
                 program = _packed_count_program(sig)
             if program is None:
                 def program(*args):
-                    return bitops.count(evaluate(args))
+                    tree = evaluate(args)
+                    with jax.named_scope("popcount_reduce"):
+                        return bitops.count(tree)
         else:
             def program(*args):
                 return evaluate(args)
 
-        fn = self._jit_program(program, reduce)
+        klass = "count_tree" if reduce == "per_shard" else "bitmap_tree"
+        fn = self._jit_program(_named(program, f"{klass}_{n_leaves}"),
+                               reduce)
         self._fn_cache[full_sig] = fn
         # Pallas kernels are not vmappable: register raw=None so the
         # coalescer falls back to per-entry launches for them.
@@ -1927,6 +1955,16 @@ class MeshPlanner:
         """jit hook: the distributed planner replicates ``per_shard``
         count outputs across the mesh so any process can host-read."""
         return jax.jit(program)
+
+
+def _named(program: Callable, name: str) -> Callable:
+    """Name a device program by its CLASS (and leaf count), never by
+    row ids or a fingerprint: XLA calls the module ``jit_<name>``, which
+    is what a device trace shows, and the module's name is part of the
+    persistent compile cache's key, so the set of compiled programs
+    stays what the structural signatures make it."""
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 def _packed_count_program(sig: tuple):
@@ -2082,29 +2120,17 @@ def _assemble_stack(didx, dmat, ci, cw, cv, s_pad: int):
     return base[:s_pad]
 
 
-@jax.jit
-def _jit_or(a, b):
-    return jnp.bitwise_or(a, b)
-
-
-@jax.jit
-def _jit_and(a, b):
-    return jnp.bitwise_and(a, b)
-
-
-@jax.jit
-def _jit_count(a):
-    return bitops.count(a)
-
-
-@jax.jit
-def _jit_and_count(a, b):
-    return bitops.count(jnp.bitwise_and(a, b))
-
-
-@jax.jit
-def _jit_full_like(a):
-    return jnp.full_like(a, jnp.uint32(0xFFFFFFFF))
+# The stepped helpers, named for the device trace like the fused
+# programs (_named): time-view union, GroupBy's steps, the BSI paths.
+_jit_or = jax.jit(_named(lambda a, b: jnp.bitwise_or(a, b),
+                         "time_views_or"))
+_jit_and = jax.jit(_named(lambda a, b: jnp.bitwise_and(a, b),
+                          "groupby_and"))
+_jit_count = jax.jit(_named(lambda a: bitops.count(a), "groupby_count"))
+_jit_and_count = jax.jit(_named(
+    lambda a, b: bitops.count(jnp.bitwise_and(a, b)), "groupby_and_count"))
+_jit_full_like = jax.jit(_named(
+    lambda a: jnp.full_like(a, jnp.uint32(0xFFFFFFFF)), "bsi_all_ones"))
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "is_min"))

@@ -41,6 +41,7 @@ from collections import deque
 from typing import Callable
 
 from pilosa_tpu.obs.histogram import SECONDS_BOUNDS, LogHistogram
+from pilosa_tpu.obs.tracing import start_span
 
 _MODES = ("on", "off")
 _default_mode = "on"
@@ -140,15 +141,16 @@ class ResidencyPrefetcher:
             ev = self._inflight.get(key)
         if ev is None:
             return False
-        t0 = time.monotonic()
-        done = ev.wait(self.WAIT_TIMEOUT_S)
-        waited = time.monotonic() - t0
+        # A request blocked on an upload in flight (feeds
+        # planner.prefetchWait).
+        with start_span("stack.wait", stats=self.stats) as span:
+            done = ev.wait(self.WAIT_TIMEOUT_S)
         with self._lock:
             self.hits += 1
-            self._waited_s += waited
+            self._waited_s += span.wall
         if self.stats is not None:
             self.stats.count("planner.prefetchHit", 1)
-            self.stats.timing("planner.prefetchWait", waited)
+            self.stats.timing("planner.prefetchWait", span.wall)
         return done
 
     def note_sync_miss(self) -> None:

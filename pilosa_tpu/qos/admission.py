@@ -27,7 +27,7 @@ import threading
 import time
 from collections import deque
 
-from pilosa_tpu.obs import profile as _profile
+from pilosa_tpu.obs.tracing import start_span
 
 from .deadline import Deadline, DeadlineExceededError, current_deadline
 
@@ -292,12 +292,10 @@ class AdmissionController:
     def admit(self, cls: str, deadline: Deadline | None = None):
         if deadline is None:
             deadline = current_deadline()
-        t0 = time.perf_counter()
-        self.acquire(cls, deadline)
-        t1 = time.perf_counter()
-        prof = _profile.current()
-        if prof is not None:
-            prof.add_ms("admissionWaitMs", (t1 - t0) * 1000.0)
+        # The wait for a slot (the profile's admissionWaitMs is fed by
+        # this span).
+        with start_span("qos.admit", stats=self._stats) as wait:
+            self.acquire(cls, deadline)
         try:
             yield
         finally:
@@ -307,7 +305,8 @@ class AdmissionController:
             # latency says nothing about the gate this tunes.
             if self.adaptive is not None and self.max_concurrent > 0 \
                     and normalize_class(cls) != CLASS_INTERNAL:
-                self.adaptive.observe(t1 - t0, time.perf_counter() - t1)
+                self.adaptive.observe(wait.wall,
+                                      time.perf_counter() - wait.end)
 
     # -- observability ------------------------------------------------
 

@@ -43,6 +43,7 @@ from pilosa_tpu.qos import (
     normalize_class,
 )
 from pilosa_tpu.obs import profile as _profile
+from pilosa_tpu.obs.tracing import extract_http_headers, start_span
 from pilosa_tpu.qos import deadline as qos_deadline
 from pilosa_tpu.server.api import API
 from pilosa_tpu.cluster.cluster import ShardUnavailableError
@@ -136,6 +137,26 @@ def _make_handler(api: API):
         def log_message(self, fmt, *args):  # quiet by default
             pass
 
+        # ``http.request``: from the request line having been read (the
+        # stdlib header parse is inside, the keep-alive wait for the next
+        # request is not) to the response written. The outermost span of
+        # a request thread: it names the registry its children fold into.
+        _span = None
+
+        def parse_request(self):
+            self._span = start_span(
+                "http.request", stats=getattr(api.executor, "stats", None))
+            self._span.__enter__()
+            return super().parse_request()
+
+        def handle_one_request(self):
+            try:
+                super().handle_one_request()
+            finally:
+                span, self._span = self._span, None
+                if span is not None:
+                    span.__exit__(None, None, None)
+
         def _dispatch(self, method: str):
             parsed = urlparse(self.path)
             params = {k: v[0] for k, v in parse_qs(parsed.query).items()}
@@ -158,9 +179,9 @@ def _make_handler(api: API):
                     continue
                 headers = None
                 # Join a propagated cross-node trace and deadline.
-                from pilosa_tpu.obs import tracing as _tr
-                tid = _tr.extract_http_headers(self.headers)
-                token = _tr.set_current_trace(tid) if tid else None
+                tid = extract_http_headers(self.headers)
+                if tid:
+                    self._span.join_trace(tid)
                 dl = qos_deadline.extract_http_headers(self.headers)
                 dtoken = (qos_deadline.set_current_deadline(dl)
                           if dl is not None else None)
@@ -226,8 +247,6 @@ def _make_handler(api: API):
                 finally:
                     if dtoken is not None:
                         qos_deadline.reset_current_deadline(dtoken)
-                    if token is not None:
-                        _tr.reset_current_trace(token)
                 return self._reply(status, payload, headers)
             return self._reply(404, {"error": "not found"})
 
@@ -313,25 +332,27 @@ def _make_handler(api: API):
                 server(req)
 
         def _reply(self, status: int, payload, headers=None):
-            if isinstance(payload, (dict, list)):
-                data = (json.dumps(payload) + "\n").encode()
-                ctype = "application/json"
-            elif isinstance(payload, bytes):
-                data = payload
-                ctype = "application/octet-stream"
-            else:
-                data = str(payload).encode()
-                ctype = "text/plain"
-            if headers and "Content-Type" in headers:
-                headers = dict(headers)
-                ctype = headers.pop("Content-Type")
-            self.send_response(status)
-            self.send_header("Content-Type", ctype)
-            self.send_header("Content-Length", str(len(data)))
-            for k, v in (headers or {}).items():
-                self.send_header(k, str(v))
-            self.end_headers()
-            self.wfile.write(data)
+            # JSON encoding and the socket write.
+            with start_span("http.reply"):
+                if isinstance(payload, (dict, list)):
+                    data = (json.dumps(payload) + "\n").encode()
+                    ctype = "application/json"
+                elif isinstance(payload, bytes):
+                    data = payload
+                    ctype = "application/octet-stream"
+                else:
+                    data = str(payload).encode()
+                    ctype = "text/plain"
+                if headers and "Content-Type" in headers:
+                    headers = dict(headers)
+                    ctype = headers.pop("Content-Type")
+                self.send_response(status)
+                self.send_header("Content-Type", ctype)
+                self.send_header("Content-Length", str(len(data)))
+                for k, v in (headers or {}).items():
+                    self.send_header(k, str(v))
+                self.end_headers()
+                self.wfile.write(data)
 
         def do_GET(self):
             self._dispatch("GET")

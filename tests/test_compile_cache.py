@@ -123,6 +123,49 @@ def test_planner_second_boot_reuses_programs(cache_dir):
     assert after["hits"] > before["hits"], (before, after)
 
 
+@pytest.mark.parametrize("first, second, name", [
+    ("Row(f=1)", "Row(g=4)", "count_tree_1"),
+    ("Intersect(Row(f=1), Row(g=2))", "Intersect(Row(f=3), Row(g=4))",
+     "count_tree_2"),
+    ("Union(Row(f=1), Row(g=2), Row(f=3))",
+     "Union(Row(g=4), Row(f=2), Row(g=1))", "count_tree_3"),
+])
+def test_program_named_by_class_not_by_rows(cache_dir, first, second, name):
+    """A device program is named by its class and leaf count only: two
+    plans of one class share one compiled program under one name, and
+    the compile cache sees no new request when only the row ids change
+    (the module's name is part of the persistent cache's key)."""
+    from pilosa_tpu.config import SHARD_WIDTH
+    from pilosa_tpu.core import Holder
+    from pilosa_tpu.parallel import MeshPlanner, make_mesh
+    from pilosa_tpu.pql import parse
+
+    h = Holder()
+    idx = h.create_index("i")
+    for fld in ("f", "g"):
+        field = idx.create_field(fld)
+        for row in (1, 2, 3, 4):
+            field.import_bits([row] * 6,
+                              [s * SHARD_WIDTH + 5 for s in range(6)])
+    planner = MeshPlanner(h, make_mesh())
+    shards = list(range(6))
+    try:
+        def run(pql):
+            tree = parse(f"Count({pql})").calls[0].children[0]
+            fn, arrays = planner.prepare_count(idx, tree, shards)
+            return fn, arrays, planner.dispatch_count(fn, arrays).result()
+
+        fn1, arrays, n1 = run(first)
+        before = compile_cache.stats()["requests"]
+        fn2, _, n2 = run(second)
+        assert fn2 is fn1 and fn1.__name__ == name
+        assert compile_cache.stats()["requests"] == before
+        assert n1 == n2 == 6
+        assert f"module @jit_{name}" in fn1.lower(*arrays).as_text()
+    finally:
+        planner.close()
+
+
 # ------------------------------------------------------------------
 # where the cache lives: placed from outside, or one fixed path
 # ------------------------------------------------------------------

@@ -15,6 +15,7 @@ from pilosa_tpu.obs import (
     set_tracer,
     start_span,
 )
+from pilosa_tpu.obs import tracing
 from pilosa_tpu.obs.tracing import NopTracer
 
 
@@ -636,3 +637,320 @@ def test_debug_device_route_and_dispatch_profile():
         assert "wave_width_hist" in out["transfer"]
     finally:
         n.close()
+
+
+# -- the span primitive (ISSUE 28) -------------------------------------------
+
+#: the spans a warm, cached-path Count enters, with each one's parent.
+_COUNT_PATH = {
+    "http.request": None,
+    "qos.admit": "http.request",
+    "exec.parse": "http.request",
+    "exec.cache": "http.request",
+    "Executor.executeCount": "http.request",
+    "plan.prepare": "Executor.executeCount",
+    "stack.fetch": "Executor.executeCount",
+    "dispatch.launch": "Executor.executeCount",
+    "transfer.wait": "Executor.executeCount",
+    "http.reply": "http.request",
+}
+#: of those, the ones that close while the request's QueryProfile is active.
+_IN_PROFILE = set(_COUNT_PATH) - {"http.request", "http.reply"}
+
+
+def _burn(n):
+    x = 0
+    for i in range(n):
+        x += i * i
+    return x
+
+
+def test_span_nesting_parents_and_self_cpu(monkeypatch):
+    """Nesting gives parent ids; a span's self CPU is its inclusive CPU
+    less its children's, so the selves of a tree sum to the root's
+    inclusive CPU."""
+    monkeypatch.setattr(tracing, "CPU_SAMPLE_EVERY", 1)
+    stats = MemoryStats()
+    t = SimpleTracer()
+    set_tracer(t)
+    try:
+        with start_span("t.root", stats=stats):
+            _burn(60_000)
+            with start_span("t.kid"):
+                _burn(60_000)
+                with start_span("t.leaf"):
+                    _burn(60_000)
+            with start_span("t.kid"):
+                _burn(20_000)
+    finally:
+        set_tracer(NopTracer())
+    by_op = {}
+    for s in t.spans:
+        by_op.setdefault(s.operation, []).append(s)
+    root, = by_op["t.root"]
+    assert root.parent_id is None
+    assert [s.parent_id for s in by_op["t.kid"]] == [root.span_id] * 2
+    assert by_op["t.leaf"][0].parent_id == by_op["t.kid"][0].span_id
+    assert len({s.tags["trace.id"] for s in t.spans}) == 1
+
+    def c(name, field):
+        return stats.counter_value(f"span.{name}.{field}")
+
+    assert c("t.root", "count") == 1 and c("t.kid", "count") == 2
+    for name in ("t.root", "t.kid", "t.leaf"):
+        assert 0 <= c(name, "selfCpuSeconds") <= c(name, "cpuSeconds")
+        assert c(name, "cpuSeconds") <= c(name, "wallSeconds") + 1e-3
+    assert c("t.leaf", "selfCpuSeconds") == c("t.leaf", "cpuSeconds")
+    # children's inclusive CPU = the parent's inclusive less its own ...
+    assert abs(c("t.kid", "cpuSeconds")
+               - (c("t.root", "cpuSeconds") - c("t.root", "selfCpuSeconds"))
+               ) < 1e-9
+    # ... so the selves of the whole tree sum to the root's inclusive.
+    selves = sum(c(n, "selfCpuSeconds") for n in ("t.root", "t.kid",
+                                                  "t.leaf"))
+    assert abs(selves - c("t.root", "cpuSeconds")) < 1e-9
+    assert c("t.root", "selfCpuSeconds") > 0
+
+
+def _span_node(**kw):
+    """A planner node with two 2-row fields, warmed so that every stack
+    is resident and every program compiled; (node, post, get)."""
+    import json
+    from pilosa_tpu.server.node import ServerNode
+
+    n = ServerNode(bind="127.0.0.1:0", **kw)
+    n.open()
+
+    def post(path, body="", headers=None):
+        r = urllib.request.Request(n.address + path, data=body.encode(),
+                                   method="POST", headers=headers or {})
+        return json.loads(urllib.request.urlopen(r, timeout=30).read()
+                          or b"{}")
+
+    def get(path):
+        return json.loads(urllib.request.urlopen(
+            n.address + path, timeout=10).read())
+
+    post("/index/sp", "{}")
+    for fld in ("f", "g"):
+        post(f"/index/sp/field/{fld}", "{}")
+        for c in range(8):
+            post("/index/sp/query", f"Set({c}, {fld}={c % 2})")
+    for r in (0, 1):
+        post("/index/sp/query?noCache=true",
+             f"Count(Intersect(Row(f={r}), Row(g={r})))")
+    return n, post, get
+
+
+def _span_counts(get, at_least=None, timeout=5.0):
+    """span.<name>.count of /debug/vars; polls until ``at_least`` holds
+    (a request thread folds its ledger after the response is written)."""
+    import time
+    deadline = time.monotonic() + timeout
+    while True:
+        counters = get("/debug/vars")["counters"]
+        out = {k[len("span."):-len(".count")]: v for k, v in counters.items()
+               if k.startswith("span.") and k.endswith(".count")}
+        if at_least is None or all(out.get(k, 0) >= v
+                                   for k, v in at_least.items()) \
+                or time.monotonic() > deadline:
+            return out
+        time.sleep(0.01)
+
+
+def _want(before, sent):
+    return {k: before.get(k, 0) + sent for k in _COUNT_PATH}
+
+
+def _check_counts(before, after, sent):
+    """Every span of the path counted once a request; the two HTTP spans
+    also count the /debug/vars reads between the two readings."""
+    for k in _COUNT_PATH:
+        moved = after.get(k, 0) - before.get(k, 0)
+        if k.startswith("http."):
+            assert moved >= sent + 1, (k, moved)
+        else:
+            assert moved == sent, (k, moved)
+    assert after["http.request"] - before.get("http.request", 0) >= \
+        after["http.reply"] - before.get("http.reply", 0)
+
+
+def test_cpu_is_read_for_one_tree_in_n_and_scaled(monkeypatch):
+    """The thread-CPU clock is read for one span tree in
+    CPU_SAMPLE_EVERY (drawn at the outermost span; every span of the tree
+    follows it) and counted that many times over, so the counters
+    estimate the whole; wall time and counts are exact for every tree."""
+    import time
+    draws = iter([0.0, 0.9, 0.5, 0.26] * 5)      # every fourth tree
+    monkeypatch.setattr(tracing, "CPU_SAMPLE_EVERY", 4)
+    monkeypatch.setattr(tracing, "_draw", lambda: next(draws))
+    stats = MemoryStats()
+    reads = []
+    real = time.thread_time
+    monkeypatch.setattr(time, "thread_time",
+                        lambda: reads.append(1) or real())
+    c0 = real()
+    for _ in range(20):
+        with start_span("s.root", stats=stats):
+            with start_span("s.kid"):
+                _burn(20_000)
+    burned = real() - c0
+    monkeypatch.undo()
+    assert len(reads) == 5 * 2 * 2      # 5 sampled trees x 2 spans x 2 ends
+    assert stats.counter_value("span.s.root.count") == 20
+    assert stats.counter_value("span.s.kid.count") == 20
+    est = stats.counter_value("span.s.kid.cpuSeconds")
+    assert 0.6 * burned < est < 1.4 * burned, (est, burned)
+    assert stats.counter_value("span.s.root.cpuSeconds") >= est
+
+
+def test_served_request_leaves_every_span_once(monkeypatch):
+    """A Count through the HTTP handler: every span of its path once,
+    under one (propagated) trace id, parents set, in the tracer; the same
+    names as span.* counters whose counts equal the requests sent; those
+    that close inside the profile in /debug/queries/<id>."""
+    monkeypatch.setattr(tracing, "CPU_SAMPLE_EVERY", 1)
+    n, post, get = _span_node()
+    t = SimpleTracer()
+    try:
+        before = _span_counts(get, at_least={"http.request": 1})
+        set_tracer(t)
+        sent = 3
+        for i in range(sent):
+            resp = post("/index/sp/query",
+                        f"Count(Union(Row(f={i % 2}), Row(g={i // 2})))",
+                        headers={"X-Pilosa-Trace-Id": f"tr-obs-{i}"})
+            assert resp["results"][0] >= 4  # distinct: none is a cache hit
+        set_tracer(NopTracer())
+        after = _span_counts(get, at_least=_want(before, sent))
+        _check_counts(before, after, sent)
+        for i in range(sent):
+            mine = [s for s in t.spans
+                    if s.tags.get("trace.id") == f"tr-obs-{i}"]
+            assert sorted(s.operation for s in mine) == sorted(_COUNT_PATH)
+            by_op = {s.operation: s for s in mine}
+            for op, parent in _COUNT_PATH.items():
+                want_pid = by_op[parent].span_id if parent else None
+                assert by_op[op].parent_id == want_pid, op
+            doc = get(f"/debug/queries/tr-obs-{i}")
+            assert set(doc["spans"]) == _IN_PROFILE
+            assert all(e["count"] == 1 and e["selfCpuMs"] <= e["cpuMs"]
+                       for e in doc["spans"].values())
+            # the three phases the spans feed still appear, and the
+            # launch is host time under its own name
+            for phase, span in (("parseMs", "exec.parse"),
+                                ("cacheLookupMs", "exec.cache"),
+                                ("admissionWaitMs", "qos.admit")):
+                assert abs(doc["timings"][phase]
+                           - doc["spans"][span]["wallMs"]) < 1e-3
+            assert abs(doc["dispatch"]["launchMs"]
+                       - doc["spans"]["dispatch.launch"]["wallMs"]) < 1e-3
+            assert "deviceMs" not in doc["dispatch"]
+    finally:
+        set_tracer(NopTracer())
+        n.close()
+
+
+def test_profile_off_counts_the_same_spans():
+    """profile_ring_n=0, profile_queries=False: no QueryProfile is built
+    (the ctor is boobytrapped) and the span counters move all the same."""
+    from pilosa_tpu.obs import profile as _profile
+    n, post, get = _span_node(profile_ring_n=0, profile_queries=False)
+    orig = _profile.QueryProfile.__init__
+    try:
+        before = _span_counts(get, at_least={"http.request": 1})
+
+        def boom(self, *a, **k):
+            raise AssertionError("QueryProfile built on the off path")
+        _profile.QueryProfile.__init__ = boom
+        assert post("/index/sp/query",
+                    "Count(Union(Row(f=0), Row(g=1)))")["results"] == [8]
+        _profile.QueryProfile.__init__ = orig
+        after = _span_counts(get, at_least=_want(before, 1))
+        _check_counts(before, after, 1)
+    finally:
+        _profile.QueryProfile.__init__ = orig
+        n.close()
+
+
+def test_prefetch_worker_span_reaches_its_planners_registry_only():
+    """stack.build/stack.upload run on a prefetch worker: the outermost
+    span of that thread folds into ITS planner's registry, and a second
+    planner in the process sees none of it."""
+    import numpy as np
+    from pilosa_tpu.config import SHARD_WIDTH
+    from pilosa_tpu.parallel import MeshPlanner, make_mesh
+
+    mesh = make_mesh()
+    h = Holder()
+    idx = h.create_index("pw")
+    f = idx.create_field("f")
+    f.import_bits(np.full(64, 1), np.arange(64) * (SHARD_WIDTH // 16))
+    mine, other = MemoryStats(), MemoryStats()
+    p_mine = MeshPlanner(h, mesh, stats=mine)
+    p_other = MeshPlanner(h, mesh, stats=other)
+    try:
+        e = Executor(h, planner=p_mine, result_cache=False, stats=mine)
+        assert e.execute("pw", "Count(Row(f=1))", shards=[0, 1, 2, 3]) == [64]
+        dbg = p_mine.prefetcher.debug()
+        assert dbg["completed"] >= 1 and dbg["sync_misses"] == 0
+        for name in ("stack.build", "stack.upload"):
+            assert mine.counter_value(f"span.{name}.count") == \
+                dbg["completed"], name
+            assert mine.counter_value(f"span.{name}.wallSeconds") > 0
+        # the request's thread waited for the upload in flight
+        assert mine.counter_value("span.stack.wait.count") >= 1
+        assert mine.counter_value("span.stack.fetch.wallSeconds") >= \
+            mine.counter_value("span.stack.wait.wallSeconds")
+        assert not [k for k in other.counters if k[0].startswith("span.")]
+    finally:
+        p_mine.close()
+        p_other.close()
+
+
+def test_device_trace_holds_the_spans_with_the_trace_id(tmp_path):
+    """With a jax.profiler session open, the spans lie on the host plane
+    of the .xplane.pb (the device trace's clock): dispatch.launch carries
+    the request's trace_id, inside http.request on the same thread."""
+    import glob
+    import http.client
+    import jax
+
+    n, _, _ = _span_node()
+    try:
+        # One keep-alive connection is one handler thread: when the
+        # second answer is here, the first request's http.request has
+        # closed (it closes after its response is written, so a client
+        # that only reads the first answer races stop_trace).
+        conn = http.client.HTTPConnection(
+            n.address.split("//")[1], timeout=30)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for tid, pql in (("tr-xplane-1",
+                              "Count(Union(Row(f=1), Row(g=0)))"),
+                             ("tr-xplane-2", "Count(Row(f=1))")):
+                conn.request("POST", "/index/sp/query?noCache=true",
+                             body=pql, headers={"X-Pilosa-Trace-Id": tid})
+                assert conn.getresponse().read()
+        finally:
+            jax.profiler.stop_trace()
+            conn.close()
+    finally:
+        n.close()
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in _COUNT_PATH and \
+                        dict(ev.stats).get("trace_id") == "tr-xplane-1":
+                    found[ev.name] = (line.name, ev.start_ns,
+                                      ev.start_ns + ev.duration_ns)
+    assert set(found) >= {"http.request", "plan.prepare", "dispatch.launch",
+                          "transfer.wait"}
+    thread, lo, hi = found["http.request"]
+    for name in ("plan.prepare", "dispatch.launch", "transfer.wait"):
+        assert found[name][0] == thread
+        assert lo <= found[name][1] and found[name][2] <= hi, name

@@ -126,7 +126,7 @@ class Plan:
         tree = parse(pql).calls[0].children[0]
         leaves = []
         sig = self.planner._signature(self.idx, tree, leaves, self.shards)
-        fn = self.planner._compiled(("count",) + sig, sig,
+        fn = self.planner._compiled(("count",) + sig, sig, len(leaves),
                                     reduce="per_shard")
         return fn, self.leaf_shapes(leaves)
 
@@ -134,7 +134,8 @@ class Plan:
         tree = parse(pql).calls[0]
         leaves = []
         sig = self.planner._signature(self.idx, tree, leaves, self.shards)
-        fn = self.planner._compiled(("row",) + sig, sig, reduce=None)
+        fn = self.planner._compiled(("row",) + sig, sig, len(leaves),
+                                    reduce=None)
         return fn, self.leaf_shapes(leaves)
 
     def sum(self, pql):
