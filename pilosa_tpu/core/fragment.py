@@ -440,23 +440,30 @@ class Fragment:
         hr = self.rows.get(row_id)
         return 0 if hr is None else hr.count()
 
-    def row_upload(self, row_id: int):
-        """Cheapest faithful host form for a device upload:
-        ``("dense", uint32[W])`` or ``("sparse", uint64[positions])``
-        (positions sorted, deduped). Sparse rows let the planner ship
-        ~8B/set-bit COO triplets instead of the 128 KiB dense block —
-        the difference IS the query rate when leaves page over a
-        bandwidth-bound link (planner sparse-upload path)."""
+    def row_words_into(self, row_id: int, out: np.ndarray) -> str | None:
+        """Write one row's dense block into ``out``, a zeroed uint32[W]
+        row of a stack matrix the caller owns, under this fragment's
+        lock (pending single-bit adds are flushed first). Returns the
+        route `HostRow.words_into` took, or None for an absent or empty
+        row, which leaves ``out`` as it is. This call, 954 times a
+        stack, is the pace of a cold or oversubscribed query: 0.05 s a
+        stack against 0.001 s for the stack's transfer call (PERF.md,
+        PR 29, `count-trees-oversub`)."""
+        with self._lock:
+            hr = self.rows.get(row_id)
+            if hr is None or hr.n == 0:
+                return None
+            return hr.words_into(out)
+
+    def row_positions(self, row_id: int) -> np.ndarray:
+        """Sorted uint64 in-shard positions of one row (empty if
+        absent), the caller's own copy: what the planner's COO and
+        packed uploads ship."""
         with self._lock:
             hr = self.rows.get(row_id)
             if hr is None:
-                return ("sparse", np.empty(0, dtype=np.uint64))
-            if hr.is_dense:
-                return ("dense", hr.dense.copy())
-            hr._flush()
-            if hr.dense is not None:  # flush may densify
-                return ("dense", hr.dense.copy())
-            return ("sparse", hr.positions.copy())
+                return np.empty(0, dtype=np.uint64)
+            return hr.to_positions()
 
     def rows_snapshot(self) -> list[tuple[int, np.ndarray]]:
         """Atomic [(row_id, positions)] snapshot of every row, sorted by

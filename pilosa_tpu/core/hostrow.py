@@ -230,6 +230,23 @@ class HostRow:
         self._flush()
         return bitops.positions_to_words(self.positions)
 
+    def words_into(self, out: np.ndarray) -> str:
+        """Write the dense block into ``out``, a ZEROED uint32[W] row of
+        the caller's matrix (a stack build), and say by which route:
+        ``"copied"`` (dense words, one copy), ``"scattered"`` (positions
+        ORed in by the native library, the interpreter lock let go) or
+        ``"numpy"`` (the same through bitops, no native library). What
+        `to_words` returns, without its block."""
+        from pilosa_tpu import native
+        if self.dense is None:
+            self._flush()  # may densify
+        if self.dense is not None:
+            np.copyto(out, self.dense)
+            return "copied"
+        if native.or_positions_into(self.positions, out):
+            return "scattered"
+        return "numpy"
+
     def to_positions(self) -> np.ndarray:
         if self.dense is not None:
             return bitops.words_to_positions(self.dense)

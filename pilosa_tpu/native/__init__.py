@@ -275,15 +275,23 @@ def encode_roaring(positions: np.ndarray) -> bytes:
     return out[:n].tobytes()
 
 
-def positions_to_words(positions: np.ndarray, n_words: int) -> np.ndarray:
-    positions = np.ascontiguousarray(positions, dtype=np.uint64)
+def or_positions_into(positions: np.ndarray, words: np.ndarray) -> bool:
+    """OR uint64 bit ``positions`` into ``words``, a C-contiguous uint32
+    buffer the caller owns (a row of a stack matrix): no copy of the
+    positions, no temporary block. ctypes lets go of the interpreter
+    lock for the call, so builders on several threads overlap. True when
+    the native library did it; False, with the same bits set through
+    ``bitops``, when there is none."""
     lib = _load()
     if lib is None:
         from pilosa_tpu.ops import bitops
-        return bitops.positions_to_words(positions, n_words)
-    words = np.zeros(n_words, dtype=np.uint32)
-    lib.positions_to_words(positions, len(positions), words, n_words)
-    return words
+        np.bitwise_or(words, bitops.positions_to_words(positions, len(words)),
+                      out=words)
+        return False
+    # ndpointer refuses another dtype or a strided buffer: the native
+    # loop is never handed memory it would misread.
+    lib.positions_to_words(positions, len(positions), words, len(words))
+    return True
 
 
 def words_to_positions(words: np.ndarray) -> np.ndarray:

@@ -231,7 +231,7 @@ class DistributedMeshPlanner(MeshPlanner):
                 frag = self.holder.fragment(idx.name, field_name, view,
                                             shard)
                 if frag is not None:
-                    block[i - lo] = frag.row_words(row_id)
+                    frag.row_words_into(row_id, block[i - lo])
             blocks.append((block, dev))
 
         def upload():

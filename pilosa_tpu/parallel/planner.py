@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import functools
 import threading
-from collections import OrderedDict, deque
+from collections import Counter, OrderedDict, deque
 from typing import Any, Callable
 
 import jax
@@ -41,6 +41,7 @@ from pilosa_tpu.errors import (
     FieldNotFoundError,
     QueryError,
 )
+from pilosa_tpu import native
 from pilosa_tpu.exec import fuse as _fuse
 from pilosa_tpu.exec import residency as _residency
 from pilosa_tpu.obs import profile as _profile
@@ -1346,7 +1347,10 @@ class MeshPlanner:
         with start_span("stack.upload", stats=self.stats):
             arr = upload()
             # upload holds the host matrix (128 MiB for a dense stack):
-            # let go of it before the eviction work, not after.
+            # let go of it before the eviction work, not after. The
+            # runtime keeps its own reference until the transfer has
+            # read the matrix; only then does the chunk go back to the
+            # page pool (scripts/stack_readback_check.py).
             del upload
             self._insert_stack(key, epoch, gens, arr, nbytes)
         return arr
@@ -1388,10 +1392,12 @@ class MeshPlanner:
                 self.stats.gauge(f"planner.residentBytes.{k}", v)
 
     #: rows with at most this many set bits upload as COO triplets
-    #: (~12 B/word touched) instead of the 128 KiB dense block; where
-    #: host->device bandwidth binds, the upload size IS the
-    #: cold/oversubscribed query rate. The threshold is not measured on
-    #: the current machine.
+    #: (~12 B/word touched) instead of the 128 KiB dense block. The
+    #: threshold is not measured on the current machine. What is
+    #: (PERF.md, PR 28 and 29, `count-trees-oversub`): the transfer call
+    #: of a 128 MiB stack returns in 0.001-0.003 s, and the host build
+    #: before it, not the bytes on the link, set the cold and
+    #: oversubscribed query rate (0.43 s a stack, 0.05 s built in place).
     SPARSE_UPLOAD_MAX_BITS = 2048
 
     def _sparse_upload_enabled(self) -> bool:
@@ -1407,71 +1413,67 @@ class MeshPlanner:
         """Materialize one row across ``shards`` on the host (the
         fragment walk: ``stack.build``) and return (upload, nbytes):
         ``upload()`` makes the transfer call that yields the device
-        ``[S_pad, W]`` stack (``stack.upload``). Sparse rows (the common case for bitmap
-        workloads) ship as COO word triplets and scatter into zeros on
-        device — ~8 B/set bit over the link instead of 128 KiB/row —
-        when `_sparse_upload_enabled`. Overridden by the distributed
-        planner to assemble a global array from each process's local
-        fragment rows (jax.make_array_from_single_device_arrays)."""
+        ``[S_pad, W]`` stack (``stack.upload``). The stack is built in
+        place: one zeroed host matrix from the recycled page pool, each
+        fragment writing its row straight into ``mat[i]`` under its own
+        lock (`Fragment.row_words_into`). The route of a row is chosen
+        from its O(1) cardinality before anything is materialized: with
+        `_sparse_upload_enabled`, rows of at most
+        `SPARSE_UPLOAD_MAX_BITS` ship as COO word triplets and scatter
+        into zeros on device. ``planner.stackRows.<route>`` counts the
+        non-empty rows by route (scattered, copied, numpy, coo).
+        Overridden by the distributed planner to assemble a global
+        array from each process's local fragment rows
+        (jax.make_array_from_single_device_arrays)."""
         s_pad = self._pad(len(shards))
         nbytes = _residency.dense_nbytes(s_pad)  # HBM-resident size
-        if not self._sparse_upload_enabled():
-            mat = np.zeros((s_pad, WORDS_PER_SHARD), dtype=np.uint32)
-            for i, shard in enumerate(shards):
-                frag = self.holder.fragment(idx.name, field_name, view,
-                                            shard)
-                if frag is not None:
-                    mat[i] = frag.row_words(row_id)
-            return self._put(mat), nbytes
-        dense_idx: list[int] = []
-        dense_rows: list[np.ndarray] = []
-        coo_i: list[np.ndarray] = []
-        coo_w: list[np.ndarray] = []
-        coo_v: list[np.ndarray] = []
+        coo_max = (self.SPARSE_UPLOAD_MAX_BITS
+                   if self._sparse_upload_enabled() else 0)
+        blocks: list[tuple] = []  # (i, fragment): rows that take 128 KiB
+        coo: list[tuple] = []
         for i, shard in enumerate(shards):
             frag = self.holder.fragment(idx.name, field_name, view, shard)
-            if frag is None:
-                continue
-            kind, payload = frag.row_upload(row_id)
-            if kind == "sparse" and len(payload) == 0:
-                continue
-            if (kind == "sparse"
-                    and len(payload) <= self.SPARSE_UPLOAD_MAX_BITS):
-                w = (payload >> np.uint64(5)).astype(np.int32)
-                b = (np.uint32(1)
-                     << (payload & np.uint64(31)).astype(np.uint32))
-                # positions are sorted, so equal words are adjacent:
-                # one reduceat OR per distinct word.
-                starts = np.flatnonzero(
-                    np.diff(w, prepend=np.int32(-1)) != 0)
-                coo_i.append(np.full(len(starts), i, dtype=np.int32))
-                coo_w.append(w[starts])
-                coo_v.append(np.bitwise_or.reduceat(b, starts))
-            else:
-                dense_idx.append(i)
-                dense_rows.append(payload if kind == "dense" else
-                                  bitops.positions_to_words(payload))
-        nnz = sum(len(x) for x in coo_i)
-        if nnz == 0:
-            # No sparse rows to scatter: the plain host-sliced
-            # device_put beats shipping the same bytes through the
-            # assemble program (and pays no extra copies).
-            mat = np.zeros((s_pad, WORDS_PER_SHARD), dtype=np.uint32)
-            for i, row in zip(dense_idx, dense_rows):
-                mat[i] = row
-            return self._put(mat), nbytes
-        # Pad both inputs to pow2 buckets so the assemble program
+            n = 0 if frag is None else frag.row_cardinality(row_id)
+            if n:
+                (coo if n <= coo_max else blocks).append((i, frag))
+        # Pad the assemble program's inputs to pow2 buckets so that it
         # compiles O(log) distinct shapes, not one per leaf; padding
         # lands in a sacrificial trash row the program slices off.
         def bucket(n: int) -> int:
             return 0 if n == 0 else max(8, 1 << (n - 1).bit_length())
 
-        d_pad = bucket(len(dense_idx))
-        didx = np.full(d_pad, s_pad, dtype=np.int32)
-        dmat = np.zeros((d_pad, WORDS_PER_SHARD), dtype=np.uint32)
-        didx[:len(dense_idx)] = dense_idx
-        for k, row in enumerate(dense_rows):
-            dmat[k] = row
+        # No sparse rows to scatter: the matrix is the stack, row i at
+        # mat[i], and the plain host-sliced device_put beats shipping
+        # the same bytes through the assemble program. Otherwise the
+        # 128 KiB rows go packed: mat[k] is stack row didx[k].
+        mat = _host_zeros(bucket(len(blocks)) if coo else s_pad)
+        routes = Counter(coo=len(coo))
+        for k, (i, frag) in enumerate(blocks):
+            routes[frag.row_words_into(row_id, mat[k if coo else i])] += 1
+        if self.stats is not None:
+            # One count per route and build, not per shard. A row
+            # emptied since its cardinality was read came back as None.
+            for route, n in routes.items():
+                if route is not None and n:
+                    self.stats.count(f"planner.stackRows.{route}", n)
+        if not coo:
+            return self._put(mat), nbytes
+        didx = np.full(len(mat), s_pad, dtype=np.int32)
+        didx[:len(blocks)] = [i for i, _ in blocks]
+        coo_i: list[np.ndarray] = []
+        coo_w: list[np.ndarray] = []
+        coo_v: list[np.ndarray] = []
+        for i, frag in coo:
+            pos = frag.row_positions(row_id)
+            w = (pos >> np.uint64(5)).astype(np.int32)
+            b = np.uint32(1) << (pos & np.uint64(31)).astype(np.uint32)
+            # positions are sorted, so equal words are adjacent:
+            # one reduceat OR per distinct word.
+            starts = np.flatnonzero(np.diff(w, prepend=np.int32(-1)) != 0)
+            coo_i.append(np.full(len(starts), i, dtype=np.int32))
+            coo_w.append(w[starts])
+            coo_v.append(np.bitwise_or.reduceat(b, starts))
+        nnz = sum(len(x) for x in coo_i)
         n_pad = bucket(nnz)
         ci = np.full(n_pad, s_pad, dtype=np.int32)
         cw = np.zeros(n_pad, dtype=np.int32)
@@ -1483,7 +1485,7 @@ class MeshPlanner:
         # (out_shardings): materializing the whole stack on one device
         # and resharding would spike that device's HBM by the full
         # stack size.
-        return functools.partial(self._assemble_jit, didx, dmat, ci, cw, cv,
+        return functools.partial(self._assemble_jit, didx, mat, ci, cw, cv,
                                  s_pad=s_pad), nbytes
 
     def _put(self, mat: np.ndarray) -> Callable[[], jax.Array]:
@@ -1509,9 +1511,7 @@ class MeshPlanner:
             frag = self.holder.fragment(idx.name, field_name, view, shard)
             if frag is None:
                 continue
-            kind, payload = frag.row_upload(row_id)
-            pos = (bitops.words_to_positions(payload) if kind == "dense"
-                   else payload)
+            pos = frag.row_positions(row_id)
             if len(pos):
                 rows.append((i, pos))
                 if len(pos) > max_bits:
@@ -2104,6 +2104,19 @@ def _copy_async(*arrays) -> None:
             a.copy_to_host_async()
         except (AttributeError, RuntimeError):  # non-jax array / backend
             pass
+
+
+def _host_zeros(rows: int) -> np.ndarray:
+    """Zeroed ``[rows, W]`` uint32 host matrix for a stack build, from
+    the recycled page pool where there is one: a fresh 128 MiB
+    allocation pays a first-touch fault on every page it writes
+    (native/roaring_codec.cpp, "recycled page pool"). The chunk goes
+    back to the pool when the last reference is collected, and the
+    runtime holds one until the transfer has read the matrix."""
+    mat = native.pool_zeros((rows, WORDS_PER_SHARD), np.uint32)
+    if mat is None:
+        mat = np.zeros((rows, WORDS_PER_SHARD), dtype=np.uint32)
+    return mat
 
 
 def _assemble_stack(didx, dmat, ci, cw, cv, s_pad: int):

@@ -22,6 +22,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
@@ -279,6 +280,60 @@ def test_sparse_upload_assemble_compiles(plan1):
         arg((4096,), jnp.int32), arg((4096,), jnp.int32),
         arg((4096,), jnp.uint32), s_pad=S_PAD).compile()
     assert c.memory_analysis().output_size_in_bytes == S_PAD * W * 4
+
+
+def test_sparse_upload_route_of_rows_over_the_coo_threshold(plan1,
+                                                           monkeypatch):
+    """The TPU-only branch with real rows at the real shard count. Rows
+    over SPARSE_UPLOAD_MAX_BITS in every shard (what both benchmark
+    cells hold): no COO, the stack is ONE [S_PAD, W] host matrix built
+    in place and handed to device_put with the shard sharding. One
+    shard under the threshold turns the same stack into the assemble
+    program with 953 rows written into dmat[k], bucketed to 1,024: that
+    one has to compile for the chip and fit beside a full budget."""
+    h = Holder()
+    idx = h.create_index("i")
+    f = idx.create_field("f")
+    rng = np.random.default_rng(7)
+    over = planner_mod.MeshPlanner.SPARSE_UPLOAD_MAX_BITS + 52
+    cols = np.concatenate([
+        rng.choice(SHARD_WIDTH, over, replace=False) + s * SHARD_WIDTH
+        for s in range(N_SHARDS)])
+    f.import_bits(np.ones(len(cols), dtype=np.uint64), cols)
+    planner = MeshPlanner(h, plan1.mesh)
+    monkeypatch.setattr(planner, "_sparse_upload_enabled", lambda: True)
+    try:
+        upload, nbytes = planner._build_stack(idx, "f", "standard", 1,
+                                              plan1.shards)
+        assert upload.func is jax.device_put and nbytes == S_PAD * W * 4
+        mat, sharding = upload.args
+        assert mat.shape == (S_PAD, W) and mat.dtype == np.uint32
+        assert sharding == shard_spec(plan1.mesh)
+        for i in (0, 500, N_SHARDS - 1):
+            frag = h.fragment("i", "f", "standard", i)
+            assert np.array_equal(mat[i], frag.row_words(1))
+        assert not mat[N_SHARDS:].any()
+
+        frag = h.fragment("i", "f", "standard", 17)
+        frag.clear_row(1)
+        f.set_bit(1, 17 * SHARD_WIDTH + 3)
+        upload, _ = planner._build_stack(idx, "f", "standard", 1,
+                                         plan1.shards)
+        assert upload.func is planner._assemble_jit
+        didx, dmat, ci, cw, cv = upload.args
+        assert dmat.shape == (S_PAD, W) and 17 not in didx
+        assert (ci[0], cw[0], cv[0]) == (17, 0, 8) and len(ci) == 8
+        rep = plan1.replicated
+        c = planner._assemble_jit.lower(
+            *(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep)
+              for a in upload.args), **upload.keywords).compile()
+        ma = c.memory_analysis()
+        assert ma.output_size_in_bytes == S_PAD * W * 4
+        total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                 + ma.temp_size_in_bytes)
+        assert total + (4 << 30) < 15 << 30, f"assemble needs {total} B"
+    finally:
+        planner.close()
 
 
 def test_coalesced_vmap_wave_compiles(plan1):
