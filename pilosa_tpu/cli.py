@@ -80,8 +80,9 @@ _DEFAULTS = {
     "qos_warmup": "count,topn,bsi",
     "qos_warmup_shards": "1,8,32",
     # Overload resilience. Adaptive concurrency: qos_max_concurrent is
-    # the CEILING; the operative limit is measured (AIMD over admitted
-    # queue-wait/latency). Per-tenant token buckets (req/s per API key
+    # the CEILING; the operative limit follows goodput, the completions
+    # per second at that limit (qos/adaptive.py): a queue alone is
+    # demand, not congestion. Per-tenant token buckets (req/s per API key
     # or index; 0 disables; rejections are 429 + Retry-After, distinct
     # from the gate's 503 shed).
     "qos_adaptive": True,
@@ -780,7 +781,10 @@ def cmd_generate_config(args) -> int:
           'qos-warmup = "count,topn,bsi"\n'
           'qos-warmup-shards = "1,8,32"\n'
           '# adaptive concurrency: qos-max-concurrent is the ceiling,\n'
-          '# the operative limit is measured (AIMD)\n'
+          '# the operative limit follows goodput (completions per second\n'
+          '# at that limit): a queue lets it probe up, a slot is kept only\n'
+          '# if it bought goodput, and of limits that serve alike the\n'
+          '# lowest wins\n'
           'qos-adaptive = true\n'
           '# per-tenant token bucket, requests/s per API key or index\n'
           '# (0 disables; rejections are 429 + Retry-After)\n'

@@ -239,18 +239,19 @@ class AdmissionController:
         depth = self._queued()
         return min(30.0, max(1.0, round(depth / self.max_concurrent + 0.5)))
 
-    def acquire(self, cls: str, deadline: Deadline | None = None) -> None:
+    def acquire(self, cls: str, deadline: Deadline | None = None) -> bool:
+        """Take a slot; True when the request had to queue for it."""
         cls = normalize_class(cls)
         if self.max_concurrent <= 0:
             self._count("qos.admitted", cls)
             self._admitted_total += 1
-            return
+            return False
         t0 = time.perf_counter()
         with self._cv:
             if self._active < self._limit_for(cls) and not self._queues[cls]:
                 self._active += 1
                 self._admit_metrics(cls, t0)
-                return
+                return False
             if self._queued() >= self.max_queue:
                 self._shed_total += 1
                 self._count("qos.shed", cls)
@@ -280,13 +281,17 @@ class AdmissionController:
                     self._count("qos.deadlineMiss", cls)
                 raise
             self._admit_metrics(cls, t0)
+            return True
 
-    def release(self) -> None:
+    def release(self) -> int:
+        """Give the slot back; the in-gate count it was one of."""
         if self.max_concurrent <= 0:
-            return
+            return 0
         with self._cv:
+            inflight = self._active
             self._active -= 1
             self._grant_next()
+            return inflight
 
     @contextlib.contextmanager
     def admit(self, cls: str, deadline: Deadline | None = None):
@@ -295,18 +300,19 @@ class AdmissionController:
         # The wait for a slot (the profile's admissionWaitMs is fed by
         # this span).
         with start_span("qos.admit", stats=self._stats) as wait:
-            self.acquire(cls, deadline)
+            queued = self.acquire(cls, deadline)
         try:
             yield
         finally:
-            self.release()
-            # Feed the gradient limit from public classes only: the
-            # internal reserve rides above the adaptive limit, so its
-            # latency says nothing about the gate this tunes.
+            inflight = self.release()
+            # Feed the adaptive limit from public classes only: the
+            # internal reserve rides above it, so what those requests
+            # saw says nothing about the gate this tunes.
             if self.adaptive is not None and self.max_concurrent > 0 \
                     and normalize_class(cls) != CLASS_INTERNAL:
-                self.adaptive.observe(wait.wall,
-                                      time.perf_counter() - wait.end)
+                self.adaptive.observe(wait.wall if queued else 0.0,
+                                      time.perf_counter() - wait.end,
+                                      inflight)
 
     # -- observability ------------------------------------------------
 

@@ -235,7 +235,8 @@ class ServerNode:
         adaptive = None
         if qos_adaptive and qos_max_concurrent > 0:
             # qos-max-concurrent becomes the CEILING; the operative
-            # limit is measured (probe up / multiplicative back-off).
+            # limit follows goodput (probe one step, keep what served
+            # more; multiplicative back-off when goodput falls).
             adaptive = AdaptiveLimit(ceiling=qos_max_concurrent,
                                      stats=self.stats)
         self.qos = AdmissionController(

@@ -8,6 +8,8 @@ integration tests drive the real HTTP edge (429 + Retry-After contract,
 slow peer; breaker opens and re-closes around a heal).
 """
 
+import collections
+import heapq
 import json
 import threading
 import time
@@ -43,56 +45,156 @@ from pilosa_tpu.server.node import ServerNode
 # ---------------------------------------------------------------------------
 
 
-def test_adaptive_limit_rises_under_light_load():
+def closed_loop(limit, clients, service_of, completions):
+    """``clients`` closed-loop clients (an answer back, the next request
+    out) through a gate that follows ``limit``, on a virtual clock: an
+    event queue, no sleeps. ``service_of(k)`` is the service time of a
+    request admitted as the k-th in the gate. Returns the limit after
+    each completion."""
+    active = seq = 0
+    waiting = collections.deque([0.0] * clients)  # arrival times
+    running = []  # (done at, seq, queued for, service)
+    trajectory = []
+    now = 0.0
+    for _ in range(completions + 1):
+        while waiting and active < limit.limit:
+            arrived = waiting.popleft()
+            active += 1
+            service = service_of(active)
+            heapq.heappush(running, (now + service, seq, now - arrived,
+                                     service))
+            seq += 1
+        if len(trajectory) == completions:
+            break
+        now, _, queued_for, service = heapq.heappop(running)
+        limit.observe(queued_for, service, active)
+        active -= 1
+        waiting.append(now)  # behind whoever waits already
+        trajectory.append(limit.limit)
+    return trajectory
+
+
+BASE = 0.003
+
+
+def interpreter_bound(k):
+    """Requests share one interpreter: goodput is flat in the limit."""
+    return BASE * k
+
+
+def overlapping_waits(k, n=6):
+    """Waits that overlap: goodput grows with the limit up to ``n``."""
+    return BASE * max(1.0, k / n)
+
+
+def convoy(k, knee=5):
+    """Above the knee everyone waits on everyone: a third of the work."""
+    return BASE if k <= knee else BASE * 3 * k / knee
+
+
+SIMULATIONS = {
+    # name: (service model, clients, start, where it settles)
+    "a_interpreter_bound_settles_low": (interpreter_bound, 24, None, 1),
+    "b_overlap_reaches_n_from_floor": (overlapping_waits, 24, 1, 6),
+    "b_overlap_reaches_n_from_half": (overlapping_waits, 24, None, 6),
+    "c_convoy_returns_under_knee_from_floor": (convoy, 24, 1, 5),
+    "c_convoy_returns_under_knee_from_half": (convoy, 24, None, 5),
+    "d_no_queue_flat_latency_holds": (lambda k: BASE, 3, None, 16),
+}
+
+
+def simulate(name, completions=12_000, stats=None):
+    model, clients, start, settles = SIMULATIONS[name]
+    a = AdaptiveLimit(ceiling=32, stats=stats)
+    assert a.limit == 16  # ceiling // 2
+    if start is not None:
+        a._limit = start
+    return a, closed_loop(a, clients, model, completions), settles
+
+
+@pytest.mark.parametrize("name", sorted(SIMULATIONS))
+def test_adaptive_limit_closed_loop(name):
+    a, trajectory, settles = simulate(name)
+    # Settled inside the first third (the oversubscribed cell's warm-up
+    # is 200-1,200 completions), and then never more than one step (a
+    # probe's one window) from its place.
+    assert trajectory[len(trajectory) // 3] in (settles - 1, settles,
+                                                 settles + 1), trajectory[::64]
+    steady = trajectory[len(trajectory) // 3:]
+    assert max(abs(l - settles) for l in steady) <= 1, steady[::64]
+    assert steady.count(settles) > 0.8 * len(steady)
+    snap = a.snapshot()
+    if name.startswith("d_"):
+        # Nobody waits and latency is flat: nothing to learn, nothing done.
+        assert set(trajectory) == {16}
+        assert (snap["probeKept"], snap["probeReverted"],
+                snap["backoff"]) == (0, 0, 0)
+    else:
+        assert snap["lastWindow"]["saturated"]  # the queue never went away
+    assert snap["backoff"] == 0  # ... and alone it is not congestion
+
+
+def test_adaptive_limit_holds_without_a_queue():
+    """Raising a limit that nobody waits on tells nothing: no runaway."""
     a = AdaptiveLimit(ceiling=16, window=4)
     start = a.limit
-    for _ in range(3 * 4):
-        a.observe(0.0, 0.01)  # no queue wait, flat latency
-    assert a.limit == start + 3
-    assert a.snapshot()["increases"] == 3
+    for _ in range(50 * 4):
+        a.observe(0.0, 0.01, 2)  # admitted at once, flat latency
+    assert a.limit == start
+    snap = a.snapshot()
+    assert snap["probeKept"] == snap["probeReverted"] == snap["backoff"] == 0
+    assert snap["lastWindow"]["goodput"] == pytest.approx(200.0)
+    assert snap["lastWindow"]["inflight"] == pytest.approx(2.0)
 
 
-def test_adaptive_limit_backs_off_on_queue_wait():
-    a = AdaptiveLimit(ceiling=16, window=4, backoff=0.8)
-    before = a.limit
-    for _ in range(4):
-        a.observe(0.1, 0.01)  # 100ms queue wait = congestion
-    assert a.limit == int(before * 0.8)
-    assert a.snapshot()["decreases"] == 1
+def test_adaptive_limit_probes_up_under_a_queue_that_waits_long():
+    """The parent's fault: 100 ms of queue wait read as congestion, and
+    the limit pinned at its floor. A queue is demand: the limit rises
+    while each step buys goodput, however long the wait."""
+    a = AdaptiveLimit(ceiling=16, window=4)
+    a._limit = 1
+    for _ in range(40):
+        limit = a.limit
+        for _ in range(4):
+            a.observe(0.1, 0.01, limit)  # goodput = limit / 10 ms
+    assert a.limit >= 10
+    assert a.snapshot()["backoff"] == 0
 
 
-def test_adaptive_limit_backs_off_on_latency_growth():
-    a = AdaptiveLimit(ceiling=16, window=4, latency_ratio=1.5)
-    for _ in range(4):
-        a.observe(0.0, 0.01)  # establish the baseline
-    lifted = a.limit
-    for _ in range(4):
-        a.observe(0.0, 0.05)  # 5x service time, still no queue wait
-    assert a.limit < lifted
+def test_adaptive_limit_backs_off_when_goodput_falls():
+    """Goodput that falls at an unchanged limit while service times grow
+    is congestion: the multiplicative back-off, in one window."""
+    a = AdaptiveLimit(ceiling=16, backoff=0.8)
+    slow = [1.0]
+    trajectory = closed_loop(a, 24, lambda k: slow[0] * overlapping_waits(k),
+                             6_000)
+    assert trajectory[-1] == 6 and a.snapshot()["backoff"] == 0
+    slow[0] = 3.0  # the same requests now take three times as long
+    trajectory = closed_loop(a, 24, lambda k: slow[0] * overlapping_waits(k),
+                             1_200)  # a settled limit's windows are long
+    assert a.snapshot()["backoff"] == 1
+    assert int(6 * 0.8) in trajectory
 
 
 def test_adaptive_limit_floor_and_ceiling():
     a = AdaptiveLimit(ceiling=4, floor=1, window=2)
-    for _ in range(40):
-        a.observe(0.5, 0.1)  # permanent congestion
+    closed_loop(a, 8, interpreter_bound, 400)
     assert a.limit == 1  # never below the floor
-    for _ in range(40):
-        a.observe(0.0, 0.1)  # recovered: probes back up
-    assert a.limit == 4  # never above the ceiling
+    trajectory = closed_loop(a, 8, lambda k: BASE, 400)  # every slot pays
+    assert a.limit == 4 and max(trajectory) == 4  # never above the ceiling
 
 
 def test_admission_gate_follows_adaptive_limit():
-    """With the adaptive limit backed off to 1, a max_concurrent=4 gate
-    admits exactly one public query — but internal legs still ride the
-    reserve above the CEILING (deadlock guard intact)."""
+    """With the adaptive limit down at 1, a max_concurrent=4 gate admits
+    exactly one public query — but internal legs still ride the reserve
+    above the CEILING (deadlock guard intact)."""
     a = AdaptiveLimit(ceiling=4, window=2)
-    for _ in range(20):
-        a.observe(0.5, 0.1)
+    closed_loop(a, 8, interpreter_bound, 400)
     assert a.limit == 1
     ctl = AdmissionController(max_concurrent=4, max_queue=4,
                               internal_reserve=1, adaptive=a)
     assert ctl.snapshot()["limit"] == 1
-    ctl.acquire(CLASS_INTERACTIVE)
+    assert ctl.acquire(CLASS_INTERACTIVE) is False  # did not queue
     # second public request queues (would admit under the static gate)
     with pytest.raises((QueryShedError, DeadlineExceededError)):
         ctl.acquire(CLASS_INTERACTIVE, deadline=Deadline(timeout=0.05))
@@ -107,7 +209,26 @@ def test_admission_gate_follows_adaptive_limit():
     t.start()
     assert got.wait(2), "internal leg blocked by the adaptive limit"
     t.join(5)
-    ctl.release()
+    assert ctl.release() == 1  # the in-gate count it was one of
+
+
+@pytest.mark.parametrize("name", ["b_overlap_reaches_n_from_floor",
+                                  "c_convoy_returns_under_knee_from_floor"])
+def test_adaptive_decisions_are_counted(name):
+    """What /debug/vars and /debug/overload show of the mechanism."""
+    from pilosa_tpu.obs.stats import MemoryStats
+    stats = MemoryStats()
+    a, _, settles = simulate(name, stats=stats)
+    snap = a.snapshot()
+    assert snap["probeKept"] >= settles - 1  # it climbed there
+    assert snap["probeReverted"] >= 2  # and found both neighbours worse
+    for kind in ("probeKept", "probeReverted", "backoff"):
+        assert stats.counter_value("qos.adaptive." + kind) == snap[kind]
+    assert stats.gauges[("qos.adaptiveLimit", ())] == a.limit
+    last = snap["lastWindow"]
+    assert last["inflight"] == pytest.approx(last["limit"])
+    assert last["goodput"] == pytest.approx(
+        last["inflight"] / (last["serviceMs"] / 1e3), rel=1e-3)
 
 
 def test_admission_feeds_adaptive_from_public_classes_only():
@@ -120,6 +241,45 @@ def test_admission_feeds_adaptive_from_public_classes_only():
     with ctl.admit(CLASS_INTERACTIVE):
         pass
     assert a.snapshot()["pending"] == 1
+
+
+def test_admission_with_adaptive_limit_under_threads():
+    """More threads than cores through the real gate, the interpreter
+    switching often: every slot comes back, nobody is admitted over the
+    limit of the moment, and the limit judged windows on the way."""
+    import sys
+    a = AdaptiveLimit(ceiling=8, window=8)
+    ctl = AdmissionController(max_concurrent=8, max_queue=64, adaptive=a)
+    over = []
+    seen = set()
+
+    def client():
+        for _ in range(150):
+            with ctl.admit(CLASS_INTERACTIVE):
+                # the limit may have fallen since this one was admitted,
+                # so the bound that holds is the ceiling
+                if ctl.snapshot()["active"] > 8:
+                    over.append(1)
+                seen.add(a.limit)
+                time.sleep(0.0002)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=client) for _ in range(24)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(interval)
+    snap = ctl.snapshot()
+    assert not over
+    assert snap["active"] == 0 and snap["queuedTotal"] == 0
+    assert snap["admitted"] == 24 * 150 and snap["shed"] == 0
+    assert a.snapshot()["lastWindow"] is not None
+    assert seen <= set(range(1, 9))
 
 
 # ---------------------------------------------------------------------------
@@ -546,6 +706,15 @@ def test_http_debug_overload_route(quota_node):
     assert payload["adaptive"] is not None
     assert 1 <= payload["adaptive"]["limit"] <= 4
     assert payload["admission"]["limit"] == payload["adaptive"]["limit"]
+    # the mechanism's decisions by kind, and the last judged window (none
+    # yet: one request is no window)
+    for kind in ("probeKept", "probeReverted", "backoff"):
+        assert payload["adaptive"][kind] == 0
+    assert payload["adaptive"]["lastWindow"] is None
+    assert payload["adaptive"]["pending"] == 1
+    _, debug_vars, _ = _req(base, "GET", "/debug/vars")
+    for kind in ("probeKept", "probeReverted", "backoff"):
+        assert debug_vars["counters"]["qos.adaptive." + kind] == 0
     assert payload["quotas"]["ratePerS"] == pytest.approx(0.01)
     assert payload["quotas"]["tenants"] >= 1
     # standalone node: no cluster, so no breakers/hedge sections
