@@ -17,8 +17,7 @@ one column per ride, at the shapes of BASELINE.json's config 1: shard width
 2^20, 954 shards = 1,000,341,504 columns, one node, durable data dir, id
 fields. Set fields ``f`` and ``g`` hold 8 rows each (``f=1`` and ``g=2`` at
 density 0.05, the rest at 0.01; a density is the share of columns drawn per
-shard, with replacement, as bench.py draws them); int field ``v`` (0..1000)
-holds 1M values.
+shard, with replacement); int field ``v`` (0..1000) holds 1M values.
 
   python chip_smoke.py              one chip, the whole run
   python chip_smoke.py --chips 4    four chips: load, Counts, TopN, GroupBy

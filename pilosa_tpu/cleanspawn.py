@@ -1,6 +1,6 @@
 """Hermetic CPU-pinned subprocess spawning for the multi-chip dryruns.
 
-The multi-process dryruns (MULTICHIP_r*.json, parallel/multihost) run on
+The multi-process dryruns (parallel/multihost) run on
 the virtual-device CPU backend: N processes x M virtual devices stand in
 for N hosts x M chips. Their children must come up on the CPU whatever
 the parent's environment selects, because a chip belongs to one process

@@ -155,10 +155,8 @@ _DEFAULTS = {
     "inline_transfer": "auto",
     # Device residency: packed [S, K] index stacks for low-cardinality
     # rows ("auto" packs only rows at least 8x smaller than the dense
-    # plane; bit-identical) and the pipelined async upload path for
-    # non-resident leaf stacks.
+    # plane; bit-identical).
     "residency_packed": "auto",
-    "prefetch": "on",
     # Device key planes (pilosa_tpu/exec/keyplane): forward key
     # translation via a resident sorted-hash plane for large keyed
     # batches ("auto" probes on device only for batches of 256+ keys;
@@ -296,8 +294,6 @@ def cmd_server(args) -> int:
         cfg["inline_transfer"] = args.inline_transfer
     if args.residency_packed is not None:
         cfg["residency_packed"] = args.residency_packed
-    if args.prefetch is not None:
-        cfg["prefetch"] = args.prefetch
     if args.translate_planes is not None:
         cfg["translate_planes"] = args.translate_planes
     if args.sketch_precision is not None:
@@ -367,7 +363,6 @@ def cmd_server(args) -> int:
         dispatch_coalesce_us=float(cfg["dispatch_coalesce_us"]),
         inline_transfer=str(cfg["inline_transfer"]) or "auto",
         residency_packed=str(cfg["residency_packed"]) or "auto",
-        prefetch=str(cfg["prefetch"]) or "on",
         translate_planes=str(cfg["translate_planes"]) or "auto",
         sketch_precision=int(cfg["sketch_precision"]),
         sketch_exact_threshold=int(cfg["sketch_exact_threshold"]),
@@ -825,10 +820,8 @@ def cmd_generate_config(args) -> int:
           'dispatch-coalesce-us = 150.0\n'
           'inline-transfer = "auto"\n'
           '# device residency: packed index stacks for low-cardinality\n'
-          '# rows (auto|on|off, bit-identical) and pipelined async\n'
-          '# uploads for non-resident leaf stacks (on|off)\n'
+          '# rows (auto|on|off, bit-identical)\n'
           'residency-packed = "auto"\n'
-          'prefetch = "on"\n'
           '# key translation: device-resident sorted-hash planes for\n'
           '# large keyed batches (auto = device probe for 256+ keys)\n'
           'translate-planes = "auto"\n'
@@ -981,9 +974,6 @@ def main(argv: list[str] | None = None) -> int:
                         "stacks on device instead of dense bit planes "
                         "(default auto = pack rows at least 8x smaller "
                         "packed; bit-identical)")
-    s.add_argument("--prefetch", choices=("on", "off"), default=None,
-                   help="upload non-resident leaf stacks asynchronously "
-                        "ahead of query execution (default on)")
     s.add_argument("--translate-planes", choices=("on", "off", "auto"),
                    default=None,
                    help="forward key translation via device-resident "

@@ -117,7 +117,8 @@ class Executor:
         #: translation for large key batches; arrays live in the
         #: planner's budgeted stack cache when a planner is attached.
         from pilosa_tpu.exec.keyplane import KeyPlaneCache
-        self.keyplanes = KeyPlaneCache(planner)
+        self.keyplanes = KeyPlaneCache(
+            planner.stacks if planner is not None else None)
         from pilosa_tpu.obs import NopStats
         self.stats = stats or NopStats()
         #: query-string -> parsed Query. Parsed trees are shared across
@@ -146,7 +147,7 @@ class Executor:
         #: index-dirty broadcasts; the cross-node half of cache stamps.
         from pilosa_tpu.cache import RemoteEpochTable
         self.remote_epochs = RemoteEpochTable()
-        self._cache_lock = threading.Lock()
+        self._prepared_lock = threading.Lock()
         #: (index, query text) -> (instance_id, schema_epoch, data epoch,
         #: shards, jitted fn, leaf device arrays, result-cache key): the
         #: prepared-query dispatch path (execute_async). Unlike the
@@ -303,7 +304,7 @@ class Executor:
                 if stale:
                     # Drop device-array references the moment an entry
                     # goes stale (don't wait for LRU churn).
-                    with self._cache_lock:
+                    with self._prepared_lock:
                         if self._prepared.get((index_name, raw)) is e:
                             del self._prepared[(index_name, raw)]
                     e = None
@@ -312,7 +313,7 @@ class Executor:
                              or (shards is not None and shards == e[3]))):
                     (_, _, epoch, pshards, fn, arrays, rkey, post, _,
                      steps) = e
-                    with self._cache_lock:
+                    with self._prepared_lock:
                         if (index_name, raw) in self._prepared:
                             self._prepared.move_to_end((index_name, raw))
                     cacheable = cache and self.result_cache is not None
@@ -416,7 +417,7 @@ class Executor:
                 steps = _fuse.call_steps(call.children[0]) + 1
                 if raw is not None:
                     sum_host = self.planner._sum_host
-                    with self._cache_lock:
+                    with self._prepared_lock:
                         # `shards` is OUR copy — never the caller's
                         # mutable list, which could change under an
                         # identity check. Final flag: prepared from
@@ -455,13 +456,13 @@ class Executor:
         return fut
 
     def _parse_cached(self, raw: str) -> Query:
-        with self._cache_lock:
+        with self._prepared_lock:
             q = self._parse_cache.get(raw)
             if q is not None:
                 self._parse_cache.move_to_end(raw)
                 return q
         q = parse(raw)
-        with self._cache_lock:
+        with self._prepared_lock:
             self._parse_cache[raw] = q
             while len(self._parse_cache) > self.PARSE_CACHE_SIZE:
                 self._parse_cache.popitem(last=False)

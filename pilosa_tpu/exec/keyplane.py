@@ -15,10 +15,10 @@ probed on device by a vectorized lexicographic binary search (the
 sorted-membership idiom packed_pair_count already uses). x64 is off in
 this stack's jax config, so the 64-bit hash lane is stored as two
 uint32 lanes (hi, lo) and the plane ships as ONE [3, H] uint32 array —
-a single stack-cache resident the planner accounts like any other
-representation class (``KEYPLANE`` in exec/residency.py, registered
-through ``MeshPlanner._insert_stack`` and rebuilt via the residency
-prefetcher on translate-version bump).
+a single resident of the planner's stack store, accounted like any
+other representation class (``KEYPLANE`` in exec/residency.py, inserted
+through ``StackStore.insert`` and rebuilt by the store's upload workers
+on translate-version bump).
 
 Fingerprint semantics (documented contract, same as any PHF): the
 64-bit hash IS the identity test on device. Keys whose hashes collide
@@ -31,15 +31,15 @@ which re-checks under the store lock before allocating — a stale plane
 is therefore correct-but-incomplete, never wrong about what it holds.
 
 Modes (``PILOSA_TPU_TRANSLATE_PLANES`` env wins over the server knob's
-``set_mode``, mirroring residency/prefetch):
+``set_mode``, mirroring exec/residency):
 
 * ``auto`` (default) — device probe only for batches of at least
   ``MIN_DEVICE_BATCH`` keys (below that the lock-free host snapshot is
   faster than a dispatch, and single-key warm Counts must stay one
   device launch); version-stale planes serve stale + schedule an async
-  rebuild on the residency prefetcher.
+  rebuild on the stack store's upload workers.
 * ``on``   — device probe for any batch, synchronous rebuild on
-  version bump (the deterministic test/bench mode).
+  version bump (the deterministic test mode).
 * ``off``  — host snapshot path only; no planes are built.
 """
 
@@ -266,17 +266,19 @@ class KeyPlane:
 class KeyPlaneCache:
     """Per-executor registry of key planes, one per translate store.
 
-    Device arrays live in the owning planner's stack cache (class
+    Device arrays live in the owning planner's stack store (class
     ``keyplane``), so planes share the residency budget, the eviction
     policy, and /debug/device byte accounting with row stacks; an
-    evicted plane simply rebuilds on next use. Without a planner (host
-    oracle tests, bench standalone mode) arrays are pinned locally.
+    evicted plane simply rebuilds on next use. Without a store (host
+    oracle tests, an executor with no planner) arrays are pinned
+    locally.
     """
 
-    def __init__(self, planner=None):
-        self.planner = planner
+    def __init__(self, stacks=None):
+        #: the planner's StackStore (parallel.stacks), or None.
+        self.stacks = stacks
         self._planes: dict[tuple, KeyPlane] = {}
-        self._mats: dict[tuple, jax.Array] = {}  # planner-less fallback
+        self._mats: dict[tuple, jax.Array] = {}  # store-less fallback
         self._lock = threading.Lock()
         self.builds = 0
         self.device_batches = 0
@@ -288,32 +290,23 @@ class KeyPlaneCache:
     # -- plumbing ----------------------------------------------------------
 
     def _stack_key(self, idx, field: str | None) -> tuple:
-        # Same 7-slot layout as row stacks: instance_id so a
-        # deleted-and-recreated index can't serve the old index's plane;
-        # klass in slot 6 drives _insert_stack's per-class accounting.
-        return (idx.name, idx.instance_id, field or "", VIEW, 0, (),
-                KEYPLANE)
+        slots = (idx.name, idx.instance_id, field or "", VIEW, 0, (),
+                 KEYPLANE)
+        return slots if self.stacks is None else self.stacks.key(*slots)
 
     def _fetch_mat(self, key: tuple):
-        pl = self.planner
-        if pl is None:
+        if self.stacks is None:
             return self._mats.get(key)
-        with pl._cache_lock:
-            hit = pl._stack_cache.get(key)
-            if hit is None:
-                return None
-            pl._stack_cache.move_to_end(key)
-            return hit[2]
+        return self.stacks.peek(key)
 
     def _build(self, key: tuple, store) -> tuple[KeyPlane, jax.Array]:
         version, fwd, _ = store.snapshot()
         mat_np, collisions, valid = build_plane(fwd)
         arr = jax.device_put(mat_np)
-        pl = self.planner
-        if pl is None:
+        if self.stacks is None:
             self._mats[key] = arr
         else:
-            pl._insert_stack(key, version, (), arr, int(mat_np.nbytes))
+            self.stacks.insert(key, version, (), arr, int(mat_np.nbytes))
         plane = KeyPlane(version, collisions, valid, key)
         with self._lock:
             self._planes[key] = plane
@@ -321,13 +314,11 @@ class KeyPlaneCache:
         return plane, arr
 
     def _schedule_build(self, key: tuple, store) -> None:
-        pl = self.planner
-        if (pl is None or not pl.prefetch_supported
-                or not pl.prefetcher.enabled()):
+        if self.stacks is None or not self.stacks.uploads_ahead:
             return
         with self._lock:
             self.rebuilds_scheduled += 1
-        pl.prefetcher.schedule(key, lambda: self._build(key, store))
+        self.stacks.schedule(key, store.version, self._build, key, store)
 
     # -- the forward-translate entry point ---------------------------------
 

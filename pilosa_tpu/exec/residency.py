@@ -144,12 +144,6 @@ def packed_nbytes(s_pad: int, k: int) -> int:
     return int(s_pad) * int(k) * 4
 
 
-def stack_nbytes(arr) -> int:
-    """Resident bytes of ANY class's device stack — the one number the
-    planner's budget accounting is allowed to use."""
-    return int(arr.nbytes)
-
-
 # ---------------------------------------------------------------------------
 # kernel variants (traced inside the planner's jitted programs)
 # ---------------------------------------------------------------------------

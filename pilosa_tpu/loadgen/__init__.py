@@ -11,8 +11,7 @@ throughput, and p99 exemplar trace ids resolved through
 ``/debug/queries/<trace-id>`` into full cost profiles.
 
 Run one with ``python -m pilosa_tpu.loadgen <scenario>`` (see
-``scenarios.py`` for the built-ins) or from bench.py via
-``BENCH_CONFIGS=overload``-style thin configs.
+``scenarios.py`` for the built-ins).
 """
 
 from pilosa_tpu.loadgen.arrival import OpenLoopArrivals

@@ -1,11 +1,10 @@
 """Built-in scenarios.
 
-Several of these re-express bench.py silos as legs of the one engine:
-``dashboard_storm`` is the dispatch-storm + cache-churn pair,
-``overload`` is the slow-peer breaker/hedge drill, ``ingest_under_query``
-is the interactive-p99-under-PTS1-stream drill, and ``elastic`` is the
-query-through-resize drill — each formerly its own hand-rolled
-bench loop, now a scenario config on shared machinery.
+Each is a scenario config on the one engine: ``dashboard_storm`` is
+the dispatch-storm + cache-churn pair, ``overload`` is the slow-peer
+breaker/hedge drill, ``ingest_under_query`` is the
+interactive-p99-under-PTS1-stream drill, and ``elastic`` is the
+query-through-resize drill.
 
 ``smoke``/``smoke3`` are the CI pair: short, seeded, deterministic
 op sequences (see ``engine.build_ops``) sized to finish in ~30 s
@@ -118,8 +117,8 @@ def overload() -> Scenario:
               QueryLeg(name="adhoc", weight=2.0, kind="adhoc",
                        qos_class="batch", population=64, no_cache=True)],
         # slow > deadline: legs via node1 breach, feed its breaker, and
-        # hedged replicas must win — mirrors the old bench's 0.6s slow
-        # peer against a 0.5s deadline.
+        # hedged replicas must win — a 0.6s slow peer against a 0.5s
+        # deadline.
         chaos=[ChaosAction(at_s=5.0, action="slow_peer", node=1, value=600.0),
                ChaosAction(at_s=14.0, action="heal_peer", node=1)],
         node_opts={"qos_max_concurrent": 4, "qos_max_queue": 8,

@@ -1,4 +1,4 @@
-"""Heap / memory observability (VERDICT r4 missing #3).
+"""Heap / memory observability.
 
 The reference exposes Go pprof heap at /debug/pprof (http/handler.go:
 281); an operator can always answer "where did the RAM go".  This

@@ -12,7 +12,7 @@ multi-op trees (e.g. popcount((a & b) &~ c)) that XLA sometimes splits.
 
 On the CPU backend (the test mesh) the same kernels run with
 ``interpret=True``: the choice is a pure function of the platform, made
-once per call in ``pair_count``/``row_counts``, so on a TPU a kernel the
+once per call in ``pair_count``, so on a TPU a kernel the
 chip's compiler refuses raises instead of being answered some other
 way. PILOSA_TPU_NO_PALLAS=1 is the one, explicit, way to the pure-XLA
 expression.
@@ -68,10 +68,6 @@ def _count_kernel(op, a_ref, b_ref, o_ref):
     _accumulate_rowsum(o_ref, op(a_ref[...], b_ref[...]))
 
 
-def _popcount_kernel(a_ref, o_ref):
-    _accumulate_rowsum(o_ref, a_ref[...])
-
-
 def _pad2d(x, tm, tw):
     m, w = x.shape
     pm = (-m) % tm
@@ -111,23 +107,6 @@ def _pallas_pair_count(a, b, op: str, interpret: bool):
     return out[:m0, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _pallas_row_counts(a, interpret: bool):
-    m0 = a.shape[0]
-    a = _pad2d(a, _TILE_M, _TILE_W)
-    m, w = a.shape
-    grid = (m // _TILE_M, w // _TILE_W)
-    out = pl.pallas_call(
-        _popcount_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((_TILE_M, _TILE_W), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((_TILE_M, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, 1), jnp.int32),
-        interpret=interpret,
-    )(a)
-    return out[:m0, 0]
-
-
 def available() -> bool:
     return not _DISABLED
 
@@ -144,13 +123,3 @@ def pair_count(a, b, op: str = "and"):
         }[op](a, b)
     shape = jnp.broadcast_shapes(a.shape, b.shape)[:-1]
     return _pallas_pair_count(a, b, op, _interpret()).reshape(shape)
-
-
-def row_counts(a):
-    """Per-row popcount over [..., W] — feeds TopN/Rows (the device-side
-    replacement for the reference's rankCache, cache.go:136)."""
-    if _DISABLED:
-        return bitops.count(a)
-    shape = a.shape[:-1]
-    out = _pallas_row_counts(a.reshape((-1, a.shape[-1])), _interpret())
-    return out.reshape(shape)

@@ -317,9 +317,10 @@ class DispatchCoalescer:
         raw = None if shared else planner.fn_raw(batch.fn)
         if not shared and (raw is None
                            or not planner.coalesce_vmap_supported):
-            # No vmappable program (e.g. a Pallas kernel): launch
-            # per entry — still one trip through this thread, and
-            # the accounting stays honest (B launches recorded).
+            # No vmappable program, or a mesh the vmapped wave would
+            # lose its shardings on: launch per entry — still one trip
+            # through this thread, and the accounting stays honest (B
+            # launches recorded).
             for args, post, fut, prof in entries:
                 _chain(self._launch_one(batch.key, batch.fn, args,
                                         post, prof=prof), fut)

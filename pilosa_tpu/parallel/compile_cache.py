@@ -5,8 +5,8 @@ aggregates — is a pure function of padded array shapes, so a restarted
 node re-deriving the exact same programs pays full trace+compile cost
 for zero new information. JAX ships an on-disk compilation cache that
 memoizes backend_compile across processes; this module turns it on and
-exposes deterministic hit/miss counters so warmup, /debug/vars,
-bench.py, and CI can all assert the cache actually did its job instead
+exposes deterministic hit/miss counters so warmup, /debug/vars, the
+benchmark and CI can all assert the cache actually did its job instead
 of trusting wall-clock deltas.
 
 The directory is part of the cache key, so a directory that moves

@@ -129,6 +129,10 @@ class DistributedMeshPlanner(MeshPlanner):
     silent zeros.
     """
 
+    #: an upload worker would build stacks on ONE process's thread and
+    #: desync the collective launch order every other process expects.
+    UPLOADS_AHEAD = False
+
     def __init__(self, holder, mesh, owned_shards, **kw):
         super().__init__(holder, mesh, **kw)
         self.owned_shards = frozenset(int(s) for s in owned_shards)
@@ -143,11 +147,8 @@ class DistributedMeshPlanner(MeshPlanner):
         self.fuse_aggregates_supported = False
         self.fuse_const_supported = False
         # Packed residency would need a packed variant of the global
-        # per-process assembly below; prefetch would run stack builds on
-        # ONE process's worker thread, desyncing the collective launch
-        # order every other process expects. Both stay off here.
+        # per-process assembly below.
         self.residency_packed_supported = False
-        self.prefetch_supported = False
         # Sketch stacks (hll planes / simtopn cubes) assemble host-side
         # on one node; the distributed mesh falls back to the executor's
         # per-shard map + register-max reduce instead.

@@ -51,13 +51,13 @@ from pilosa_tpu.ops import bitops, bsi as bsi_ops
 from pilosa_tpu.parallel import compile_cache
 from pilosa_tpu.parallel.batcher import TransferBatcher
 from pilosa_tpu.parallel.coalesce import DispatchCoalescer
-from pilosa_tpu.parallel.prefetch import ResidencyPrefetcher
 from pilosa_tpu.parallel.mesh import (
     SHARD_AXIS,
     make_mesh,
     pad_to_multiple,
     shard_spec,
 )
+from pilosa_tpu.parallel.stacks import StackKey, StackStore
 from pilosa_tpu.pql import BETWEEN, NEQ, Call, Condition
 from pilosa_tpu.pql import ast as pql_ast
 
@@ -70,6 +70,11 @@ class MeshPlanner:
 
     #: default device-memory budget for cached leaf stacks (bytes).
     DEFAULT_CACHE_BYTES = 4 << 30
+    #: whether the store's workers upload a plan's stacks ahead of the
+    #: request. Off for the distributed planner: its stack builds must
+    #: run on every process of the mesh in lockstep, not on one node's
+    #: worker.
+    UPLOADS_AHEAD = True
 
     def __init__(self, holder, mesh=None,
                  max_cache_bytes: int = DEFAULT_CACHE_BYTES,
@@ -83,33 +88,17 @@ class MeshPlanner:
         #: round up to power-of-two buckets so a never-seen shard count
         #: dispatches into an already-compiled program (see _pad).
         self.bucket_policy = bucket_policy
-        #: LRU of (index, field, view, row_id, shards) ->
-        #: (epoch, gens, [S, W] device array); bounded by max_cache_bytes.
-        #: Epoch-stamped: a hit is ONE integer compare against the index's
-        #: mutation epoch; only an epoch change triggers the per-fragment
-        #: generation walk (and only for the touched leaf). This replaces
-        #: r2's per-query walk of every fragment per leaf.
-        self._stack_cache: "OrderedDict[tuple, tuple[int, tuple, jax.Array]]" = \
-            OrderedDict()
-        self._cache_bytes = 0
-        #: resident bytes per representation class (the key's last
-        #: element) — the compression win is invisible in the single
-        #: total; /debug/device renders the split.
-        self._class_bytes = {k: 0 for k in _residency.REPR_CLASSES}
-        #: lifetime stack-cache evictions (budget pressure), for the
-        #: runtime monitor / /debug/heap — churn in the oversubscribed
-        #: regime is invisible without it.
-        self._cache_evictions = 0
-        #: lifetime host->device stack builds and their bytes: with the
-        #: eviction counter these are THE oversubscription signal — a
-        #: working set over budget shows as uploads tracking queries
-        #: instead of flatlining after warmup (/debug/device).
-        self._uploads = 0
-        self._upload_bytes = 0
-        self.max_cache_bytes = max_cache_bytes
-        #: guards _stack_cache/_cache_bytes — one planner serves every
-        #: thread of the HTTP server.
-        self._cache_lock = threading.Lock()
+        #: every device-resident stack (rows, cubes, sketch planes, key
+        #: planes): keys, validity, the byte budget, eviction and the
+        #: uploads in flight (parallel.stacks). The builders below say
+        #: how a stack is made; the store says whether one is needed.
+        self.stacks = StackStore(max_cache_bytes, _residency.REPR_CLASSES,
+                                 stats=stats,
+                                 uploads_ahead=self.UPLOADS_AHEAD)
+        #: guards the plan, TopN-filter and vmap caches and the
+        #: observed-traffic list — one planner serves every thread of
+        #: the HTTP server.
+        self._plan_lock = threading.Lock()
         #: structural signature -> jitted tree evaluator
         self._fn_cache: dict[tuple, Callable] = {}
         #: sparse-upload assembler, jitted per mesh so the scatter
@@ -147,16 +136,15 @@ class MeshPlanner:
         #: structural signature (the coalescer's batch key — the result
         #: cache already proved same-signature plans identical, so the
         #: key comes free) and the raw unjitted program (vmappable for
-        #: the [B, ...] batched launch; None for programs that can't
-        #: vmap, e.g. Pallas kernels). Entries live exactly as long as
+        #: the [B, ...] batched launch). Entries live exactly as long as
         #: _fn_cache pins the function, so ids never recycle underneath.
-        self._fn_info: dict[int, tuple[tuple, Callable | None]] = {}
+        self._fn_info: dict[int, tuple[tuple, Callable]] = {}
         #: plan signature -> jitted vmapped program (jit re-specializes
         #: per [B, ...] shape internally, so one entry per signature).
         self._vmap_cache: dict[tuple, Callable] = {}
         #: query-program launch accounting (planner.dispatchCount /
         #: dispatchCoalesced / coalesceBatchWidth on /debug/vars; the
-        #: bench's dispatches-per-query series reads the raw counters).
+        #: benchmark's dispatches_per_request reads the counters).
         self._dispatch_lock = threading.Lock()
         self.dispatches = 0
         self.dispatches_coalesced = 0
@@ -187,14 +175,6 @@ class MeshPlanner:
         #: _build_stack assembles per-process dense fragments and has
         #: no packed assembly path yet.
         self.residency_packed_supported = True
-        #: async upload pipeline for non-resident leaf stacks; off for
-        #: the distributed planner (its stack builds must run on every
-        #: process of the mesh in lockstep, not on one node's worker).
-        self.prefetch_supported = True
-        #: pipelined miss path: prepare peeks the plan's leaf set and
-        #: schedules async uploads here; _stack_rows rendezvouses with
-        #: inflight uploads instead of re-building (parallel.prefetch).
-        self.prefetcher = ResidencyPrefetcher(self, stats=stats)
         #: fused sketch programs (pilosa_tpu.sketch): HLL distinct-count
         #: register planes and the SimilarTopN row-cube ranking; off for
         #: the distributed planner — its per-process stack assembly has
@@ -290,7 +270,7 @@ class MeshPlanner:
             # growth — must miss.
             plan_key = (idx.name, idx.instance_id, idx.schema_epoch.value,
                         text, shards)
-            with self._cache_lock:
+            with self._plan_lock:
                 hit = self._plan_cache.get(plan_key)
                 if hit is not None:
                     self._plan_cache.move_to_end(plan_key)
@@ -300,7 +280,7 @@ class MeshPlanner:
                 return hit[0], hit[1]
             leaves: list[tuple] = []
             fn = build(leaves)
-            with self._cache_lock:
+            with self._plan_lock:
                 self._plan_cache[plan_key] = (leaves, fn, idx.epoch.value)
                 while len(self._plan_cache) > self.PLAN_CACHE_SIZE:
                     self._plan_cache.popitem(last=False)
@@ -351,11 +331,11 @@ class MeshPlanner:
             _, field_name, view, row_id = leaf
             if self._leaf_class(idx, field_name, view, row_id,
                                 shards) != _residency.PACKED:
-                with self._cache_lock:
+                with self._plan_lock:
                     self._plan_cache.pop(plan_key, None)
                 return None
         hit = (leaves, fn, epoch)
-        with self._cache_lock:
+        with self._plan_lock:
             if plan_key in self._plan_cache:
                 self._plan_cache[plan_key] = hit
         return hit
@@ -411,7 +391,7 @@ class MeshPlanner:
                 p.add_dispatch(width)
 
     def batch_widths(self) -> list[int]:
-        """Recent per-launch batch widths (bench's coalesce p50)."""
+        """Recent per-launch batch widths."""
         with self._dispatch_lock:
             return list(self._batch_widths)
 
@@ -432,11 +412,11 @@ class MeshPlanner:
     def vmapped(self, full_sig: tuple, raw) -> Callable:
         """jit(vmap(program)) for the [B, ...] coalesced wave; cached by
         signature (jit re-specializes per batch-shape internally)."""
-        with self._cache_lock:
+        with self._plan_lock:
             vfn = self._vmap_cache.get(full_sig)
         if vfn is None:
             vfn = jax.jit(_named(jax.vmap(raw), raw.__name__ + "_wave"))
-            with self._cache_lock:
+            with self._plan_lock:
                 self._vmap_cache[full_sig] = vfn
         return vfn
 
@@ -466,7 +446,7 @@ class MeshPlanner:
         return Row({shard: out[i] for i, shard in enumerate(shards)})
 
     # ------------------------------------------------------------------
-    # aggregates (VERDICT r1 #4): Sum/Min/Max as ONE SPMD program over
+    # aggregates: Sum/Min/Max as ONE SPMD program over
     # the BSI leaf stacks + optional filter tree, instead of the per-shard
     # host loop (reference executor.go:406-999). Rows() stays host-side by
     # design: it is a row-id metadata scan with no device math to batch.
@@ -572,8 +552,8 @@ class MeshPlanner:
         CPU backend). A FILTERED aggregate fuses under ``auto`` only
         off-CPU: XLA's CPU backend compiles the bit-serial comparator
         and the broadcast reduction into a ~2x-slower loop structure
-        when they share one module (bench's dispatch config;
-        optimization barriers don't dissuade it); on an accelerator one
+        when they share one module (optimization barriers don't
+        dissuade it); on an accelerator one
         launch instead of three is taken to win (not measured on the
         current machine). ``on`` forces fusion — the bit-equivalence
         tests and TPU-style measurement use it."""
@@ -865,14 +845,14 @@ class MeshPlanner:
             # across TopN's two passes (same filter, same epoch).
             fkey = (idx.name, idx.instance_id, str(filter_call),
                     tuple(shards), idx.epoch.value)
-            with self._cache_lock:
+            with self._plan_lock:
                 hit = self._filter_host_cache.get(fkey)
             if hit is not None:
                 filt_host = hit
             else:
                 filt.copy_to_host_async()
                 filt_host = np.asarray(filt, dtype=np.uint32)
-                with self._cache_lock:
+                with self._plan_lock:
                     self._filter_host_cache[fkey] = filt_host
                     while len(self._filter_host_cache) > 4:
                         self._filter_host_cache.pop(
@@ -924,7 +904,7 @@ class MeshPlanner:
         return out
 
     # ------------------------------------------------------------------
-    # GroupBy (VERDICT r2 weak #4): the per-shard DFS paid one device
+    # GroupBy: the per-shard DFS paid one device
     # sync per (shard, prefix); here the WHOLE local shard batch runs on
     # the cached [S, W] stacks — one cheap async dispatch per
     # (prefix, last-level row), every count delivered through the
@@ -1015,24 +995,27 @@ class MeshPlanner:
                 out.append((group, cnt))
         return out
 
+    @property
+    def max_cache_bytes(self) -> int:
+        return self.stacks.budget_bytes
+
+    @max_cache_bytes.setter
+    def max_cache_bytes(self, n: int) -> None:
+        self.stacks.budget_bytes = n
+
     def invalidate(self) -> None:
-        with self._cache_lock:
-            self._stack_cache.clear()
+        self.stacks.clear()
+        with self._plan_lock:
             self._filter_host_cache.clear()
             self._plan_cache.clear()
-            self._cache_bytes = 0
-            self._class_bytes = {k: 0 for k in _residency.REPR_CLASSES}
 
     def drop_index(self, index_name: str) -> None:
         """Evict one index's entries from the stack/filter/plan caches.
         Compiled programs (`_fn_cache`) are structural — not tied to any
         index — and are kept; this is what lets the QoS warmup service
         discard its scratch index without losing the warmed kernels."""
-        with self._cache_lock:
-            for key in [k for k in self._stack_cache if k[0] == index_name]:
-                nb = _residency.stack_nbytes(self._stack_cache.pop(key)[2])
-                self._cache_bytes -= nb
-                self._class_bytes[key[6]] -= nb
+        self.stacks.drop_index(index_name)
+        with self._plan_lock:
             for key in [k for k in self._filter_host_cache
                         if k[0] == index_name]:
                 del self._filter_host_cache[key]
@@ -1043,31 +1026,24 @@ class MeshPlanner:
         """The structural query shapes this planner compiled for, oldest
         first — what ServerNode persists to warmup.json at shutdown so
         the next boot can precompile the programs real traffic hit."""
-        with self._cache_lock:
+        with self._plan_lock:
             return [{"index": i, "query": q, "shards": s, "count": n}
                     for (i, q, s), n in self._observed.items()]
 
     def close(self) -> None:
-        """Release caches and stop the prefetcher + coalescer + batcher
-        threads."""
-        self.prefetcher.close()
+        """Release caches and stop the upload workers + coalescer +
+        batcher threads."""
+        self.stacks.close()
         self.coalescer.close()
         self.invalidate()
         self.batcher.close()
 
     def cache_stats(self) -> dict:
         """Locked snapshot of HBM-cache occupancy for monitoring."""
-        with self._cache_lock:
-            out = {"bytes": self._cache_bytes,
-                   "budget_bytes": self.max_cache_bytes,
-                   "entries": len(self._stack_cache),
-                   "evictions": self._cache_evictions,
-                   "uploads": self._uploads,
-                   "upload_bytes": self._upload_bytes,
-                   "bucket_policy": self.bucket_policy,
-                   "class_bytes": dict(self._class_bytes),
-                   "residency_mode": _residency.mode(),
-                   "programs": len(self._fn_cache)}
+        out = self.stacks.snapshot()
+        out["bucket_policy"] = self.bucket_policy
+        out["residency_mode"] = _residency.mode()
+        out["programs"] = len(self._fn_cache)
         with self._dispatch_lock:
             out["dispatches"] = self.dispatches
             out["dispatches_coalesced"] = self.dispatches_coalesced
@@ -1083,7 +1059,7 @@ class MeshPlanner:
             out["batch_width_hist"] = self._width_hist.snapshot()
         out["queue_depth"] = self.coalescer.queue_depth()
         out["transfer"] = self.batcher.debug()
-        out["prefetch"] = self.prefetcher.debug()
+        out["prefetch"] = self.stacks.upload_stats()
         # WHICH device: the platform is the whole proof that a kernel
         # ran compiled and not interpreted (ops/pallas_kernels), and the
         # per-device split shows whether stacks really spread over the
@@ -1092,13 +1068,8 @@ class MeshPlanner:
         out["platform"] = devices[0].platform
         out["deviceKind"] = devices[0].device_kind
         out["deviceCount"] = len(devices)
-        per_device = {str(d): 0 for d in devices}
-        with self._cache_lock:
-            arrays = [entry[2] for entry in self._stack_cache.values()]
-        for arr in arrays:
-            for shard in arr.addressable_shards:
-                per_device[str(shard.device)] += int(shard.data.nbytes)
-        out["perDeviceBytes"] = per_device
+        out["perDeviceBytes"] = {**{str(d): 0 for d in devices},
+                                 **self.stacks.per_device_bytes()}
         out["compileCache"] = compile_cache.stats()
         return out
 
@@ -1291,105 +1262,34 @@ class MeshPlanner:
                     shards: tuple,
                     klass: str = _residency.DENSE) -> jax.Array:
         """Stack of one row across shards, device-put with the shard
-        sharding; cached until any involved fragment mutates. ``klass``
-        picks the representation: dense [S_pad, W] uint32 planes or a
-        packed [S_pad, K] int32 index stack (exec/residency) — each
-        class is its own cache entry (the key's last element), with the
-        same validation and the shared budget.
-
-        Validation is two-tier: an O(1) index-epoch compare on the hot
-        path, falling back to the per-fragment generation walk only when
-        the epoch moved (a write anywhere in the index) — if the walk
-        shows this leaf's fragments unchanged, the entry is re-stamped
-        instead of re-uploaded."""
-        # instance_id: a deleted-and-recreated index restarts its epoch,
-        # so name alone could serve the old index's stacks as fresh.
-        key = (idx.name, idx.instance_id, field_name, view, row_id, shards,
-               klass)
-        epoch = idx.epoch.value
-        with self._cache_lock:
-            hit = self._stack_cache.get(key)
-            if hit is not None:
-                if hit[0] == epoch:
-                    self._stack_cache.move_to_end(key)
-                    return hit[2]
-                gens = self._gens(idx.name, field_name, view, shards)
-                if gens == hit[1]:
-                    self._stack_cache[key] = (epoch, gens, hit[2])
-                    self._stack_cache.move_to_end(key)
-                    return hit[2]
-            else:
-                gens = None
-        # Pipelined miss path: if a prefetch worker is already uploading
-        # this stack, wait for it to land and re-read the cache — the
-        # wait is a prefetch HIT, not a synchronous upload. Workers skip
-        # the rendezvous (they ARE the inflight upload; waiting on their
-        # own key would deadlock) and their builds aren't misses.
-        if not self.prefetcher.is_worker():
-            # Re-check the cache even when no upload was in flight: it
-            # may have completed between our miss and the rendezvous.
-            self.prefetcher.wait(key)
-            with self._cache_lock:
-                hit = self._stack_cache.get(key)
-                if hit is not None and hit[0] == epoch:
-                    self._stack_cache.move_to_end(key)
-                    return hit[2]
-            self.prefetcher.note_sync_miss()
-        # Build outside the lock: row materialization + device_put can be
-        # slow, and fragments have their own locks. Two threads may race
-        # to build the same stack; the second insert simply wins.
-        if gens is None:
-            gens = self._gens(idx.name, field_name, view, shards)
+        sharding; resident until any involved fragment mutates
+        (parallel.stacks has the validation, the budget and the
+        rendezvous with an upload in flight). ``klass`` picks the
+        representation: dense [S_pad, W] uint32 planes or a packed
+        [S_pad, K] int32 index stack (exec/residency) — each class is
+        its own entry, under the shared budget."""
+        key = StackKey(idx.name, idx.instance_id, field_name, view, row_id,
+                       shards, klass)
+        # The resident hit makes no closure.
+        arr = self.stacks.get(key, idx.epoch.value)
+        if arr is not None:
+            return arr
         build = (self._build_stack_packed if klass == _residency.PACKED
                  else self._build_stack)
-        with start_span("stack.build", stats=self.stats):
-            upload, nbytes = build(idx, field_name, view, row_id, shards)
-        with start_span("stack.upload", stats=self.stats):
-            arr = upload()
-            # upload holds the host matrix (128 MiB for a dense stack):
-            # let go of it before the eviction work, not after. The
-            # runtime keeps its own reference until the transfer has
-            # read the matrix; only then does the chunk go back to the
-            # page pool (scripts/stack_readback_check.py).
-            del upload
-            self._insert_stack(key, epoch, gens, arr, nbytes)
-        return arr
+        return self._resident(
+            idx, key,
+            functools.partial(build, idx, field_name, view, row_id, shards),
+            staged=True)
 
-    def _insert_stack(self, key: tuple, epoch: int, gens: tuple, arr,
-                      nbytes: int, count_upload: bool = True) -> None:
-        """THE one cache-insertion/byte-accounting path for every
-        representation class (the hand-expanded nbytes loops this
-        replaces could drift the eviction budget independently).
-        Eviction is double-buffered: the new stack is inserted FIRST
-        and the LRU victims dropped after, so the upload that produced
-        ``arr`` overlapped the evictee's last use instead of
-        serializing behind the eviction (the transient overshoot is one
-        stack). The class is the key's last element; per-class bytes
-        feed /debug/device."""
-        klass = key[6]
-        with self._cache_lock:
-            if count_upload:
-                self._uploads += 1
-                self._upload_bytes += nbytes
-            old = self._stack_cache.pop(key, None)
-            if old is not None:
-                old_nb = _residency.stack_nbytes(old[2])
-                self._cache_bytes -= old_nb
-                self._class_bytes[klass] -= old_nb
-            self._stack_cache[key] = (epoch, gens, arr)
-            self._cache_bytes += nbytes
-            self._class_bytes[klass] += nbytes
-            while (self._cache_bytes > self.max_cache_bytes
-                   and len(self._stack_cache) > 1):
-                k2, (_, _, dropped) = self._stack_cache.popitem(last=False)
-                nb = _residency.stack_nbytes(dropped)
-                self._cache_bytes -= nb
-                self._class_bytes[k2[6]] -= nb
-                self._cache_evictions += 1
-            class_bytes = dict(self._class_bytes)
-        if self.stats is not None:
-            for k, v in class_bytes.items():
-                self.stats.gauge(f"planner.residentBytes.{k}", v)
+    def _resident(self, idx: Index, key: StackKey, build: Callable, **how):
+        """``key``'s array from the store, validated against the index's
+        epoch and the generations of the key's fragments; ``build`` and
+        ``how`` as `StackStore.get_or_build` takes them."""
+        return self.stacks.get_or_build(
+            key, idx.epoch.value,
+            functools.partial(self._gens, key.index, key.field, key.view,
+                              key.shards),
+            build, **how)
 
     #: rows with at most this many set bits upload as COO triplets
     #: (~12 B/word touched) instead of the 128 KiB dense block. The
@@ -1565,30 +1465,20 @@ class MeshPlanner:
 
     def _prefetch_leaves(self, idx: Index, leaves: list,
                          shards: tuple) -> None:
-        """Pipelined miss path (tentpole front 2): peek the plan's FULL
-        leaf set before execution and issue async uploads for every
-        non-resident stack, so the query thread's later fetches only
-        ever wait on uploads already in flight (prefetch hits) instead
-        of starting their own (synchronous misses). The prefetcher's
-        inflight table dedupes by stack key, so coalesced waves of
-        same-plan queries prefetch the union of their leaves at the
-        cost of one upload each."""
-        if not (shards and self.prefetch_supported
-                and self.prefetcher.enabled()):
+        """Peek the plan's full leaf set before execution and schedule
+        an upload for every stack that is not resident and current, so
+        the request's later fetches wait on uploads already in flight
+        instead of starting their own."""
+        if not (shards and self.stacks.uploads_ahead):
             return
         epoch = idx.epoch.value
         for field_name, view, row_id, klass in self._leaf_stack_specs(
                 idx, leaves, shards):
-            key = (idx.name, idx.instance_id, field_name, view, row_id,
-                   shards, klass)
-            with self._cache_lock:
-                hit = self._stack_cache.get(key)
-                if hit is not None and hit[0] == epoch:
-                    continue  # resident and current
-            self.prefetcher.schedule(
-                key,
-                functools.partial(self._stack_rows, idx, field_name, view,
-                                  row_id, shards, klass))
+            self.stacks.schedule(
+                StackKey(idx.name, idx.instance_id, field_name, view, row_id,
+                         shards, klass),
+                epoch, self._stack_rows, idx, field_name, view, row_id,
+                shards, klass)
 
     def _zeros_stack(self, n_shards: int) -> jax.Array:
         s_pad = self._pad(n_shards)
@@ -1717,69 +1607,33 @@ class MeshPlanner:
         stacked once and cached with the same two-tier (epoch, then
         per-fragment generation) validation as _stack_rows."""
         view = view_bsi_name(field_name)
-        key = (idx.name, idx.instance_id, field_name, view,
-               ("planes", depth), shards, _residency.DENSE)
-        epoch = idx.epoch.value
-        with self._cache_lock:
-            hit = self._stack_cache.get(key)
-            if hit is not None:
-                if hit[0] == epoch:
-                    self._stack_cache.move_to_end(key)
-                    return hit[2]
-                gens = self._gens(idx.name, field_name, view, shards)
-                if gens == hit[1]:
-                    self._stack_cache[key] = (epoch, gens, hit[2])
-                    self._stack_cache.move_to_end(key)
-                    return hit[2]
-            else:
-                gens = None
-        if gens is None:
-            gens = self._gens(idx.name, field_name, view, shards)
-        from pilosa_tpu.core.fragment import BSI_OFFSET_BIT
-        bits = [self._stack_rows(idx, field_name, view, BSI_OFFSET_BIT + i,
-                                 shards)
-                for i in range(depth)]
-        if bits:
-            arr = jnp.stack(bits, axis=0)
-        else:
+
+        def build() -> jax.Array:
+            from pilosa_tpu.core.fragment import BSI_OFFSET_BIT
+            bits = [self._stack_rows(idx, field_name, view,
+                                     BSI_OFFSET_BIT + i, shards)
+                    for i in range(depth)]
+            if bits:
+                return jnp.stack(bits, axis=0)
             zero = self._fetch_leaf(idx, ("zero",), shards)
-            arr = jnp.zeros((0,) + zero.shape, zero.dtype)
+            return jnp.zeros((0,) + zero.shape, zero.dtype)
+
         # count_upload=False: the cube is stacked from already-uploaded
         # (and upload-counted) per-plane rows — no new link traffic.
-        self._insert_stack(key, epoch, gens, arr,
-                           _residency.stack_nbytes(arr),
-                           count_upload=False)
-        return arr
+        return self._resident(
+            idx, StackKey(idx.name, idx.instance_id, field_name, view,
+                          ("planes", depth), shards, _residency.DENSE),
+            build, count_upload=False)
 
     def _hll_stack(self, idx: Index, field_name: str, tag: tuple,
                    shards: tuple, build) -> jax.Array:
-        """Shared cache protocol for the sketch stacks: the same
-        two-tier (epoch, then per-fragment generation) validation as
-        _stack_rows, keyed under the ``hll`` representation class so
-        /debug/device accounts their HBM separately."""
+        """The sketch stacks, keyed under the ``hll`` representation
+        class so /debug/device accounts their HBM separately."""
         view = view_bsi_name(field_name)
-        key = (idx.name, idx.instance_id, field_name, view, tag, shards,
-               _residency.HLL)
-        epoch = idx.epoch.value
-        with self._cache_lock:
-            hit = self._stack_cache.get(key)
-            if hit is not None:
-                if hit[0] == epoch:
-                    self._stack_cache.move_to_end(key)
-                    return hit[2]
-                gens = self._gens(idx.name, field_name, view, shards)
-                if gens == hit[1]:
-                    self._stack_cache[key] = (epoch, gens, hit[2])
-                    self._stack_cache.move_to_end(key)
-                    return hit[2]
-            else:
-                gens = None
-        if gens is None:
-            gens = self._gens(idx.name, field_name, view, shards)
-        arr = build(view)
-        self._insert_stack(key, epoch, gens, arr,
-                           _residency.stack_nbytes(arr))
-        return arr
+        return self._resident(
+            idx, StackKey(idx.name, idx.instance_id, field_name, view, tag,
+                          shards, _residency.HLL),
+            functools.partial(build, view))
 
     def _stack_hll_planes(self, idx: Index, field_name: str, depth: int,
                           p: int, shards: tuple) -> jax.Array:
@@ -1825,35 +1679,20 @@ class MeshPlanner:
         (SimilarTopN), stacked from the per-row cached stacks and
         cached itself under the same validation; zero padding rows rank
         with overlap 0 and are sliced off in the host fold."""
-        view = VIEW_STANDARD
-        key = (idx.name, idx.instance_id, field_name, view,
-               ("simcube", row_ids, r_pad), shards, _residency.DENSE)
-        epoch = idx.epoch.value
-        with self._cache_lock:
-            hit = self._stack_cache.get(key)
-            if hit is not None:
-                if hit[0] == epoch:
-                    self._stack_cache.move_to_end(key)
-                    return hit[2]
-                gens = self._gens(idx.name, field_name, view, shards)
-                if gens == hit[1]:
-                    self._stack_cache[key] = (epoch, gens, hit[2])
-                    self._stack_cache.move_to_end(key)
-                    return hit[2]
-            else:
-                gens = None
-        if gens is None:
-            gens = self._gens(idx.name, field_name, view, shards)
-        bits = [self._stack_rows(idx, field_name, view, rid, shards)
-                for rid in row_ids]
-        zero = self._zeros_stack(len(shards))
-        bits.extend(zero for _ in range(r_pad - len(bits)))
-        arr = jnp.stack(bits, axis=0)
+        def build() -> jax.Array:
+            bits = [self._stack_rows(idx, field_name, VIEW_STANDARD, rid,
+                                     shards)
+                    for rid in row_ids]
+            zero = self._zeros_stack(len(shards))
+            bits.extend(zero for _ in range(r_pad - len(bits)))
+            return jnp.stack(bits, axis=0)
+
         # count_upload=False: stacked from already-counted row uploads.
-        self._insert_stack(key, epoch, gens, arr,
-                           _residency.stack_nbytes(arr),
-                           count_upload=False)
-        return arr
+        return self._resident(
+            idx, StackKey(idx.name, idx.instance_id, field_name,
+                          VIEW_STANDARD, ("simcube", row_ids, r_pad), shards,
+                          _residency.DENSE),
+            build, count_upload=False)
 
     # ------------------------------------------------------------------
     # compile: signature → jitted evaluator
@@ -1875,12 +1714,8 @@ class MeshPlanner:
             with jax.named_scope("tree_eval"):
                 return _eval_node(sig, args)
 
-        is_pallas = False
         if reduce == "per_shard":
-            program = self._pallas_count_program(sig)
-            is_pallas = program is not None
-            if program is None:
-                program = _packed_count_program(sig)
+            program = _packed_count_program(sig)
             if program is None:
                 def program(*args):
                     tree = evaluate(args)
@@ -1894,62 +1729,8 @@ class MeshPlanner:
         fn = self._jit_program(_named(program, f"{klass}_{n_leaves}"),
                                reduce)
         self._fn_cache[full_sig] = fn
-        # Pallas kernels are not vmappable: register raw=None so the
-        # coalescer falls back to per-entry launches for them.
-        self._register_fn(fn, full_sig, None if is_pallas else program)
+        self._register_fn(fn, full_sig, program)
         return fn
-
-    def _pallas_count_enabled(self) -> bool:
-        """Kernel selection for the Count fast path.
-        PILOSA_TPU_PALLAS_COUNT: "1" forces Pallas (measurement runs);
-        "auto" picks Pallas only where PILOSA_TPU_PALLAS_VS_XLA states a
-        measured ratio > 1 for the machine at hand (no ratio is baked
-        in: without one, "auto" behaves as off); anything else keeps the
-        XLA-fused default. bench.py's pallas_vs_xla A/B is where such a
-        ratio comes from."""
-        import os as _os
-
-        from pilosa_tpu.ops import pallas_kernels as pk
-        mode = _os.environ.get("PILOSA_TPU_PALLAS_COUNT", "")
-        if mode == "auto":
-            try:
-                ratio = float(_os.environ.get("PILOSA_TPU_PALLAS_VS_XLA", ""))
-            except ValueError:
-                return False
-            if ratio <= 1.0:
-                return False
-        elif mode != "1":
-            return False
-        return (pk.available() and jax.default_backend() == "tpu"
-                and self.n_devices == 1)
-
-    def _pallas_count_program(self, sig: tuple):
-        """Fused Pallas count for the hottest shapes — a bare row and a
-        2-leaf binary op (the headline Count(Intersect(Row,Row))): the
-        VMEM-tiled op+popcount+rowsum kernel. OPT-IN
-        (PILOSA_TPU_PALLAS_COUNT=1): Pallas against XLA's own fusion is
-        not measured on the current machine, so the default stays with
-        XLA (bench pallas_vs_xla is the A/B). Also gated to a
-        SINGLE-device TPU mesh: off-TPU pallas runs in interpret mode
-        (every CPU-mesh test's Count would become an interpreter loop),
-        and on a multi-device mesh a pallas_call has no partitioning
-        rule, so GSPMD would all-gather the sharded leaf stacks instead
-        of counting shard-locally (a shard_map wrapping is the
-        multi-chip path)."""
-        from pilosa_tpu.ops import pallas_kernels as pk
-        if not self._pallas_count_enabled():
-            return None
-        if sig[0] == "leaf":
-            slot = sig[1]
-            return lambda *args: pk.row_counts(args[slot])
-        ops = {"intersect": "and", "union": "or", "xor": "xor",
-               "difference": "andnot"}
-        if (sig[0] in ops and len(sig) == 2 and len(sig[1]) == 2
-                and all(k[0] == "leaf" for k in sig[1])):
-            i, j = sig[1][0][1], sig[1][1][1]
-            op = ops[sig[0]]
-            return lambda *args: pk.pair_count(args[i], args[j], op)
-        return None
 
     def _jit_program(self, program: Callable, reduce: str | None) -> Callable:
         """jit hook: the distributed planner replicates ``per_shard``

@@ -29,8 +29,8 @@ logger = logging.getLogger("pilosa_tpu.qos")
 
 SCRATCH_INDEX = "qos-warmup-scratch"
 
-#: canonical kernel families; the default set mirrors what BENCH_r05
-#: shows paying cold-compile latency.
+#: canonical kernel families: the query classes whose first request
+#: would otherwise pay a compile.
 KIND_COUNT = "count"
 KIND_TOPN = "topn"
 KIND_BSI = "bsi"
@@ -38,8 +38,8 @@ DEFAULT_KINDS = (KIND_COUNT, KIND_TOPN, KIND_BSI)
 
 DEFAULT_SHARD_COUNTS = (1, 8, 32)
 
-#: matches the bench BSI field range (bench.py seeds values ~1e6);
-#: BSI compiles are depth-shaped, so warm the common depth.
+#: BSI compiles are depth-shaped, so warm one common depth (values
+#: up to ~1e6).
 _INT_MAX = 1 << 20
 
 _QUERIES = {

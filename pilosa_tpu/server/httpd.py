@@ -1077,7 +1077,7 @@ def _build_routes(api: API):
     def get_debug_heap(pv, params, body):
         """One-stop memory accounting: tracemalloc top sites + native
         pool + planner HBM cache + per-index host-row bytes (reference
-        /debug/pprof heap, http/handler.go:281; VERDICT r4 #3)."""
+        /debug/pprof heap, http/handler.go:281)."""
         from pilosa_tpu.obs.heap import heap_stats
         top_n = min(max(int(params.get("top", 25)), 1), 200)
         return 200, heap_stats(api.holder,

@@ -26,9 +26,9 @@ from pilosa_tpu.server.httpd import HTTPServer
 class ServerNode:
     """A runnable node (reference `pilosa server`, cmd/server.go:64)."""
 
-    #: default repair cadence, seconds (VERDICT r2 #10: repair must be ON
-    #: by default — a killed-and-restarted node converges with no
-    #: operator action). The reference's default is 10 minutes
+    #: default repair cadence, seconds (repair must be ON by default —
+    #: a killed-and-restarted node converges with no operator
+    #: action). The reference's default is 10 minutes
     #: (server.go antiEntropyInterval); ours is short because repairs
     #: are cheap host diffs.
     DEFAULT_ANTI_ENTROPY_INTERVAL = 10.0
@@ -97,7 +97,6 @@ class ServerNode:
                  dispatch_coalesce_us: float = 150.0,
                  inline_transfer: str = "auto",
                  residency_packed: str = "auto",
-                 prefetch: str = "on",
                  translate_planes: str = "auto",
                  sketch_precision: int = 12,
                  sketch_exact_threshold: int = 1024,
@@ -360,14 +359,11 @@ class ServerNode:
         _dispatch_coalesce.set_mode(dispatch_coalesce)
         from pilosa_tpu.parallel import batcher as _transfer_batcher
         _transfer_batcher.set_inline_mode(inline_transfer)
-        # Device-residency knobs (README "Device residency & prefetch"):
-        # container-classed packed leaf stacks and the pipelined async
-        # miss path. Env vars PILOSA_TPU_RESIDENCY_PACKED /
-        # PILOSA_TPU_PREFETCH override per-run.
+        # Device-residency knob (README "Device residency"):
+        # container-classed packed leaf stacks. Env var
+        # PILOSA_TPU_RESIDENCY_PACKED overrides per-run.
         from pilosa_tpu.exec import residency as _residency
         _residency.set_mode(residency_packed)
-        from pilosa_tpu.parallel import prefetch as _prefetch
-        _prefetch.set_mode(prefetch)
         # Key-translation planes (README "Key translation"); env var
         # PILOSA_TPU_TRANSLATE_PLANES overrides per-run.
         from pilosa_tpu.exec import keyplane as _keyplane

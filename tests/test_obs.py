@@ -892,7 +892,7 @@ def test_prefetch_worker_span_reaches_its_planners_registry_only():
     try:
         e = Executor(h, planner=p_mine, result_cache=False, stats=mine)
         assert e.execute("pw", "Count(Row(f=1))", shards=[0, 1, 2, 3]) == [64]
-        dbg = p_mine.prefetcher.debug()
+        dbg = p_mine.stacks.upload_stats()
         assert dbg["completed"] >= 1 and dbg["sync_misses"] == 0
         for name in ("stack.build", "stack.upload"):
             assert mine.counter_value(f"span.{name}.count") == \

@@ -26,12 +26,6 @@ def test_pair_count(pair, op, oracle):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(oracle(a, b)))
 
 
-def test_row_counts(pair):
-    a, _ = pair
-    got = pallas_kernels.row_counts(a)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(bitops.count(a)))
-
-
 def test_pair_count_3d(pair):
     a, b = pair
     a3 = jnp.stack([a, b])

@@ -422,10 +422,10 @@ def test_shift_full_range_device_vs_oracle(mesh):
 
 def _stack_key_rows(planner):
     """row ids currently resident, in LRU order (oldest first)."""
-    return [k[4] for k in planner._stack_cache]
+    return [k.tag for k in planner.stacks.keys()]
 
 
-def test_stack_cache_evicts_lru_and_accounts_bytes(mesh, rng, monkeypatch):
+def test_stack_store_evicts_lru_and_accounts_bytes(mesh, rng, monkeypatch):
     # These rows are sparse enough to pack under residency auto mode;
     # the exact byte arithmetic below is the dense class's contract.
     monkeypatch.setenv("PILOSA_TPU_RESIDENCY_PACKED", "off")
@@ -469,7 +469,7 @@ def test_stack_cache_evicts_lru_and_accounts_bytes(mesh, rng, monkeypatch):
         assert c == counts[r]
 
 
-def test_stack_cache_eviction_does_not_break_inflight_refs(mesh, rng,
+def test_stack_store_eviction_does_not_break_inflight_refs(mesh, rng,
                                                            monkeypatch):
     """An evicted entry's device array may still be referenced by an
     in-flight prepared plan; eviction only drops the cache's ref, so
@@ -506,42 +506,6 @@ def test_stack_cache_eviction_does_not_break_inflight_refs(mesh, rng,
     # ...and a fresh prepare re-resolves leaves through the cache.
     fn2, arrays2 = planner.prepare_count(idx, call, shards)
     assert planner._sum_host(np.asarray(fn2(*arrays2))) == want
-
-
-def test_pallas_count_program_wiring(rng):
-    """The opt-in fused count path's slot/op wiring, exercised on CPU
-    (gate forced on; pallas falls back to interpret mode off-TPU, tiny
-    shapes keep it fast). Guards the args[i]/args[j] leaf-slot indexing
-    and the op table against silent regressions that would otherwise
-    only surface on an operator's TPU rig with PILOSA_TPU_PALLAS_COUNT
-    set."""
-    h = Holder()
-    idx = h.create_index("pc")
-    f = idx.create_field("f")
-    g = idx.create_field("g")
-    total = SHARD_WIDTH
-    f.import_bits(rng.integers(0, 3, 3000), rng.integers(0, total, 3000))
-    g.import_bits(rng.integers(0, 3, 3000), rng.integers(0, total, 3000))
-    planner = MeshPlanner(h, make_mesh(n=1))
-    planner._pallas_count_enabled = lambda: True
-    fast = Executor(h, planner=planner, result_cache=False)
-    scalar = Executor(h)
-    queries = ["Count(Row(f=1))",
-               "Count(Intersect(Row(f=1), Row(g=2)))",
-               "Count(Union(Row(f=0), Row(g=0)))",
-               "Count(Xor(Row(f=1), Row(g=1)))",
-               "Count(Difference(Row(f=2), Row(g=2)))"]
-    for q in queries:
-        (got,) = fast.execute("pc", q, cache=False)
-        (want,) = scalar.execute("pc", q, cache=False)
-        assert got == want, (q, got, want)
-    # The fused program really was selected for these shapes.
-    assert planner._pallas_count_program(("leaf", 0)) is not None
-    assert planner._pallas_count_program(
-        ("intersect", (("leaf", 0), ("leaf", 1)))) is not None
-    # Deeper trees fall back to the generic XLA program.
-    assert planner._pallas_count_program(
-        ("not", 0, ("leaf", 1))) is None
 
 
 def test_sparse_upload_stack_matches_dense(rng):

@@ -1,5 +1,5 @@
 """Device residency tests: container-classed stacks (exec/residency)
-and the pipelined prefetch miss path (parallel/prefetch).
+and the uploads that run ahead of the requests (parallel/stacks).
 
 The contract mirrors the reference's roaring container-class tests
 (roaring_internal_test.go: array/bitmap conversions are bit-exact):
@@ -22,7 +22,6 @@ from pilosa_tpu.exec import Executor
 from pilosa_tpu.exec import residency
 from pilosa_tpu.ops import bitops
 from pilosa_tpu.parallel import MeshPlanner, make_mesh
-from pilosa_tpu.parallel import prefetch as prefetch_mod
 
 
 @pytest.fixture(scope="module")
@@ -62,8 +61,6 @@ def test_choose_class_per_mode(monkeypatch):
 def test_mode_knob_validates_and_env_wins(monkeypatch):
     with pytest.raises(ValueError):
         residency.set_mode("sometimes")
-    with pytest.raises(ValueError):
-        prefetch_mod.set_mode("maybe")
     try:
         monkeypatch.delenv("PILOSA_TPU_RESIDENCY_PACKED", raising=False)
         residency.set_mode("on")
@@ -188,9 +185,9 @@ def _run_suite(h, mesh, mode_name, monkeypatch):
     shards = list(range(N_SHARDS))
     try:
         out = [e.execute("rq", q, shards=shards) for q in EQ_QUERIES]
-        classes = {k[6] for k in planner._stack_cache}
-        n_packed = sum(1 for k in planner._stack_cache
-                       if k[6] == residency.PACKED)
+        classes = {k.klass for k in planner.stacks.keys()}
+        n_packed = sum(1 for k in planner.stacks.keys()
+                       if k.klass == residency.PACKED)
         cls_bytes = planner.cache_stats()["class_bytes"]
     finally:
         planner.close()
@@ -275,7 +272,7 @@ def test_auto_high_cardinality_rows_stay_dense(mesh, monkeypatch):
     try:
         e.execute("hc", "Count(Row(f=0))", shards=shards)
         e.execute("hc", "Count(Row(f=1))", shards=shards)
-        by_row = {k[4]: k[6] for k in planner._stack_cache}
+        by_row = {k.tag: k.klass for k in planner.stacks.keys()}
         assert by_row[0] == residency.PACKED
         assert by_row[1] == residency.DENSE    # fell back, as documented
         st = planner.cache_stats()
@@ -289,11 +286,10 @@ def test_auto_high_cardinality_rows_stay_dense(mesh, monkeypatch):
 
 
 def test_oversubscribed_prefetch_no_sync_uploads(mesh, monkeypatch):
-    """Working set > device budget with prefetch on: eviction churns,
-    yet every query-thread miss rendezvouses with an inflight upload —
-    zero synchronous uploads on the query path (the BENCH_r05 cliff)."""
+    """Working set > device budget: eviction churns, yet every
+    query-thread miss rendezvouses with an inflight upload — zero
+    synchronous uploads on the query path."""
     monkeypatch.setenv("PILOSA_TPU_RESIDENCY_PACKED", "off")  # dense bytes
-    monkeypatch.setenv("PILOSA_TPU_PREFETCH", "on")
     h = Holder()
     idx = h.create_index("ov")
     f = idx.create_field("f")
@@ -311,7 +307,7 @@ def test_oversubscribed_prefetch_no_sync_uploads(mesh, monkeypatch):
             for r in range(6):
                 e.execute("ov", f"Count(Row(f={r}))", shards=shards)
         assert planner.cache_stats()["evictions"] > 0
-        dbg = planner.prefetcher.debug()
+        dbg = planner.stacks.upload_stats()
         assert dbg["sync_misses"] == 0
         assert dbg["hits"] > 0
         assert dbg["completed"] == dbg["scheduled"] >= 6
@@ -320,17 +316,20 @@ def test_oversubscribed_prefetch_no_sync_uploads(mesh, monkeypatch):
         planner.close()
 
 
-def test_prefetch_off_counts_sync_misses(mesh, monkeypatch):
-    monkeypatch.setenv("PILOSA_TPU_PREFETCH", "off")
+def test_prefetch_off_counts_sync_misses(mesh):
+    class InLockstep(MeshPlanner):
+        """As DistributedMeshPlanner: no upload runs ahead."""
+        UPLOADS_AHEAD = False
+
     h = Holder()
     idx = h.create_index("sy")
     f = idx.create_field("f")
     f.import_bits(np.full(100, 0), np.arange(100))
-    planner = MeshPlanner(h, mesh)
+    planner = InLockstep(h, mesh)
     e = Executor(h, planner=planner, result_cache=False)
     try:
         e.execute("sy", "Count(Row(f=0))", shards=[0])
-        dbg = planner.prefetcher.debug()
+        dbg = planner.stacks.upload_stats()
         assert dbg["scheduled"] == 0
         assert dbg["sync_misses"] >= 1
     finally:

@@ -178,12 +178,6 @@ def test_pallas_pair_count_compiles(one_chip, op):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_pallas_row_counts_compiles(one_chip):
-    c = pk._pallas_row_counts.lower(_stack(one_chip),
-                                    interpret=False).compile()
-    assert "tpu_custom_call" in c.as_text()
-
-
 @pytest.mark.parametrize("rows", [ROW_TILE, 8, 5])
 def test_fragment_sweep_pair_count_compiles(one_chip, rows):
     """pair_count as core/fragment.intersection_counts_async calls it

@@ -248,7 +248,7 @@ def test_plane_rebuilds_on_version_bump(monkeypatch):
     h, idx = _keyed_idx()
     store = idx.translate_store
     ida, idb = store.translate_keys(["a", "b"])
-    cache = kp.KeyPlaneCache(planner=None)
+    cache = kp.KeyPlaneCache(stacks=None)
     assert cache.lookup(idx, None, store, ["a", "b"]) == [ida, idb]
     assert cache.builds == 1
     # Same version: plane reused, no rebuild.
@@ -270,7 +270,7 @@ def test_plane_auto_serves_stale_and_small_batches_host(monkeypatch):
     store = idx.translate_store
     keys = [f"k{i}" for i in range(kp.MIN_DEVICE_BATCH)]
     ids = store.translate_keys(keys)
-    cache = kp.KeyPlaneCache(planner=None)
+    cache = kp.KeyPlaneCache(stacks=None)
     monkeypatch.setenv("PILOSA_TPU_TRANSLATE_PLANES", "on")
     assert cache.lookup(idx, None, store, keys) == ids   # build plane
     monkeypatch.setenv("PILOSA_TPU_TRANSLATE_PLANES", "auto")
@@ -302,7 +302,7 @@ def test_plane_collision_bucket(monkeypatch):
     mat, collisions, valid = kp.build_plane(store.snapshot()[1])
     assert set(collisions) == {"x", "y"}
     assert valid == 2
-    cache = kp.KeyPlaneCache(planner=None)
+    cache = kp.KeyPlaneCache(stacks=None)
     got = cache.lookup(idx, None, store, ["x", "y", "a", "b", "nope"])
     assert got == idx_ids + [None]
     assert cache.collision_hits == 2
