@@ -31,6 +31,9 @@ void scatter_row_blocks(const uint64_t* cols, int64_t n, int exp,
                         uint32_t* blocks, int64_t n_shards,
                         int64_t words_per_shard, uint8_t* touched,
                         int64_t* block_counts);
+int positions_to_rows(uint32_t* mat, int64_t n_rows, int64_t n_words,
+                      const uint64_t* src, const int64_t* len,
+                      const int64_t* dst, int64_t n);
 int scatter_bsi_blocks(const uint64_t* cols, const int64_t* vals,
                        int64_t n, int exp, int depth, uint32_t* blocks,
                        int64_t n_shards, int64_t words_per_shard,
@@ -246,6 +249,58 @@ void scatter_case() {
                      bcounts.data());
 }
 
+// Sanitizer exercise of the one-call stack build: random sources, some
+// positions at or past the row width (ignored, never written), and now
+// and then a destination row outside the matrix or a negative length,
+// which must be refused before anything is written.
+void rows_case() {
+  int64_t n_words = 1 + rnd() % 512;
+  int64_t n_rows = 1 + rnd() % 24;
+  int64_t n = rnd() % 40;
+  std::vector<std::vector<uint64_t>> rows(n);
+  std::vector<uint64_t> src(n);
+  std::vector<int64_t> len(n), dst(n);
+  uint64_t span = static_cast<uint64_t>(n_words) * 32;
+  for (int64_t k = 0; k < n; k++) {
+    rows[k].resize(rnd() % 3000);
+    for (auto& p : rows[k])
+      p = (rnd() % 9 == 0) ? span + rnd() % (1ULL << (rnd() % 58))
+                           : rnd() % span;
+    src[k] = reinterpret_cast<uintptr_t>(rows[k].data());
+    len[k] = static_cast<int64_t>(rows[k].size());
+    dst[k] = rnd() % n_rows;
+  }
+  bool bad = n > 0 && rnd() % 4 == 0;
+  if (bad) {
+    int64_t k = rnd() % n;
+    switch (rnd() % 3) {
+      case 0: dst[k] = n_rows + rnd() % 5; break;
+      case 1: dst[k] = -1 - static_cast<int64_t>(rnd() % 5); break;
+      default: len[k] = -1 - static_cast<int64_t>(rnd() % 5);
+    }
+  }
+  std::vector<uint32_t> mat(n_rows * n_words, 0);
+  int rc = positions_to_rows(mat.data(), n_rows, n_words, src.data(),
+                             len.data(), dst.data(), n);
+  if (bad) {
+    bool written = std::any_of(mat.begin(), mat.end(),
+                               [](uint32_t w) { return w != 0; });
+    if (rc == 0 || written) {
+      fprintf(stderr, "positions_to_rows took a row outside the matrix\n");
+      abort();
+    }
+    return;
+  }
+  std::vector<uint32_t> want(n_rows * n_words, 0);
+  for (int64_t k = 0; k < n; k++)
+    for (uint64_t p : rows[k])
+      if (p < span) want[dst[k] * n_words + (p >> 5)] |= 1u << (p & 31);
+  if (rc != 0 || mat != want) {
+    fprintf(stderr, "positions_to_rows: wrong matrix\n");
+    abort();
+  }
+}
+
 }  // namespace
 
 int main(int argc, char** argv) {
@@ -260,6 +315,7 @@ int main(int argc, char** argv) {
     }
     one_case(buf, valid);
     if (i % 2000 == 0) scatter_case();
+    if (i % 50 == 0) rows_case();
   }
   printf("fuzz_roaring: %ld iterations clean\n", iters);
   return 0;

@@ -12,6 +12,8 @@
 //   roaring_encode_bound(pos_u64, n)            -> max encoded bytes
 //   roaring_encode(pos_u64, n, out_u8, cap)     -> bytes written or -1
 //   positions_to_words(pos_u64, n, words_u32, n_words)   (pos < n_words*32)
+//   positions_to_rows(mat_u32, n_rows, n_words, src_addr_u64, len_i64,
+//                     dst_row_i64, n)           -> 0, or -1 (refused)
 //   words_to_positions(words_u32, n_words, out_u64, cap) -> n
 //   popcount_words(words_u32, n_words)          -> total set bits
 
@@ -670,6 +672,27 @@ void positions_to_words(const uint64_t* pos, int64_t n, uint32_t* words,
     int64_t w = static_cast<int64_t>(p >> 5);
     if (w < n_words) words[w] |= 1u << (p & 31);
   }
+}
+
+// One call a row stack (MeshPlanner._build_stack): OR the bit positions
+// of `n` source arrays into rows of one [n_rows, n_words] matrix.
+// src[k] is the address of len[k] uint64 positions, dst[k] the matrix
+// row they go to. The caller holds a reference to every source for the
+// whole call and holds no interpreter lock: 954 rows cost one hand-over
+// of it, not 954. A position at or past the row width is ignored, as
+// positions_to_words ignores it. Returns -1, with nothing written, for
+// a destination row outside the matrix or a negative length.
+int positions_to_rows(uint32_t* mat, int64_t n_rows, int64_t n_words,
+                      const uint64_t* src, const int64_t* len,
+                      const int64_t* dst, int64_t n) {
+  if (n_rows < 0 || n_words < 0 || n < 0) return -1;
+  for (int64_t k = 0; k < n; k++)
+    if (dst[k] < 0 || dst[k] >= n_rows || len[k] < 0) return -1;
+  for (int64_t k = 0; k < n; k++)
+    positions_to_words(
+        reinterpret_cast<const uint64_t*>(static_cast<uintptr_t>(src[k])),
+        len[k], mat + dst[k] * n_words, n_words);
+  return 0;
 }
 
 int64_t words_to_positions(const uint32_t* words, int64_t n_words,

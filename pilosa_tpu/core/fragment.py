@@ -440,15 +440,37 @@ class Fragment:
         hr = self.rows.get(row_id)
         return 0 if hr is None else hr.count()
 
+    def row_source(self, row_id: int) -> tuple[int, np.ndarray | None]:
+        """What one row IS at this moment, for a stack build that
+        writes it later and outside every lock: ``(n, positions)``, the
+        set-bit count and the row's own sorted uint64 position array
+        (pending single-bit adds flushed first, under this fragment's
+        lock), not a copy. The reference stays true after the lock is
+        let go because a position array is never written in place:
+        every mutator of `HostRow` assigns a new one, and whoever holds
+        the old one keeps it alive. ``positions`` is None for a row
+        held as a dense block, which writers DO change in place: that
+        row is copied under the lock (`row_words_into`). ``(0, None)``
+        for an absent or empty row."""
+        with self._lock:
+            hr = self.rows.get(row_id)
+            if hr is None or hr.n == 0:
+                return 0, None
+            if hr.dense is None:
+                hr._flush()  # may densify
+            return hr.n, hr.positions
+
     def row_words_into(self, row_id: int, out: np.ndarray) -> str | None:
         """Write one row's dense block into ``out``, a zeroed uint32[W]
         row of a stack matrix the caller owns, under this fragment's
         lock (pending single-bit adds are flushed first). Returns the
         route `HostRow.words_into` took, or None for an absent or empty
-        row, which leaves ``out`` as it is. This call, 954 times a
-        stack, is the pace of a cold or oversubscribed query: 0.05 s a
-        stack against 0.001 s for the stack's transfer call (PERF.md,
-        PR 29, `count-trees-oversub`)."""
+        row, which leaves ``out`` as it is. One call a shard, each
+        letting go of the interpreter lock and queueing for it again:
+        `MeshPlanner._build_stack` takes it only for rows held dense,
+        the distributed planner for every row of its per-device blocks.
+        A row held as positions goes through `row_source` and one
+        native call a stack (PERF.md §6, PR 34)."""
         with self._lock:
             hr = self.rows.get(row_id)
             if hr is None or hr.n == 0:

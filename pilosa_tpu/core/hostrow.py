@@ -25,7 +25,12 @@ _PENDING_FLUSH = 256
 
 
 class HostRow:
-    """One bitmap row (2^20 columns) of one fragment, host resident."""
+    """One bitmap row (2^20 columns) of one fragment, host resident.
+
+    A ``positions`` array is never written in place: every mutator
+    assigns a new one. `Fragment.row_source` hands out references on
+    that, to readers that use them after the fragment's lock is let go;
+    a ``dense`` block IS written in place."""
 
     __slots__ = ("positions", "dense", "n", "_pending")
 

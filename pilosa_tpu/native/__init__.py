@@ -27,6 +27,8 @@ _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libpilosa_native.so")
 _SOURCES = ("Makefile", "roaring_codec.cpp", "fuzz_roaring.cpp")
 
+_U64 = np.dtype(np.uint64).str  # "<u8": what `__array_interface__` says
+
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
@@ -108,6 +110,13 @@ def _load() -> ctypes.CDLL | None:
         lib.positions_to_words.argtypes = [
             np.ctypeslib.ndpointer(np.uint64, flags="C"), ctypes.c_int64,
             np.ctypeslib.ndpointer(np.uint32, flags="C"), ctypes.c_int64]
+        lib.positions_to_rows.restype = ctypes.c_int
+        lib.positions_to_rows.argtypes = [
+            np.ctypeslib.ndpointer(np.uint32, ndim=2, flags=("C", "W")),
+            ctypes.c_int64, ctypes.c_int64,
+            np.ctypeslib.ndpointer(np.uint64, flags="C"),
+            np.ctypeslib.ndpointer(np.int64, flags="C"),
+            np.ctypeslib.ndpointer(np.int64, flags="C"), ctypes.c_int64]
         lib.words_to_positions.restype = ctypes.c_int64
         lib.words_to_positions.argtypes = [
             np.ctypeslib.ndpointer(np.uint32, flags="C"), ctypes.c_int64,
@@ -278,10 +287,13 @@ def encode_roaring(positions: np.ndarray) -> bytes:
 def or_positions_into(positions: np.ndarray, words: np.ndarray) -> bool:
     """OR uint64 bit ``positions`` into ``words``, a C-contiguous uint32
     buffer the caller owns (a row of a stack matrix): no copy of the
-    positions, no temporary block. ctypes lets go of the interpreter
-    lock for the call, so builders on several threads overlap. True when
-    the native library did it; False, with the same bits set through
-    ``bitops``, when there is none."""
+    positions, no temporary block. True when the native library did it;
+    False, with the same bits set through ``bitops``, when there is
+    none. One row a call, and ctypes lets go of the interpreter lock
+    for each: `HostRow.words_into` calls it for the rows the distributed
+    planner writes into its per-device blocks; a whole stack of
+    `MeshPlanner._build_stack` goes through `or_positions_into_rows`,
+    which comes here only with no library."""
     lib = _load()
     if lib is None:
         from pilosa_tpu.ops import bitops
@@ -291,6 +303,50 @@ def or_positions_into(positions: np.ndarray, words: np.ndarray) -> bool:
     # ndpointer refuses another dtype or a strided buffer: the native
     # loop is never handed memory it would misread.
     lib.positions_to_words(positions, len(positions), words, len(words))
+    return True
+
+
+def or_positions_into_rows(sources: list[np.ndarray], mat: np.ndarray,
+                           rows: list[int]) -> bool:
+    """OR the uint64 bit positions of ``sources[k]`` into ``mat[rows[k]]``
+    for every k, in ONE native call: ``mat`` is a C-contiguous uint32
+    ``[n_rows, W]`` matrix the caller owns (a row stack). ctypes lets go
+    of the interpreter lock once for the whole stack, where a call a row
+    queues for it again after every row (PERF.md §6, PR 32 and 34).
+    ``sources`` keeps every array alive through the call; an array that
+    is not a C-contiguous 1-D uint64 buffer, or a row outside the
+    matrix, raises ValueError before anything is written. True when the
+    native library did it; False, with the same bits set row by row
+    through `or_positions_into`, when there is none."""
+    if len(rows) != len(sources):
+        raise ValueError("or_positions_into_rows: one row per source")
+    if rows and not 0 <= min(rows) <= max(rows) < len(mat):
+        raise ValueError("or_positions_into_rows: a row outside the matrix")
+    addrs: list[int] = []
+    lengths: list[int] = []
+    for a in sources:
+        # One dict per array gives address, length, dtype and layout
+        # (strides is None for a C-contiguous buffer): the native loop
+        # is never handed memory it would misread.
+        ai = a.__array_interface__
+        if (ai["typestr"] != _U64 or ai["strides"] is not None
+                or len(ai["shape"]) != 1):
+            raise ValueError(
+                "or_positions_into_rows: a source is not a C-contiguous "
+                "1-D uint64 array")
+        addrs.append(ai["data"][0])
+        lengths.append(ai["shape"][0])
+    lib = _load()
+    if lib is None:
+        for a, r in zip(sources, rows):
+            or_positions_into(a, mat[r])
+        return False
+    if lib.positions_to_rows(mat, mat.shape[0], mat.shape[1],
+                             np.array(addrs, dtype=np.uint64),
+                             np.array(lengths, dtype=np.int64),
+                             np.array(rows, dtype=np.int64),
+                             len(sources)) != 0:
+        raise ValueError("or_positions_into_rows: refused by the library")
     return True
 
 

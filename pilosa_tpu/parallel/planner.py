@@ -1314,14 +1314,26 @@ class MeshPlanner:
         fragment walk: ``stack.build``) and return (upload, nbytes):
         ``upload()`` makes the transfer call that yields the device
         ``[S_pad, W]`` stack (``stack.upload``). The stack is built in
-        place: one zeroed host matrix from the recycled page pool, each
-        fragment writing its row straight into ``mat[i]`` under its own
-        lock (`Fragment.row_words_into`). The route of a row is chosen
-        from its O(1) cardinality before anything is materialized: with
+        place, in one zeroed host matrix from the recycled page pool,
+        with ONE native call a stack. *Gather*, one pass over the
+        shards under the interpreter lock: each fragment says, under
+        its own lock, what its row is (`Fragment.row_source`: the
+        set-bit count and a reference to the sorted position array,
+        which nobody writes in place). *Scatter*, outside it: the
+        native library ORs every referenced array into its row of the
+        matrix in one call (`native.or_positions_into_rows`), so a
+        build hands the interpreter lock over once, not once a shard
+        beside whichever request thread runs Python (PERF.md §6, PR 32
+        and 34); with no native library the same call writes the
+        rows one by one through numpy. A row held as a dense block is
+        written in place by writers, so it is copied under its
+        fragment's lock (`Fragment.row_words_into`). With
         `_sparse_upload_enabled`, rows of at most
         `SPARSE_UPLOAD_MAX_BITS` ship as COO word triplets and scatter
         into zeros on device. ``planner.stackRows.<route>`` counts the
-        non-empty rows by route (scattered, copied, numpy, coo).
+        non-empty rows by route (scattered, copied, numpy, coo),
+        ``planner.stackBuilds.native|perRow`` the builds by whether the
+        library was there to take the one call.
         Overridden by the distributed planner to assemble a global
         array from each process's local fragment rows
         (jax.make_array_from_single_device_arrays)."""
@@ -1329,13 +1341,23 @@ class MeshPlanner:
         nbytes = _residency.dense_nbytes(s_pad)  # HBM-resident size
         coo_max = (self.SPARSE_UPLOAD_MAX_BITS
                    if self._sparse_upload_enabled() else 0)
-        blocks: list[tuple] = []  # (i, fragment): rows that take 128 KiB
+        # (i, fragment, positions): rows that take 128 KiB; positions is
+        # None for a dense row, which its fragment writes itself.
+        blocks: list[tuple] = []
         coo: list[tuple] = []
+        field = self.holder.field(idx.name, field_name)
+        v = None if field is None else field.view(view)
         for i, shard in enumerate(shards):
-            frag = self.holder.fragment(idx.name, field_name, view, shard)
-            n = 0 if frag is None else frag.row_cardinality(row_id)
-            if n:
-                (coo if n <= coo_max else blocks).append((i, frag))
+            frag = None if v is None else v.fragment(shard)
+            if frag is None:
+                continue
+            n, pos = frag.row_source(row_id)
+            if not n:
+                continue
+            if n <= coo_max:
+                coo.append((i, frag))
+            else:
+                blocks.append((i, frag, pos))
         # Pad the assemble program's inputs to pow2 buckets so that it
         # compiles O(log) distinct shapes, not one per leaf; padding
         # lands in a sacrificial trash row the program slices off.
@@ -1348,18 +1370,29 @@ class MeshPlanner:
         # 128 KiB rows go packed: mat[k] is stack row didx[k].
         mat = _host_zeros(bucket(len(blocks)) if coo else s_pad)
         routes = Counter(coo=len(coo))
-        for k, (i, frag) in enumerate(blocks):
-            routes[frag.row_words_into(row_id, mat[k if coo else i])] += 1
+        sources: list[np.ndarray] = []
+        rows: list[int] = []
+        for k, (i, frag, pos) in enumerate(blocks):
+            dst = k if coo else i
+            if pos is None:
+                routes[frag.row_words_into(row_id, mat[dst])] += 1
+            else:
+                sources.append(pos)
+                rows.append(dst)
+        one_call = native.or_positions_into_rows(sources, mat, rows)
+        routes["scattered" if one_call else "numpy"] += len(sources)
         if self.stats is not None:
             # One count per route and build, not per shard. A row
-            # emptied since its cardinality was read came back as None.
+            # emptied since the gather came back as None.
+            self.stats.count("planner.stackBuilds."
+                             + ("native" if one_call else "perRow"))
             for route, n in routes.items():
                 if route is not None and n:
                     self.stats.count(f"planner.stackRows.{route}", n)
         if not coo:
             return self._put(mat), nbytes
         didx = np.full(len(mat), s_pad, dtype=np.int32)
-        didx[:len(blocks)] = [i for i, _ in blocks]
+        didx[:len(blocks)] = [i for i, _, _ in blocks]
         coo_i: list[np.ndarray] = []
         coo_w: list[np.ndarray] = []
         coo_v: list[np.ndarray] = []

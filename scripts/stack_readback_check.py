@@ -104,6 +104,8 @@ def run(shards: int, rows: int, bits: int, uploads: int, fit: int) -> dict:
                        - pool0.get("fresh_mmaps", 0)),
         "stackRows": {r: stats.counter_value(f"planner.stackRows.{r}")
                       for r in ("scattered", "copied", "coo", "numpy")},
+        "stackBuilds": {r: stats.counter_value(f"planner.stackBuilds.{r}")
+                        for r in ("native", "perRow")},
     }
 
 

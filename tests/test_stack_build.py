@@ -1,12 +1,19 @@
-"""A dense row stack is built in place (MeshPlanner._build_stack): every
-fragment writes its row straight into one host matrix. Whatever form a
-row has in its fragment, and whichever route writes it, the uploaded
-stack is ``np.stack`` of ``frag.row_words``.
+"""A dense row stack is built in place (MeshPlanner._build_stack): one
+pass gathers what every fragment's row is, one native call scatters the
+position arrays into one host matrix. Whatever form a row has in its
+fragment, and whichever route writes it, the uploaded stack is
+``np.stack`` of ``frag.row_words``.
 """
 
+import ctypes
+import gc
 import importlib.util
 import json
 import os
+import sys
+import threading
+import time
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -137,31 +144,55 @@ def test_stack_built_in_place_equals_row_words(built, monkeypatch, form,
         # under -> coo on the TPU route; over, pending -> scatter;
         # dense -> one copy into dmat[k] (tpu) or mat[i] (cpu).
         assert n["copied"] == 1 and n["coo"] == (1 if sparse else 0)
+    # One build, by the path the library's presence chose.
+    assert stats.counter_value("planner.stackBuilds.native") == has_native
+    assert stats.counter_value("planner.stackBuilds.perRow") == (
+        not has_native)
     planner.close()
 
 
+def _need_native():
+    if not native.available():
+        pytest.skip("the native library cannot be built here")
+
+
+def _before_the_scatter(monkeypatch, act):
+    """Run ``act()`` once at the seam of a build: after the gather has
+    taken its references, before the one native call."""
+    real = native.or_positions_into_rows
+    done = []
+
+    def seam(sources, mat, rows):
+        if not done:
+            done.append(act())
+        return real(sources, mat, rows)
+
+    monkeypatch.setattr(native, "or_positions_into_rows", seam)
+    return done
+
+
 def test_set_during_a_build_leaves_old_generations(built, monkeypatch):
-    """A Set that lands while a stack is being built (after its shard's
-    row was written into the matrix) leaves the entry stamped with the
+    """A Set that lands while a stack is being built (after the gather
+    took its reference to the row, before the native call wrote it)
+    is not in the uploaded stack, and leaves the entry stamped with the
     generations read before the build: the next read sees the epoch
-    moved, the generations differ, and the stack is rebuilt."""
+    moved, the generations differ, and the stack is rebuilt with it."""
+    _need_native()
     h, idx = built
     f = idx.field("f")
     planner = MeshPlanner(h, make_mesh())
     row = FORMS.index("over")
     shards = (1, 2, 3)
     col = 1 * SHARD_WIDTH + 12345
-    assert not h.fragment("i", "f", "standard", 1).contains(row, col)
-    last = h.fragment("i", "f", "standard", 3)
-    real = Fragment.row_words_into
-    landed = []
+    frag = h.fragment("i", "f", "standard", 1)
+    assert not frag.contains(row, col)
 
-    def write_then_set(self, row_id, out):
-        if self is last and not landed:
-            landed.append(f.set_bit(row, col))  # shard 1 is written
-        return real(self, row_id, out)
+    def set_and_flush():
+        changed = f.set_bit(row, col)
+        frag.row_words(row)  # flushed: the row's array is a new one
+        return changed
 
-    monkeypatch.setattr(Fragment, "row_words_into", write_then_set)
+    landed = _before_the_scatter(monkeypatch, set_and_flush)
     stale = np.asarray(planner._stack_rows(idx, "f", "standard", row,
                                            shards))
     assert landed == [True]
@@ -176,13 +207,198 @@ def test_set_during_a_build_leaves_old_generations(built, monkeypatch):
     planner.close()
 
 
+class _CountingLib:
+    """The native library with every entry point's calls counted."""
+
+    def __init__(self, lib):
+        self._lib = lib
+        self.calls = Counter()
+
+    def __getattr__(self, name):
+        fn = getattr(self._lib, name)
+
+        def call(*args):
+            self.calls[name] += 1
+            return fn(*args)
+
+        return call
+
+
+@pytest.fixture
+def wide(rng):
+    """(holder, index): field ``f`` rows 0 and 1 over 40 shards, each
+    shard's row a position array above the COO threshold."""
+    h = Holder()
+    idx = h.create_index("i")
+    f = idx.create_field("f")
+    for row in (0, 1):
+        for shard in range(40):
+            cols = (rng.choice(SHARD_WIDTH, MAX_BITS + 100 + shard,
+                               replace=False) + shard * SHARD_WIDTH)
+            f.import_bits(np.full(len(cols), row, dtype=np.uint64), cols)
+    return h, idx
+
+
+@pytest.mark.parametrize("sparse", [True, False], ids=["tpu", "cpu"])
+def test_a_stack_is_one_native_scatter_call(wide, monkeypatch, sparse):
+    """40 shards of positions: one `positions_to_rows` call, one pooled
+    matrix, and not one call a row (`positions_to_words`, which
+    `or_positions_into` makes)."""
+    _need_native()
+    h, idx = wide
+    lib = _CountingLib(native._load())
+    monkeypatch.setattr(native, "_load", lambda: lib)
+    stats = MemoryStats()
+    planner = MeshPlanner(h, make_mesh(), stats=stats)
+    monkeypatch.setattr(planner, "_sparse_upload_enabled", lambda: sparse)
+    shards = tuple(range(40))
+    want = _want(h, "f", 0, shards, planner._pad(40))
+    lib.calls.clear()
+
+    upload, _ = planner._build_stack(idx, "f", "standard", 0, shards)
+
+    assert lib.calls["positions_to_rows"] == 1
+    assert lib.calls["positions_to_words"] == 0
+    assert lib.calls["pool_alloc"] == 1
+    assert np.array_equal(np.asarray(upload()), want)
+    assert stats.counter_value("planner.stackRows.scattered") == 40
+    assert stats.counter_value("planner.stackBuilds.native") == 1
+    assert stats.counter_value("planner.stackBuilds.perRow") == 0
+    planner.close()
+
+
+def test_rows_changed_after_the_gather_build_the_gathered_snapshot(
+        wide, monkeypatch):
+    """Between the gather and the native call another thread flushes a
+    row, removes from one, empties one and densifies one. The builder's
+    references keep the gathered arrays alive and nobody writes them in
+    place: the stack is the snapshot, and the next build the new rows."""
+    _need_native()
+    h, idx = wide
+    f = idx.field("f")
+    planner = MeshPlanner(h, make_mesh())
+    shards = tuple(range(40))
+    s_pad = planner._pad(40)
+    before = _want(h, "f", 0, shards, s_pad)
+    frags = [h.fragment("i", "f", "standard", s) for s in shards]
+
+    def change_rows():
+        def work():
+            f.set_bit(0, 0 * SHARD_WIDTH + 3)
+            frags[0].row_words(0)  # flush: a new array
+            gone = frags[1].row_positions(0)
+            f.clear_bit(0, 1 * SHARD_WIDTH + int(gone[0]))  # np.delete
+            for p in frags[2].row_positions(0):  # emptied
+                f.clear_bit(0, 2 * SHARD_WIDTH + int(p))
+            cols = np.arange(DENSE_CUTOFF + 10, dtype=np.uint64)
+            f.import_bits(np.zeros(len(cols), dtype=np.uint64),
+                          cols + np.uint64(3 * SHARD_WIDTH))  # densified
+        t = threading.Thread(target=work)
+        t.start()
+        t.join(timeout=60)
+        assert not t.is_alive()
+        gc.collect()  # what only the fragments held is gone
+        return True
+
+    done = _before_the_scatter(monkeypatch, change_rows)
+    upload, _ = planner._build_stack(idx, "f", "standard", 0, shards)
+    assert done == [True]
+    assert np.array_equal(np.asarray(upload()), before)
+    assert frags[3].rows[0].is_dense and frags[2].rows[0].n == 0
+    after = _want(h, "f", 0, shards, s_pad)
+    assert not np.array_equal(after, before)
+    upload, _ = planner._build_stack(idx, "f", "standard", 0, shards)
+    assert np.array_equal(np.asarray(upload()), after)
+    planner.close()
+
+
+def test_native_scatter_bounds_positions_and_refuses_bad_rows():
+    """`native.or_positions_into_rows`: a position at or past the row
+    width is ignored (as `or_positions_into` ignores it), a destination
+    row outside the matrix or a source that is not a contiguous uint64
+    buffer is refused with nothing written."""
+    _need_native()
+    w = 64
+    good = np.array([0, 33, w * 32 - 1], dtype=np.uint64)
+    wild = np.array([5, w * 32, w * 32 + 7, 2**40, 2**64 - 1],
+                    dtype=np.uint64)
+    mat = np.zeros((3, w), dtype=np.uint32)
+    native.or_positions_into_rows(
+        [good, wild, np.empty(0, dtype=np.uint64), good], mat, [0, 2, 1, 2])
+    want = np.zeros((3, w), dtype=np.uint32)
+    for r, pos in ((0, good), (2, good), (2, wild[:1])):
+        native.or_positions_into(pos, want[r])
+    assert np.array_equal(mat, want) and not mat[1].any()
+    assert mat[2, 0] == (1 | 1 << 5) and mat[2, w - 1] == 1 << 31
+
+    untouched = mat.copy()
+    for rows in ([0, 3], [-1, 0], [0]):
+        with pytest.raises(ValueError):
+            native.or_positions_into_rows([good, good], mat, rows)
+    for bad in (good.astype(np.int64), np.arange(8, dtype=np.uint64)[::2],
+                good.reshape(1, -1)):
+        with pytest.raises(ValueError):
+            native.or_positions_into_rows([good, bad], mat, [0, 1])
+    with pytest.raises(ctypes.ArgumentError):  # a strided matrix
+        native.or_positions_into_rows([good], mat[:, ::2], [0])
+    assert np.array_equal(mat, untouched)
+
+
+def test_two_builders_beside_busy_python_threads(wide):
+    """Two builders (`StackStore.MAX_WORKERS`) beside four threads that
+    never let go of the interpreter willingly: every stack is
+    ``np.stack`` of ``row_words``."""
+    _need_native()
+    h, idx = wide
+    planner = MeshPlanner(h, make_mesh())
+    shards = tuple(range(40))
+    want = {row: _want(h, "f", row, shards, planner._pad(40))
+            for row in (0, 1)}
+    stop = threading.Event()
+    wrong: list = []
+
+    def spin():
+        x = 0
+        while not stop.is_set():
+            x = (x * 31 + 7) % 1000003
+
+    def build(row):
+        try:
+            for _ in range(6):
+                upload, _ = planner._build_stack(idx, "f", "standard", row,
+                                                 shards)
+                if not np.array_equal(np.asarray(upload()), want[row]):
+                    wrong.append(row)
+        except Exception as e:  # read in the main thread below
+            wrong.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    busy = [threading.Thread(target=spin) for _ in range(4)]
+    builders = [threading.Thread(target=build, args=(row,))
+                for row in (0, 1)]
+    try:
+        for t in busy + builders:
+            t.start()
+        deadline = time.monotonic() + 120
+        for t in builders:
+            t.join(timeout=max(0.0, deadline - time.monotonic()))
+    finally:
+        stop.set()
+        for t in busy:
+            t.join(timeout=10)
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in busy + builders)
+    assert wrong == []
+    planner.close()
+
+
 def test_readback_check_script_passes_at_a_tiny_size(capsys):
     """scripts/stack_readback_check.py, the chip run's check that a
     pooled matrix is never reused under its transfer, end to end on the
     CPU backend: every fetch a build, an upload and an eviction, every
     device stack equal to a host rebuild."""
-    if not native.available():
-        pytest.skip("the native library cannot be built here")
+    _need_native()
     path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
                         "scripts", "stack_readback_check.py")
     spec = importlib.util.spec_from_file_location("stack_readback_check",
