@@ -14,6 +14,8 @@ from __future__ import annotations
 from collections.abc import Iterable
 from typing import Any
 
+from pilosa_tpu.core.shardset import as_shard_set
+
 #: sketch calls resolve OMITTED keyword literals against server-level
 #: defaults at execute time, so `Count(Distinct(field=v))` and
 #: `Count(Distinct(field=v, precision=12))` (under default precision
@@ -79,5 +81,5 @@ def cache_key(idx: Any, query: Any, shards: Iterable[int],
     entry's stamp, not the key, so a stale entry is found (and replaced
     in place) rather than leaking alongside a fresh one."""
     return (idx.name, idx.instance_id, plan_signature(query),
-            tuple(shards), opt.remote, opt.exclude_row_attrs,
+            as_shard_set(shards), opt.remote, opt.exclude_row_attrs,
             opt.exclude_columns, opt.column_attrs)

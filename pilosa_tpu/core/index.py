@@ -13,9 +13,11 @@ from dataclasses import dataclass
 from typing import Iterable
 
 from pilosa_tpu.config import EXISTENCE_FIELD_NAME
+from pilosa_tpu.core import shardset
 from pilosa_tpu.core.attrs import AttrStore
 from pilosa_tpu.core.field import Field, FieldOptions
 from pilosa_tpu.core.row import Row
+from pilosa_tpu.core.shardset import ShardSet
 from pilosa_tpu.core.translate import TranslateStore
 from pilosa_tpu.errors import (
     FieldExistsError,
@@ -171,8 +173,8 @@ class Index:
         #: (e.g. how many bit planes a comparator reads), so they key on
         #: this, separately from the data epoch.
         self.schema_epoch = Epoch()
-        #: (epoch stamp, frozenset) memo for available_shards().
-        self._avail_shards_cache: tuple | None = None
+        #: (epoch stamp, ShardSet) memo for shard_set().
+        self._shard_set_memo: tuple | None = None
         self.fields: dict[str, Field] = {}
         self.column_attr_store = AttrStore(epoch=self.epoch)
         self.translate_store = TranslateStore(epoch=self.epoch)
@@ -245,22 +247,35 @@ class Index:
 
     # -- shards ------------------------------------------------------------
 
-    def available_shards(self) -> set[int]:
-        """Union over fields (reference index.go:292). Memoized on the
-        (data, schema) epoch pair: every query start calls this, and for
-        a time field the underlying walk visits hundreds of time views —
-        ~0.7 ms per call that turned sub-ms cached reads into
-        millisecond ones. Any write or schema change invalidates."""
+    def shard_set(self, stats=None) -> ShardSet:
+        """Union over fields (reference index.go:292), as the one
+        object every query over the whole index carries (core.shardset).
+        Memoized on the (data, schema) epoch pair: every query start
+        calls this, and for a time field the underlying walk visits
+        hundreds of time views. Any write or schema change invalidates
+        the memo, but only a change of the contents changes the
+        object: a write into a shard that exists re-issues the same
+        set, so that what is keyed by it (plans, resident stacks) is
+        still found by identity. ``stats`` (the asking executor's)
+        counts the set as issued or reused."""
         stamp = (self.epoch.value, self.schema_epoch.value)
-        cached = self._avail_shards_cache
+        cached = self._shard_set_memo
         if cached is not None and cached[0] == stamp:
-            return set(cached[1])
+            if stats is not None:
+                stats.count(shardset.REUSED, 1)
+            return cached[1]
         out: set[int] = set()
         for f in self.fields.values():
             out |= f.available_shards()
-        out = out or {0}
-        self._avail_shards_cache = (stamp, frozenset(out))
-        return out
+        made = shardset.reissue(out or {0},
+                                None if cached is None else cached[1],
+                                stats)
+        self._shard_set_memo = (stamp, made)
+        return made
+
+    def available_shards(self) -> set[int]:
+        """`shard_set` as a set of the caller's own."""
+        return set(self.shard_set())
 
     # -- schema ------------------------------------------------------------
 

@@ -30,11 +30,12 @@ import numpy as np
 
 from pilosa_tpu.cache.tenant import current_tenant
 from pilosa_tpu.config import SHARD_WIDTH, WORDS_PER_SHARD
-from pilosa_tpu.core import timequantum as tq
+from pilosa_tpu.core import shardset, timequantum as tq
 from pilosa_tpu.core.field import FIELD_TYPE_BOOL, FIELD_TYPE_INT, FIELD_TYPE_TIME
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.core.index import Index
 from pilosa_tpu.core.row import Row
+from pilosa_tpu.core.shardset import as_shard_set
 from pilosa_tpu.core.view import VIEW_STANDARD, view_bsi_name
 from pilosa_tpu.errors import (
     BSIGroupNotFoundError,
@@ -188,9 +189,14 @@ class Executor:
         needs_shards = any(c.name not in ("Set", "Clear", "SetRowAttrs",
                                           "SetColumnAttrs")
                            for c in query.calls)
-        if shards is None and needs_shards:
-            shards = sorted(idx.available_shards())
-        shards = list(shards) if shards is not None else []
+        # One object a request (core.shardset): the index's own set as
+        # it stands, neither copied nor sorted; a caller's list by
+        # content, hashed here and nowhere after.
+        if shards is None:
+            shards = (idx.shard_set(self.stats) if needs_shards
+                      else shardset.EMPTY)
+        else:
+            shards = as_shard_set(shards, self.stats)
 
         # Cluster mode: coordinator-side caching is safe because every
         # node broadcasts index-dirty on its local writes (the
@@ -567,7 +573,7 @@ class Executor:
                 raise ShardCorruptError()
         if local_batch_fn is not None:
             check_deadline()
-            return local_batch_fn(list(shards))
+            return local_batch_fn(as_shard_set(shards, self.stats))
         acc = None
         for shard in shards:
             # Per-shard cancellation point: an expired deadline stops
@@ -1798,7 +1804,7 @@ class Executor:
             if not isinstance(v, list) or not all(
                     isinstance(s, int) and not isinstance(s, bool) for s in v):
                 raise QueryError("Query(): shards must be a list of unsigned integers")
-            shards = v
+            shards = as_shard_set(v, self.stats)
         if len(c.children) != 1:
             raise QueryError("Options() requires a single child call")
         return self._execute_call(idx, c.children[0], shards, opt_copy)

@@ -49,6 +49,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from pilosa_tpu.config import SHARD_WIDTH, WORDS_PER_SHARD
 from pilosa_tpu.core.row import Row
+from pilosa_tpu.core.shardset import as_shard_set
 from pilosa_tpu.core.view import VIEW_STANDARD
 from pilosa_tpu.errors import QueryError
 from pilosa_tpu.exec.executor import Executor
@@ -279,6 +280,7 @@ class DistributedMeshPlanner(MeshPlanner):
         as host segments every process can read."""
         if not shards:
             return Row()
+        shards = self._shards(shards)
         out = self._tree_stack(idx, c, shards)
         host = np.asarray(self._replicate_stack(out), dtype=np.uint32)
         return Row({shard: host[i] for i, shard in enumerate(shards)})
@@ -293,6 +295,7 @@ class DistributedMeshPlanner(MeshPlanner):
                               dtype=np.uint64)
                    if row_ids is not None else None)
         filt_host = None
+        shards = self._shards(shards)
         if filter_call is not None:
             # Uniform global program + replication; per-fragment use
             # below is host/local-device only.
@@ -379,7 +382,7 @@ class DistributedExecutor(Executor):
         if local_batch_fn is not None:
             # Planner paths produce globally-correct results (device
             # collectives + internal allgathers).
-            return local_batch_fn(list(shards))
+            return local_batch_fn(as_shard_set(shards, self.stats))
         # Host path: run the per-shard loop over OWNED shards only (for
         # reads, remote shards contribute nothing locally; for
         # multi-shard writes — ClearRow/Store — this IS the ownership

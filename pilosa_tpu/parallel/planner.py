@@ -35,6 +35,7 @@ from pilosa_tpu.config import SHARD_WIDTH, WORDS_PER_SHARD
 from pilosa_tpu.core import timequantum as tq
 from pilosa_tpu.core.index import Index
 from pilosa_tpu.core.row import Row
+from pilosa_tpu.core.shardset import ShardSet, as_shard_set
 from pilosa_tpu.core.view import VIEW_STANDARD, view_bsi_name
 from pilosa_tpu.errors import (
     BSIGroupNotFoundError,
@@ -124,6 +125,14 @@ class MeshPlanner:
         #: caching, not result caching).
         self._plan_cache: "OrderedDict[tuple, tuple]" = OrderedDict()
         self.PLAN_CACHE_SIZE = 128
+        #: (index instance, field, view, row, shard set) -> (index
+        #: epoch, largest per-shard cardinality): what `_leaf_class`
+        #: measured, oldest out first. A plan that misses the text-keyed
+        #: cache above (the benchmark's count trees are 1,479 distinct
+        #: texts over 16 rows) then costs a tree walk, not a walk of
+        #: every shard's fragment for every leaf.
+        self._leaf_bits: "OrderedDict[tuple, tuple]" = OrderedDict()
+        self.LEAF_BITS_SIZE = 1024
         #: structural shapes real traffic compiled for — (index name,
         #: call text, shard count) -> hit count, recency-ordered. The
         #: seed list for warmup-from-observed-traffic: ServerNode
@@ -236,7 +245,7 @@ class MeshPlanner:
         # text-keyed plan cache: their __const__ slots print identically
         # while holding per-query host rows. The structural _fn_cache
         # still shares the compiled program across const values.
-        shards = tuple(shards)
+        shards = self._shards(shards)
 
         def build(leaves):
             sig = self._signature(idx, c, leaves, shards)
@@ -248,7 +257,7 @@ class MeshPlanner:
             # warmup replays these strings through the Executor, and
             # only a Count() reaches prepare_count again.
             leaves, fn = self._plan_cached(idx, str(c), shards, build,
-                                           observed=f"Count({c})")
+                                           observed="Count({})")
         else:
             leaves = []
             with start_span("plan.prepare", stats=self.stats):
@@ -256,13 +265,21 @@ class MeshPlanner:
         return fn, self._fetch_leaves(idx, leaves, shards,
                                       const_rows=const_rows)
 
-    def _plan_cached(self, idx: Index, text: str, shards: tuple,
+    def _shards(self, shards) -> ShardSet:
+        """The one place a caller's shards become what the plan cache
+        and the stack store are keyed by (core.shardset): a `ShardSet`
+        passes through as it is, a list is interned by content."""
+        return as_shard_set(shards, self.stats)
+
+    def _plan_cached(self, idx: Index, text: str, shards: ShardSet,
                      build: Callable[[list], Callable],
                      observed: str | None = None):
         """(leaf descriptors, jitted fn) through the prepared-plan cache;
         on a miss ``build(leaves)`` fills the leaf list and returns the
-        compiled program, and ``observed`` (the query's executable text)
-        joins the warm-up seed list."""
+        compiled program, and ``observed.format(text)`` (the query's
+        executable text, rendered only then) joins the warm-up seed
+        list. ``shards`` in the key costs a cached integer and, on the
+        hit, a pointer compare."""
         with start_span("plan.prepare", stats=self.stats):
             # schema_epoch: plans bake field STRUCTURE (a BSI
             # comparator's bit-depth, sign-class branches, base folds),
@@ -285,7 +302,7 @@ class MeshPlanner:
                 while len(self._plan_cache) > self.PLAN_CACHE_SIZE:
                     self._plan_cache.popitem(last=False)
                 if observed is not None:
-                    okey = (idx.name, observed, len(shards))
+                    okey = (idx.name, observed.format(text), len(shards))
                     self._observed[okey] = self._observed.get(okey, 0) + 1
                     self._observed.move_to_end(okey)
                     while len(self._observed) > self.OBSERVED_SIZE:
@@ -424,11 +441,12 @@ class MeshPlanner:
                     const_rows: list | None = None) -> jax.Array:
         """Evaluate a bitmap tree to its stacked [S_pad, W] device array."""
         leaves: list[tuple] = []
+        shards = self._shards(shards)
         with start_span("plan.prepare", stats=self.stats):
-            sig = self._signature(idx, c, leaves, tuple(shards))
+            sig = self._signature(idx, c, leaves, shards)
             fn = self._compiled(("row",) + sig, sig, len(leaves),
                                 reduce=None)
-        arrays = self._fetch_leaves(idx, leaves, tuple(shards),
+        arrays = self._fetch_leaves(idx, leaves, shards,
                                     const_rows=const_rows)
         out = fn(*arrays)
         self._record_dispatch(1)
@@ -441,6 +459,7 @@ class MeshPlanner:
         the stacked result (no host sync)."""
         if not shards:
             return Row()
+        shards = self._shards(shards)
         out = self._tree_stack(idx, c, shards,
                                const_rows=const_rows)  # [S_pad, W]
         return Row({shard: out[i] for i, shard in enumerate(shards)})
@@ -472,8 +491,9 @@ class MeshPlanner:
         field_name, _ = c.string_arg("field")
         f = idx.field(field_name)
         depth = f.bsi_group.bit_depth
+        shards = self._shards(shards)
         exists, sign, bits = self._fetch_leaf(
-            idx, ("bsi", field_name, depth), tuple(shards))
+            idx, ("bsi", field_name, depth), shards)
         if c.children:
             filt = self._tree_stack(idx, c.children[0], shards)
         else:
@@ -493,18 +513,18 @@ class MeshPlanner:
         field_name, _ = c.string_arg("field")
         f = idx.field(field_name)
         depth = f.bsi_group.bit_depth
+        shards = self._shards(shards)
 
         def build(leaves):
             leaves.append(("bsiagg", field_name, depth))
-            filt_sig = (self._signature(idx, c.children[0], leaves,
-                                        tuple(shards))
+            filt_sig = (self._signature(idx, c.children[0], leaves, shards)
                         if c.children else None)
             return self._compiled_agg((kind, is_min, depth, filt_sig),
                                       kind, depth, filt_sig, is_min)
 
         leaves, fn = self._plan_cached(
-            idx, f"{kind}{int(is_min)}:{c}", tuple(shards), build)
-        return fn, self._fetch_leaves(idx, leaves, tuple(shards)), depth
+            idx, f"{kind}{int(is_min)}:{c}", shards, build)
+        return fn, self._fetch_leaves(idx, leaves, shards), depth
 
     def _compiled_agg(self, full_sig: tuple, kind: str, depth: int,
                       filt_sig, is_min: bool) -> Callable:
@@ -628,6 +648,7 @@ class MeshPlanner:
             fut: Future = Future()
             fut.set_result((0, 0))
             return fut
+        shards = self._shards(shards)
         n_shards = len(shards)
         if self._fuse_agg_ok(c):
             fn, arrays, _ = self._prepare_agg(idx, c, shards,
@@ -715,21 +736,22 @@ class MeshPlanner:
         field_name, _ = c.string_arg("field")
         f = idx.field(field_name)
         depth = f.bsi_group.bit_depth
+        shards = self._shards(shards)
 
         def build(leaves):
             if c.children:
                 leaves.append(("hll", field_name, depth, p))
                 filt_sig = self._signature(idx, c.children[0], leaves,
-                                           tuple(shards))
+                                           shards)
             else:
                 leaves.append(("hllreg", field_name, depth, p))
                 filt_sig = None
             return self._compiled_distinct(
                 ("distinct", p, depth, filt_sig), p, filt_sig)
 
-        leaves, fn = self._plan_cached(idx, f"distinct{p}:{c}",
-                                       tuple(shards), build)
-        return fn, self._fetch_leaves(idx, leaves, tuple(shards))
+        leaves, fn = self._plan_cached(idx, f"distinct{p}:{c}", shards,
+                                       build)
+        return fn, self._fetch_leaves(idx, leaves, shards)
 
     def _compiled_distinct(self, full_sig: tuple, p: int,
                            filt_sig) -> Callable:
@@ -776,6 +798,7 @@ class MeshPlanner:
         dedupes compiles by (padded R, filter shape)."""
         if not shards or not row_ids:
             return None
+        shards = self._shards(shards)
         s_pad = self._pad(len(shards))
         r = len(row_ids)
         r_pad = max(8, 1 << (r - 1).bit_length())
@@ -783,10 +806,10 @@ class MeshPlanner:
             return None
         ids = tuple(int(x) for x in row_ids)
         leaves: list[tuple] = [("simtopn", field_name, ids, r_pad)]
-        filt_sig = self._signature(idx, filter_call, leaves, tuple(shards))
+        filt_sig = self._signature(idx, filter_call, leaves, shards)
         full_sig = ("simtopn", r_pad, filt_sig)
         fn = self._compiled_similar(full_sig, r_pad, filt_sig)
-        arrays = self._fetch_leaves(idx, leaves, tuple(shards))
+        arrays = self._fetch_leaves(idx, leaves, shards)
         _fuse.add_fused_steps(_fuse.call_steps(filter_call) + 1)
         ids_arr = np.asarray(ids, dtype=np.uint64)
 
@@ -838,13 +861,14 @@ class MeshPlanner:
                    if row_ids is not None else None)
         out: dict[int, tuple] = {}
         filt = filt_host = None
+        shards = self._shards(shards)
         if filter_call is not None:
             filt = self._tree_stack(idx, filter_call, shards)  # [S_pad, W]
             # ONE pull of the filter for every shard's sparse host tier
             # (per-shard pulls each cost a link round-trip), cached
             # across TopN's two passes (same filter, same epoch).
             fkey = (idx.name, idx.instance_id, str(filter_call),
-                    tuple(shards), idx.epoch.value)
+                    shards, idx.epoch.value)
             with self._plan_lock:
                 hit = self._filter_host_cache.get(fkey)
             if hit is not None:
@@ -939,6 +963,7 @@ class MeshPlanner:
             total *= max(1, len(rows))
         if total > self.GROUP_BY_MAX_PAIRS or not shards:
             return None
+        shards = self._shards(shards)
         # Memory bound, not just dispatch count: every candidate row of
         # every level pins one [S_pad, W] stack for the whole query
         # (the ``stacks`` dict below holds strong refs, so LRU eviction
@@ -958,10 +983,9 @@ class MeshPlanner:
             idx,
             [("row", fields[i], VIEW_STANDARD, r)
              for i, rows in enumerate(cands) for r in rows],
-            tuple(shards))
+            shards)
         stacks = [
-            {r: self._stack_rows(idx, fields[i], VIEW_STANDARD, r,
-                                 tuple(shards))
+            {r: self._stack_rows(idx, fields[i], VIEW_STANDARD, r, shards)
              for r in rows}
             for i, rows in enumerate(cands)
         ]
@@ -1087,6 +1111,16 @@ class MeshPlanner:
         if not (shards and self.residency_packed_supported
                 and _residency.mode() != "off"):
             return _residency.DENSE
+        # The walk visits every shard's fragment (954 lookups a leaf at
+        # 1B columns), and a plan-cache miss asks for every leaf of its
+        # tree: the measure is kept for as long as the index's epoch
+        # stands. Read before the walk, so a write during it leaves a
+        # stale stamp behind.
+        key = (idx.instance_id, field_name, view, row_id, shards)
+        epoch = idx.epoch.value
+        hit = self._leaf_bits.get(key)
+        if hit is not None and hit[0] == epoch:
+            return _residency.choose_class(hit[1])
         max_bits = 0
         for shard in shards:
             frag = self.holder.fragment(idx.name, field_name, view, shard)
@@ -1094,6 +1128,10 @@ class MeshPlanner:
                 n = frag.row_cardinality(row_id)
                 if n > max_bits:
                     max_bits = n
+        with self._plan_lock:
+            self._leaf_bits[key] = (epoch, max_bits)
+            while len(self._leaf_bits) > self.LEAF_BITS_SIZE:
+                self._leaf_bits.popitem(last=False)
         return _residency.choose_class(max_bits)
 
     def _signature(self, idx: Index, c: Call, leaves: list[tuple],

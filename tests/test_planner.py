@@ -87,6 +87,64 @@ def test_planner_bitmap_result_matches(env):
         assert np.array_equal(a.columns(), b.columns()), query
 
 
+SHARD_SPELLINGS = {
+    "none": None,
+    "full-list": [0, 1, 2, 3, 4],
+    "subset": [1, 3, 4],
+    "unsorted-with-a-duplicate": [4, 0, 3, 3, 1],
+}
+
+
+@pytest.mark.parametrize("spelling", list(SHARD_SPELLINGS))
+def test_planner_matches_per_shard_executor_for_any_shard_list(env, spelling):
+    """One shard set a request: however the caller spells its shards,
+    the planner answers what the per-shard executor answers shard by
+    shard, before and after a Set that creates a new shard, and the
+    stacks made for the set as it was are not served for what the
+    shards hold now."""
+    h, idx, plain, fast = env
+    seed(idx, np.random.default_rng(14))
+    shards = SHARD_SPELLINGS[spelling]
+    counts = ["Count(Intersect(Row(f=1), Row(g=2)))",
+              "Count(Union(Row(f=0), Row(g=0), Row(f=3)))",
+              "Count(Row(v > 100))"]
+
+    def per_shard(q):
+        # The reference: one shard at a time through the scalar path,
+        # each shard once whatever the spelling repeats.
+        each = sorted(idx.available_shards() if shards is None
+                      else set(shards))
+        return sum(plain.execute("i", q, shards=[s], cache=False)[0]
+                   for s in each)
+
+    def check():
+        for q in counts:
+            assert fast.execute("i", q, shards=shards, cache=False) == \
+                [per_shard(q)], (spelling, q)
+        (want,) = plain.execute("i", "Sum(Row(f=1), field=v)", shards=shards)
+        (got,) = fast.execute("i", "Sum(Row(f=1), field=v)", shards=shards)
+        assert (got.val, got.count) == (want.val, want.count)
+        (a,) = plain.execute("i", "Union(Row(f=1), Row(g=2))", shards=shards)
+        (b,) = fast.execute("i", "Union(Row(f=1), Row(g=2))", shards=shards)
+        assert np.array_equal(a.columns(), b.columns())
+        (a,) = plain.execute("i", "TopN(f, Row(g=1), n=3)", shards=shards)
+        (b,) = fast.execute("i", "TopN(f, Row(g=1), n=3)", shards=shards)
+        assert [(p.id, p.count) for p in a] == [(p.id, p.count) for p in b]
+
+    check()
+    before = [per_shard(q) for q in counts]
+    # A column of a shard that exists, and one of a shard that does not.
+    for col in (3 * SHARD_WIDTH + 12345, 7 * SHARD_WIDTH + 5):
+        for q in ("Set({}, f=1)", "Set({}, g=2)", "Set({}, f=0)"):
+            fast.execute("i", q.format(col))
+    assert 7 in idx.shard_set() and 7 in idx.available_shards()
+    check()
+    after = [per_shard(q) for q in counts]
+    # Shard 3 is in every spelling, shard 7 only in the index's own set.
+    grew = 2 if shards is None else 1
+    assert [a - b for a, b in zip(after, before)][:2] == [grew, grew]
+
+
 def test_planner_cache_invalidation_on_write(env):
     h, idx, plain, fast = env
     f = idx.create_field("f")
