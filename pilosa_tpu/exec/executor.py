@@ -122,6 +122,13 @@ class Executor:
             planner.stacks if planner is not None else None)
         from pilosa_tpu.obs import NopStats
         self.stats = stats or NopStats()
+        if planner is not None:
+            # Calls that left the planner's one-dispatch path for the
+            # per-shard interpreter, by query class. Published at 0 so
+            # that a reader tells "never fell back" from "no such counter".
+            for name in ("executor.fallback.topn",
+                         "executor.fallback.groupby"):
+                self.stats.count(name, 0)
         #: query-string -> parsed Query. Parsed trees are shared across
         #: threads; every consumer clones before mutating
         #: (_translate_call clones; Options copies opt).
@@ -1196,6 +1203,10 @@ class Executor:
         ids_arg, _ = c.uint_slice_arg("ids")
         n, _ = c.uint_arg("n")
 
+        if self.planner is not None and self._topn_batch_fn(idx, c) is None:
+            # once a call, both passes: it leaves the planner for the
+            # per-shard path
+            self.stats.count("executor.fallback.topn", 1)
         pairs = self._top_n_shards(idx, c, shards, opt)
         if not pairs or ids_arg or opt.remote:
             return pairs
@@ -1476,6 +1487,9 @@ class Executor:
         local_batch = None
         gb_fields = self._planner_group_by_fields(idx, c, filter_call,
                                                   child_rows)
+        if gb_fields is None and self.planner is not None:
+            # the planner's lattice was not tried: per-shard path
+            self.stats.count("executor.fallback.groupby", 1)
         if gb_fields is not None:
             def local_batch(shs):
                 p = self.planner
@@ -1488,6 +1502,7 @@ class Executor:
                 elif shs:  # a level has no rows anywhere: empty result
                     return []
                 if res is None:  # too many pairs: per-shard streaming
+                    self.stats.count("executor.fallback.groupby", 1)
                     acc = None
                     for shard in shs:
                         acc = reduce_fn(acc, map_fn(shard))

@@ -25,6 +25,7 @@ from __future__ import annotations
 import functools
 import threading
 from collections import Counter, OrderedDict, deque
+from contextlib import nullcontext
 from typing import Any, Callable
 
 import jax
@@ -863,59 +864,74 @@ class MeshPlanner:
         filt = filt_host = None
         shards = self._shards(shards)
         if filter_call is not None:
-            filt = self._tree_stack(idx, filter_call, shards)  # [S_pad, W]
-            # ONE pull of the filter for every shard's sparse host tier
-            # (per-shard pulls each cost a link round-trip), cached
-            # across TopN's two passes (same filter, same epoch).
-            fkey = (idx.name, idx.instance_id, str(filter_call),
-                    shards, idx.epoch.value)
-            with self._plan_lock:
-                hit = self._filter_host_cache.get(fkey)
-            if hit is not None:
-                filt_host = hit
-            else:
-                filt.copy_to_host_async()
-                filt_host = np.asarray(filt, dtype=np.uint32)
+            with start_span("topn.filter", stats=self.stats):
+                # [S_pad, W]
+                filt = self._tree_stack(idx, filter_call, shards)
+                # ONE pull of the filter for every shard's sparse host tier
+                # (per-shard pulls each cost a link round-trip), cached
+                # across TopN's two passes (same filter, same epoch).
+                fkey = (idx.name, idx.instance_id, str(filter_call),
+                        shards, idx.epoch.value)
                 with self._plan_lock:
-                    self._filter_host_cache[fkey] = filt_host
-                    while len(self._filter_host_cache) > 4:
-                        self._filter_host_cache.pop(
-                            next(iter(self._filter_host_cache)))
-            if self.n_devices > 1:
-                # The per-fragment sweep is a single-device program over
-                # row stacks on the default device, and a slice of the
-                # mesh-sharded stack spans every chip: the jit around the
-                # Pallas kernel would become an SPMD program, which the
-                # chip's compiler refuses ("Mosaic kernels cannot be
-                # automatically partitioned"; first met on four chips).
-                # ONE upload of the host copy puts every shard's segment
-                # where the fragments' stacks are.
-                filt = jax.device_put(filt_host)
+                    hit = self._filter_host_cache.get(fkey)
+                if hit is not None:
+                    filt_host = hit
+                else:
+                    filt.copy_to_host_async()
+                    filt_host = np.asarray(filt, dtype=np.uint32)
+                    with self._plan_lock:
+                        self._filter_host_cache[fkey] = filt_host
+                        while len(self._filter_host_cache) > 4:
+                            self._filter_host_cache.pop(
+                                next(iter(self._filter_host_cache)))
+                if self.n_devices > 1:
+                    # The per-fragment sweep is a single-device program over
+                    # row stacks on the default device, and a slice of the
+                    # mesh-sharded stack spans every chip: the jit around the
+                    # Pallas kernel would become an SPMD program, which the
+                    # chip's compiler refuses ("Mosaic kernels cannot be
+                    # automatically partitioned"; first met on four chips).
+                    # ONE upload of the host copy puts every shard's segment
+                    # where the fragments' stacks are.
+                    filt = jax.device_put(filt_host)
         pending: list[tuple[int, np.ndarray, np.ndarray, list]] = []
-        for si, shard in enumerate(shards):
-            frag = self.holder.fragment(idx.name, field_name, view, shard)
-            if frag is None:
-                continue
-            if filt is None:
-                ids, counts = frag.top_counts()  # cached sorted order
+        with (start_span("topn.sweep", stats=self.stats)
+              if filt is not None else nullcontext()):
+            for si, shard in enumerate(shards):
+                frag = self.holder.fragment(idx.name, field_name, view, shard)
+                if frag is None:
+                    continue
+                if filt is None:
+                    ids, counts = frag.top_counts()  # cached sorted order
+                    if allowed is not None and len(ids):
+                        keep = np.isin(ids, allowed)
+                        ids, counts = ids[keep], counts[keep]
+                    if len(ids):
+                        out[shard] = (ids, counts)
+                    continue
+                ids, _ = frag.row_counts()
                 if allowed is not None and len(ids):
-                    keep = np.isin(ids, allowed)
-                    ids, counts = ids[keep], counts[keep]
-                if len(ids):
-                    out[shard] = (ids, counts)
-                continue
-            ids, _ = frag.row_counts()
-            if allowed is not None and len(ids):
-                ids = ids[np.isin(ids, allowed, assume_unique=True)]
-            if not len(ids):
-                continue
-            counts, parts = frag.intersection_counts_async(
-                ids, filt[si], reuse=True, seg_host=filt_host[si])
-            for _ in parts:
-                self._record_dispatch(1)  # one Pallas launch per dense tile
-            futs = [(slots, self.batcher.submit(dev, lambda h: h))
-                    for slots, dev in parts]
-            pending.append((shard, ids, counts, futs))
+                    ids = ids[np.isin(ids, allowed, assume_unique=True)]
+                if not len(ids):
+                    continue
+                counts, parts = frag.intersection_counts_async(
+                    ids, filt[si], reuse=True, seg_host=filt_host[si])
+                for _ in parts:  # one Pallas launch per dense tile
+                    self._record_dispatch(1)
+                futs = [(slots, self.batcher.submit(dev, lambda h: h))
+                        for slots, dev in parts]
+                pending.append((shard, ids, counts, futs))
+        if filt is not None and self.stats is not None:
+            # once a call, not once a launch: what the sweep launched, and
+            # the rows it counted on the device (held dense) and on the
+            # host (held as positions, or empty)
+            rows = sum(len(ids) for _, ids, _, _ in pending)
+            on_device = sum(len(slots) for _, _, _, futs in pending
+                            for slots, _ in futs)
+            self.stats.count("planner.topn.launches",
+                             sum(len(futs) for _, _, _, futs in pending))
+            self.stats.count("planner.topn.rowsDeviceTier", on_device)
+            self.stats.count("planner.topn.rowsHostTier", rows - on_device)
         # Resolve every shard's device tiles in one pipelined wave.
         with start_span("transfer.wait", stats=self.stats):
             for _, _, counts, futs in pending:
@@ -976,40 +992,49 @@ class MeshPlanner:
             return None
         filt = (self._tree_stack(idx, filter_call, shards)
                 if filter_call is not None else None)
-        # The GroupBy lattice stays on dense stacks (intersections
-        # accumulate across levels), but its row uploads still ride the
-        # async pipeline: prefetch the union of candidate rows.
-        self._prefetch_leaves(
-            idx,
-            [("row", fields[i], VIEW_STANDARD, r)
-             for i, rows in enumerate(cands) for r in rows],
-            shards)
-        stacks = [
-            {r: self._stack_rows(idx, fields[i], VIEW_STANDARD, r, shards)
-             for r in rows}
-            for i, rows in enumerate(cands)
-        ]
         pending: list[tuple[tuple, Any]] = []
         k = len(cands)
+        launches = 0
+        with start_span("groupby.lattice", stats=self.stats):
+            # The GroupBy lattice stays on dense stacks (intersections
+            # accumulate across levels), but its row uploads still ride
+            # the async pipeline: prefetch the union of candidate rows.
+            self._prefetch_leaves(
+                idx,
+                [("row", fields[i], VIEW_STANDARD, r)
+                 for i, rows in enumerate(cands) for r in rows],
+                shards)
+            stacks = [
+                {r: self._stack_rows(idx, fields[i], VIEW_STANDARD, r, shards)
+                 for r in rows}
+                for i, rows in enumerate(cands)
+            ]
 
-        def rec(level: int, acc, prefix: tuple):
-            for r in cands[level]:
-                stack = stacks[level][r]
-                nxt = stack
-                if acc is not None:
-                    nxt = self._and(acc, stack)
-                    self._record_dispatch(1)
-                if level == k - 1:
-                    cnt = self._and_count(nxt, filt) if filt is not None \
-                        else self._count_arr(nxt)
-                    self._record_dispatch(1)
-                    pending.append(
-                        (prefix + (r,),
-                         self.batcher.submit(cnt, lambda h: h)))
-                else:
-                    rec(level + 1, nxt, prefix + (r,))
+            def rec(level: int, acc, prefix: tuple):
+                nonlocal launches
+                for r in cands[level]:
+                    stack = stacks[level][r]
+                    nxt = stack
+                    if acc is not None:
+                        nxt = self._and(acc, stack)
+                        self._record_dispatch(1)
+                        launches += 1
+                    if level == k - 1:
+                        cnt = self._and_count(nxt, filt) \
+                            if filt is not None else self._count_arr(nxt)
+                        self._record_dispatch(1)
+                        launches += 1
+                        pending.append(
+                            (prefix + (r,),
+                             self.batcher.submit(cnt, lambda h: h)))
+                    else:
+                        rec(level + 1, nxt, prefix + (r,))
 
-        rec(0, None, ())
+            rec(0, None, ())
+        if self.stats is not None:
+            # once a call, not once a launch
+            self.stats.count("planner.groupby.launches", launches)
+            self.stats.count("planner.groupby.groups", len(pending))
         with start_span("transfer.wait", stats=self.stats):
             hosts = [fut.result() for _, fut in pending]
         out = []

@@ -813,7 +813,9 @@ def test_served_request_leaves_every_span_once(monkeypatch):
     n, post, get = _span_node()
     t = SimpleTracer()
     try:
-        before = _span_counts(get, at_least={"http.request": 1})
+        # the node's warm-up sent two Counts: wait for the second one's
+        # ledger (a thread folds it after the response is written)
+        before = _span_counts(get, at_least={"Executor.executeCount": 2})
         set_tracer(t)
         sent = 3
         for i in range(sent):
@@ -858,7 +860,9 @@ def test_profile_off_counts_the_same_spans():
     n, post, get = _span_node(profile_ring_n=0, profile_queries=False)
     orig = _profile.QueryProfile.__init__
     try:
-        before = _span_counts(get, at_least={"http.request": 1})
+        # the node's warm-up sent two Counts: wait for the second one's
+        # ledger (a thread folds it after the response is written)
+        before = _span_counts(get, at_least={"Executor.executeCount": 2})
 
         def boom(self, *a, **k):
             raise AssertionError("QueryProfile built on the off path")
