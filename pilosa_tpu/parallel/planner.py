@@ -113,8 +113,9 @@ class MeshPlanner:
         #: Count pull goes through it, so concurrent queries share one
         #: stacked device->host transfer per wave.
         self.batcher = TransferBatcher()
-        #: tiny host-side filter cache for TopN's two passes (keyed by
-        #: call text + shards + epoch; each pull is a link round-trip).
+        #: tiny host-side filter cache for the two passes of a TopN
+        #: that takes the per-fragment sweep (keyed by call text +
+        #: shards + epoch; each pull is a link round-trip).
         self._filter_host_cache: dict[tuple, np.ndarray] = {}
         #: prepared plans: (index identity, call text, shards) ->
         #: (leaf descriptors, jitted fn). A repeated query shape skips
@@ -134,6 +135,12 @@ class MeshPlanner:
         #: every shard's fragment for every leaf.
         self._leaf_bits: "OrderedDict[tuple, tuple]" = OrderedDict()
         self.LEAF_BITS_SIZE = 1024
+        #: (index instance, field, view, shard set) -> (index epoch,
+        #: sorted row ids the field holds in any of the shards): a
+        #: filtered TopN's candidate rows, kept as `_leaf_bits` is, so
+        #: that a pass walks no fragment for metadata.
+        self._field_rows: "OrderedDict[tuple, tuple]" = OrderedDict()
+        self.FIELD_ROWS_SIZE = 64
         #: structural shapes real traffic compiled for — (index name,
         #: call text, shard count) -> hit count, recency-ordered. The
         #: seed list for warmup-from-observed-traffic: ServerNode
@@ -378,8 +385,9 @@ class MeshPlanner:
         ``planner.dispatchCount`` sums the query programs of every
         class: a fused count/aggregate/bitmap program (one per query or
         per coalesced wave), each of GroupBy's stepped AND/count
-        launches, filtered TopN's filter-stack program and each
-        fragment's Pallas tile launch. Not counted: stack builds and
+        launches, a filtered TopN pass's one program (on the sweep's
+        route its filter-stack program and each fragment's Pallas tile
+        launch). Not counted: stack builds and
         uploads, key-plane lookups, sketch kernels.
 
         ``profs``: the QueryProfiles of the queries this launch served.
@@ -843,12 +851,59 @@ class MeshPlanner:
     # ------------------------------------------------------------------
     # TopN batched counts. Filterless: each fragment's generation-cached
     # sorted counts (O(results) repeat queries — the rankCache
-    # replacement). Filtered: ONE compiled filter tree over all shards,
-    # then each fragment's two-tier count sweep (host membership for
-    # sparse rows, tiled device popcounts for dense rows —
-    # fragment.intersection_counts), so data motion tracks actual set
-    # bits, not rows x shard-width.
+    # replacement). Filtered, two routes chosen by the observed size:
+    # a field whose candidate rows' dense stacks fit beside each other
+    # (`_stacks_fit`) is counted by ONE program over those resident
+    # stacks with the filter tree traced into it; a field of more rows
+    # keeps ONE compiled filter tree over all shards, then each
+    # fragment's two-tier count sweep (host membership for sparse rows,
+    # tiled device popcounts for dense rows —
+    # fragment.intersection_counts), where data motion tracks actual
+    # set bits, not rows x shard-width.
     # ------------------------------------------------------------------
+
+    def _stacks_fit(self, n_stacks: int, n_shards: int) -> bool:
+        """Whether one call may hold ``n_stacks`` dense ``[S_pad, W]``
+        stacks at once: it keeps strong references to all of them for
+        its whole length, so LRU eviction cannot make room under it."""
+        return (n_stacks * _residency.dense_nbytes(self._pad(n_shards))
+                <= min(self.max_cache_bytes, 2 << 30))
+
+    def _field_row_ids(self, idx: Index, field_name: str, view: str,
+                       shards: ShardSet) -> np.ndarray:
+        """Sorted uint64 ids of the rows ``field_name`` holds in any of
+        ``shards``: one walk of the fragments an index epoch (each
+        fragment's ids are generation-cached), read before the walk like
+        `_leaf_class`'s stamp."""
+        key = (idx.instance_id, field_name, view, shards)
+        epoch = idx.epoch.value
+        hit = self._field_rows.get(key)
+        if hit is not None and hit[0] == epoch:
+            return hit[1]
+        per_frag = [frag.row_counts()[0] for frag in (
+            self.holder.fragment(idx.name, field_name, view, shard)
+            for shard in shards) if frag is not None]
+        ids = (np.unique(np.concatenate(per_frag)) if per_frag
+               else np.zeros(0, dtype=np.uint64))
+        with self._plan_lock:
+            self._field_rows[key] = (epoch, ids)
+            while len(self._field_rows) > self.FIELD_ROWS_SIZE:
+                self._field_rows.popitem(last=False)
+        return ids
+
+    def _count_topn_pass(self, stacked: bool, launches: int,
+                         on_device: int, on_host: int) -> None:
+        """Once a filtered pass, not once a launch: the programs it
+        launched, the (row, fragment) pairs it counted on the device and
+        on the host, and the route it took."""
+        if self.stats is None:
+            return
+        for name, n in (("launches", launches),
+                        ("rowsDeviceTier", on_device),
+                        ("rowsHostTier", on_host),
+                        ("passesStacked", int(stacked)),
+                        ("passesSwept", int(not stacked))):
+            self.stats.count("planner.topn." + name, n)
 
     def execute_topn_counts(self, idx: Index, field_name: str, view: str,
                             shards: list[int], filter_call: Call | None,
@@ -864,6 +919,14 @@ class MeshPlanner:
         filt = filt_host = None
         shards = self._shards(shards)
         if filter_call is not None:
+            cands = self._field_row_ids(idx, field_name, view, shards)
+            if allowed is not None:
+                # an id no fragment holds counts 0 everywhere: no stack
+                # is built for it
+                cands = np.intersect1d(cands, allowed, assume_unique=True)
+            if len(cands) and self._stacks_fit(len(cands), len(shards)):
+                return self._topn_counts_stacked(
+                    idx, field_name, view, shards, filter_call, cands)
             with start_span("topn.filter", stats=self.stats):
                 # [S_pad, W]
                 filt = self._tree_stack(idx, filter_call, shards)
@@ -921,17 +984,15 @@ class MeshPlanner:
                 futs = [(slots, self.batcher.submit(dev, lambda h: h))
                         for slots, dev in parts]
                 pending.append((shard, ids, counts, futs))
-        if filt is not None and self.stats is not None:
-            # once a call, not once a launch: what the sweep launched, and
-            # the rows it counted on the device (held dense) and on the
-            # host (held as positions, or empty)
+        if filt is not None:
+            # rows held dense are counted on the device, rows held as
+            # positions (or empty) on the host
             rows = sum(len(ids) for _, ids, _, _ in pending)
             on_device = sum(len(slots) for _, _, _, futs in pending
                             for slots, _ in futs)
-            self.stats.count("planner.topn.launches",
-                             sum(len(futs) for _, _, _, futs in pending))
-            self.stats.count("planner.topn.rowsDeviceTier", on_device)
-            self.stats.count("planner.topn.rowsHostTier", rows - on_device)
+            self._count_topn_pass(
+                False, sum(len(futs) for _, _, _, futs in pending),
+                on_device, rows - on_device)
         # Resolve every shard's device tiles in one pipelined wave.
         with start_span("transfer.wait", stats=self.stats):
             for _, _, counts, futs in pending:
@@ -942,6 +1003,77 @@ class MeshPlanner:
             order = np.lexsort((ids, -counts))
             out[shard] = (ids[order], counts[order])
         return out
+
+    def _topn_counts_stacked(self, idx: Index, field_name: str, view: str,
+                             shards: ShardSet, filter_call: Call,
+                             cands: np.ndarray) -> dict[int, tuple]:
+        """One filtered pass as ONE program: the filter tree and the
+        per-shard popcount of ``row AND filter`` for every candidate
+        row, over the rows' resident dense stacks (fetched like any
+        plan's leaves, so the store accounts for them, uploads them
+        ahead and may evict them), whatever tier a fragment holds a row
+        in. A (row, shard) pair that counts 0 is left out: the executor
+        keeps counts of at least one."""
+        r = len(cands)
+        # the row ids are arguments, the row count a power of two: fields
+        # of 5 to 8 rows, and every filter of one shape, share a program
+        r_pad = 1 << (r - 1).bit_length()
+        with start_span("topn.filter", stats=self.stats):
+
+            def build(leaves):
+                filt_sig = self._signature(idx, filter_call, leaves, shards)
+                return self._compiled_topn_counts(r_pad, filt_sig,
+                                                  len(leaves))
+
+            leaves, fn = self._plan_cached(
+                idx, f"topn{r_pad}:{filter_call}", shards, build)
+            arrays = self._fetch_leaves(idx, leaves, shards)
+        with start_span("topn.sweep", stats=self.stats):
+            rows = self._fetch_leaves(
+                idx, [("row", field_name, view, rid)
+                      for rid in cands.tolist()], shards)
+            # the padding slots count the last row again (no stack is
+            # made or moved for them); their counts are cut off below
+            rows += rows[-1:] * (r_pad - r)
+            fut = self.coalescer.dispatch(fn, arrays + rows, np.asarray)
+        _fuse.add_fused_steps(_fuse.call_steps(filter_call) + 1)
+        self._count_topn_pass(True, 1, r * len(shards), 0)
+        counts = self._wait(fut)[:r, :len(shards)].T.astype(np.int64)
+        # [S, R], every shard at once: a stable sort of the negated
+        # counts keeps the candidates' ascending ids among equals
+        order = np.argsort(-counts, axis=1, kind="stable")
+        counts = np.take_along_axis(counts, order, axis=1)
+        ids = cands[order]
+        kept = np.count_nonzero(counts, axis=1).tolist()
+        return {shard: (ids[i, :k], counts[i, :k])
+                for i, (shard, k) in enumerate(zip(shards, kept)) if k}
+
+    def _compiled_topn_counts(self, r_pad: int, filt_sig: tuple,
+                              n_filter: int) -> Callable:
+        """``[r_pad, S_pad]`` int32 counts of ``row AND filter``: the
+        filter tree's leaves come first (``filt_sig``'s slots), then
+        ``r_pad`` dense row stacks. The rows stay separate arguments: a
+        stacked cube of eight would copy 1 GiB a pass."""
+        full_sig = ("topn_counts", r_pad, filt_sig)
+        fn = self._fn_cache.get(full_sig)
+        if fn is not None:
+            return fn
+
+        def program(*args):
+            # the barrier pins the filter as one shared value for the
+            # r_pad consumers (same rationale as _compiled_agg)
+            filt = jax.lax.optimization_barrier(_eval_node(filt_sig, args))
+            return jnp.stack([bitops.intersection_count(row, filt)
+                              for row in args[n_filter:]])
+
+        fn = self._jit_program(_named(program, "topn_counts"), None)
+        self._fn_cache[full_sig] = fn
+        # No raw program: passes of different filters must not form the
+        # coalescer's [B, ...] wave, which would copy every row stack B
+        # times; they launch one by one, and passes of the same filter
+        # (the very same arrays) still share a launch.
+        self._register_fn(fn, full_sig, None)
+        return fn
 
     # ------------------------------------------------------------------
     # GroupBy: the per-shard DFS paid one device
@@ -980,15 +1112,12 @@ class MeshPlanner:
         if total > self.GROUP_BY_MAX_PAIRS or not shards:
             return None
         shards = self._shards(shards)
-        # Memory bound, not just dispatch count: every candidate row of
-        # every level pins one [S_pad, W] stack for the whole query
-        # (the ``stacks`` dict below holds strong refs, so LRU eviction
-        # can't save us). Row-heavy GroupBys keep the per-shard
-        # streaming path, which is O(tile) in device memory.
-        n_stacks = sum(len(rows) for rows in cands)
-        stack_bytes = n_stacks * _residency.dense_nbytes(
-            self._pad(len(shards)))
-        if stack_bytes > min(self.max_cache_bytes, 2 << 30):
+        # Memory bound, not just dispatch count: the ``stacks`` dict
+        # below pins every candidate row of every level. Row-heavy
+        # GroupBys keep the per-shard streaming path, which is O(tile)
+        # in device memory.
+        if not self._stacks_fit(sum(len(rows) for rows in cands),
+                                len(shards)):
             return None
         filt = (self._tree_stack(idx, filter_call, shards)
                 if filter_call is not None else None)

@@ -375,6 +375,85 @@ def test_concurrent_writers_and_readers_converge():
     planner.close()
 
 
+def test_filtered_topn_follows_rows_written_under_it():
+    """Readers rank ``f`` under a filter through the planner's
+    one-program route while writers set bits of rows that did not exist
+    a moment before: the candidate rows are kept by index epoch
+    (`MeshPlanner._field_rows`), so a lost update there would leave a
+    new row out of the ranking for good. More threads than cores, a
+    short switch interval; no count may fall, and the last answer is the
+    per-shard interpreter's."""
+    import sys
+
+    import jax
+
+    from pilosa_tpu.config import SHARD_WIDTH
+    from pilosa_tpu.obs import MemoryStats
+
+    h = Holder()
+    idx = h.create_index("i")
+    idx.create_field("f")
+    idx.create_field("g")
+    stats = MemoryStats()
+    # One device: rows of a few bits are packed leaves, whose expansion
+    # is a collective on a mesh, and the CPU backend's collectives miss
+    # their rendezvous when many threads launch them at once.
+    planner = MeshPlanner(h, make_mesh(jax.devices()[:1]), stats=stats)
+    ex = Executor(h, planner=planner, stats=stats)
+    n_writers, n_readers, per_writer = 4, 12, 40
+    q = "TopN(f, Row(g=1))"
+    errors = []
+    barrier = threading.Barrier(n_writers + n_readers)
+
+    def writer(w):
+        barrier.wait()
+        for i in range(per_writer):
+            col = (w * per_writer + i) * 3 + (i % 2) * SHARD_WIDTH
+            try:
+                # writer w's i-th bit opens row 4 * (i // 8) + w
+                ex.execute("i", f"Set({col}, f={4 * (i // 8) + w})")
+                ex.execute("i", f"Set({col}, g=1)")
+            except Exception as e:  # pragma: no cover
+                errors.append(("w", w, repr(e)))
+                return
+
+    def reader():
+        barrier.wait()
+        last = {}
+        for _ in range(25):
+            try:
+                (pairs,) = ex.execute("i", q, cache=False)
+            except Exception as e:  # pragma: no cover
+                errors.append(("r", repr(e)))
+                return
+            now = {p.id: p.count for p in pairs}
+            if any(now.get(r, 0) < n for r, n in last.items()):
+                errors.append(("r", "a count fell", last, now))
+                return
+            last = now
+
+    threads = ([threading.Thread(target=writer, args=(w,))
+                for w in range(n_writers)]
+               + [threading.Thread(target=reader) for _ in range(n_readers)])
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+    finally:
+        sys.setswitchinterval(was)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors[:3]
+    (got,) = ex.execute("i", q, cache=False)
+    assert got == Executor(h).execute("i", q)[0]
+    assert {p.id: p.count for p in got} == {r: 8 for r in range(20)}
+    # (a reader that ran before any row of f existed had nothing to stack)
+    assert stats.counter_value("planner.topn.passesStacked") > 0
+    planner.close()
+
+
 def test_plan_cache_invalidated_by_set_value_depth_growth():
     """Single-value Set() grows BSI depth too — must also miss plans."""
     from pilosa_tpu.core import FieldOptions

@@ -304,11 +304,97 @@ def test_planner_topn_matches_scalar(env, rng, q):
         [(p.id, p.count) for p in want], q
 
 
+def _sweep_only(planner, n_shards, stacks=1):
+    """Leave the planner a budget of ``stacks`` dense stacks: a field of
+    more candidate rows keeps the per-fragment sweep (`_stacks_fit`)."""
+    from pilosa_tpu.exec import residency
+    planner.max_cache_bytes = stacks * residency.dense_nbytes(
+        planner._pad(n_shards))
+
+
+def _routes(stats):
+    return (stats.counter_value("planner.topn.passesStacked"),
+            stats.counter_value("planner.topn.passesSwept"))
+
+
+@pytest.fixture(scope="module")
+def ranked(mesh):
+    """Five shards. ``f``: rows 0-5 held as positions in every shard,
+    row 7 held dense in shards 0-2 and absent from 3-4, row 8 in shard 4
+    alone. ``g``: rows 0-5 as positions, row 6 dense; no row 9."""
+    from pilosa_tpu.config import DENSE_CUTOFF
+    from pilosa_tpu.obs import MemoryStats
+
+    h = Holder()
+    idx = h.create_index("i")
+    f, g, _ = seed(idx, np.random.default_rng(39))
+    dense = np.arange(0, 3 * SHARD_WIDTH, 5)
+    assert len(dense) // 3 > DENSE_CUTOFF
+    f.import_bits(np.full(len(dense), 7), dense)
+    g.import_bits(np.full(len(dense), 6), dense + 2 * SHARD_WIDTH)
+    lone = 4 * SHARD_WIDTH + np.arange(0, 40000, 4)
+    f.import_bits(np.full(len(lone), 8), lone)
+    stats = MemoryStats()
+    planner = MeshPlanner(h, mesh, stats=stats)
+    yield h, Executor(h), Executor(h, planner=planner, stats=stats), stats
+    planner.close()
+
+
+STACKED_TOPN_CASES = {
+    **{q: q for q in TOPN_QUERIES if "Row(" in q},
+    "a row absent from some shards, dense beside positions":
+        "TopN(f, Row(g=6))",
+    "a dense filter over rows held as positions": "TopN(f, Row(g=6), n=3)",
+    "a nested filter": "TopN(f, Union(Row(g=6), Difference(Row(g=1), "
+                       "Row(g=2))), n=4, threshold=3)",
+    "an id that exists nowhere": "TopN(f, Row(g=1), ids=[2, 7, 99])",
+    "only ids that exist nowhere": "TopN(f, Row(g=1), ids=[98, 99])",
+    "an empty filter": "TopN(f, Row(g=9), n=3)",
+    "a row of one shard": "TopN(f, Row(g=4), ids=[8])",
+    "over its budget: the sweep": "TopN(f, Row(g=6), n=5)",
+}
+
+
+#: Row(g=1) and Row(g=2) share no column under the fixture's seed
+EMPTY_TOPN_ANSWERS = {"TopN(f, Intersect(Row(g=1), Row(g=2)), n=5)",
+                      "only ids that exist nowhere", "an empty filter"}
+
+
+@pytest.mark.parametrize("case", list(STACKED_TOPN_CASES))
+def test_planner_filtered_topn_counts_the_resident_stacks(ranked, case):
+    """A filtered pass is one program over the candidate rows' dense
+    stacks and the filter tree, whatever tier a fragment holds a row
+    in; a field whose stacks do not fit the budget keeps the sweep. Both
+    answer what the per-shard interpreter answers."""
+    h, plain, fast, stats = ranked
+    q = STACKED_TOPN_CASES[case]
+    (want,) = plain.execute("i", q)
+    # the second pass runs unless ids were given or the first found nothing
+    passes = 2 if want and "ids=" not in q else 1
+    budget = fast.planner.max_cache_bytes
+    swept = case.startswith("over its budget")
+    if swept:
+        _sweep_only(fast.planner, 5, stacks=3)  # f holds 8 rows
+    if case == "only ids that exist nowhere":
+        passes, swept = 1, True  # no candidate: nothing to stack
+    before = _routes(stats)
+    try:
+        (got,) = fast.execute("i", q)
+    finally:
+        fast.planner.max_cache_bytes = budget
+    assert [(p.id, p.count) for p in got] == \
+        [(p.id, p.count) for p in want], q
+    assert bool(want) == (case not in EMPTY_TOPN_ANSWERS), q
+    stacked, swept_n = (b - a for a, b in zip(before, _routes(stats)))
+    assert (stacked, swept_n) == ((0, passes) if swept else (passes, 0))
+
+
 def test_planner_topn_streams_tiles(env, rng, monkeypatch):
     """The planner TopN path must bound device stacks by TOPN_TILE."""
     from pilosa_tpu.parallel import planner as planmod
     h, idx, plain, fast = env
     seed(idx, rng, n_rows=40)
+    _sweep_only(fast.planner, 5, stacks=4)  # 40 rows: the sweep
     from pilosa_tpu.ops import pallas_kernels
     from pilosa_tpu.core import fragment as fragmod
     monkeypatch.setattr(fragmod, "STACK_CACHE_MAX_ROWS", 8)
@@ -327,6 +413,7 @@ def test_planner_topn_streams_tiles(env, rng, monkeypatch):
     # Dense rows stream in bounded tiles; sparse rows never touch the
     # device at all (host membership path).
     assert seen["max"] <= 8
+    assert not any(k[0] == "topn_counts" for k in fast.planner._fn_cache)
     assert [(p.id, p.count) for p in got] == [(p.id, p.count) for p in want]
 
 
@@ -346,6 +433,7 @@ def test_planner_topn_hands_fragments_single_device_segments(
     cols = np.arange(0, n_shards * SHARD_WIDTH, 3)  # dense rows
     f.import_bits(np.repeat([1, 2], len(cols)), np.tile(cols, 2))
     g.import_bits(np.ones(len(cols) // 2, dtype=np.int64), cols[::2])
+    _sweep_only(fast.planner, n_shards)  # f's two rows: the sweep
     seen = []
     real = pallas_kernels.pair_count
 
@@ -357,6 +445,46 @@ def test_planner_topn_hands_fragments_single_device_segments(
     (got,) = fast.execute("i", "TopN(f, Row(g=1), n=2)")
     assert seen and set(seen) == {(1, 1)}
     monkeypatch.setattr(pallas_kernels, "pair_count", real)
+    (want,) = plain.execute("i", "TopN(f, Row(g=1), n=2)")
+    assert [(p.id, p.count) for p in got] == \
+        [(p.id, p.count) for p in want] == \
+        [(1, len(cols[::2])), (2, len(cols[::2]))]
+
+
+def test_planner_topn_program_keeps_its_operands_on_the_mesh(
+        env, monkeypatch):
+    """The twin of the test above for the one-program route: it is a
+    plain XLA program, so every operand (the filter's leaves and the
+    candidate rows' stacks) stays sharded over the ``shard`` axis of the
+    whole mesh and nothing is gathered onto one device first."""
+    from jax.sharding import NamedSharding
+    from pilosa_tpu.parallel.mesh import SHARD_AXIS
+    h, idx, plain, fast = env
+    planner = fast.planner
+    assert planner.n_devices > 1
+    f = idx.create_field("f")
+    g = idx.create_field("g")
+    n_shards = 3
+    cols = np.arange(0, n_shards * SHARD_WIDTH, 3)
+    f.import_bits(np.repeat([1, 2], len(cols)), np.tile(cols, 2))
+    g.import_bits(np.ones(len(cols) // 2, dtype=np.int64), cols[::2])
+    seen = []
+    real = planner.coalescer.dispatch
+
+    def spy(fn, args, post):
+        if planner.fn_key(fn)[0] == "topn_counts":
+            seen.append([a.sharding for a in args])
+        return real(fn, args, post)
+
+    monkeypatch.setattr(planner.coalescer, "dispatch", spy)
+    (got,) = fast.execute("i", "TopN(f, Row(g=1), n=2)")
+    assert len(seen) == 2  # one launch a pass
+    for shardings in seen:
+        assert len(shardings) == 3  # the filter's leaf, rows 1 and 2
+        for sh in shardings:
+            assert isinstance(sh, NamedSharding)
+            assert sh.spec[0] == SHARD_AXIS
+            assert len(sh.device_set) == planner.n_devices
     (want,) = plain.execute("i", "TopN(f, Row(g=1), n=2)")
     assert [(p.id, p.count) for p in got] == \
         [(p.id, p.count) for p in want] == \

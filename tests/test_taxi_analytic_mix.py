@@ -209,11 +209,12 @@ def test_the_new_metrics_are_on_the_line_with_the_shapes_counts(cut_run):
     metrics = result["metrics"]
     assert set(metrics) == NEW_METRICS
     value = {name: m["value"] for name, m in metrics.items()}
-    # passenger_count holds two rows dense in every shard: one dense part
-    # a fragment, in each of TopN's two passes; the other six rows are
-    # counted on the host
-    assert value["topn_launches_per_call"] == 2 * SHARDS
-    assert value["topn_host_tier_share"] == 75.0
+    # passenger_count's eight dense stacks fit the planner's budget: one
+    # program a pass counts all of them, in each of TopN's two passes,
+    # whatever tier a fragment holds a row in; nothing is counted on the
+    # host
+    assert value["topn_launches_per_call"] == 2
+    assert value["topn_host_tier_share"] == 0.0
     # the lattice: one AND a (year, passenger count) pair, one count a group
     years = config["fields"]["pickup_year"]["rows"]
     counts = config["fields"]["passenger_count"]["rows"]
@@ -223,7 +224,8 @@ def test_the_new_metrics_are_on_the_line_with_the_shapes_counts(cut_run):
     # and nothing compiled inside it
     assert value["analytic_compiles_in_window"] == 0
     for name in NEW_METRICS - {"class_fallbacks_per_request",
-                               "analytic_compiles_in_window"}:
+                               "analytic_compiles_in_window",
+                               "topn_host_tier_share"}:
         assert value[name] > 0, name
     assert value["topn_sweep_ms_per_call"] + \
         value["topn_filter_ms_per_call"] < value["topn_ms_per_call"]
@@ -356,11 +358,13 @@ def test_the_planners_answers_are_the_interpreters(served):
 
 
 @pytest.mark.parametrize("name, amount", [
-    # two passes over two fragments: one dense part each, one row on the
-    # device tier and three on the host tier
-    ("planner.topn.launches", lambda s, c, y: 2 * s),
-    ("planner.topn.rowsDeviceTier", lambda s, c, y: 2 * s),
-    ("planner.topn.rowsHostTier", lambda s, c, y: 2 * s * (c - 1)),
+    # two passes, one program each over the four rows' dense stacks:
+    # every (row, fragment) pair counted on the device, none on the host
+    ("planner.topn.launches", lambda s, c, y: 2),
+    ("planner.topn.rowsDeviceTier", lambda s, c, y: 2 * s * c),
+    ("planner.topn.rowsHostTier", lambda s, c, y: 0),
+    ("planner.topn.passesStacked", lambda s, c, y: 2),
+    ("planner.topn.passesSwept", lambda s, c, y: 0),
     # one AND a pair below the first level, one count a group
     ("planner.groupby.launches", lambda s, c, y: 2 * y * c),
     ("planner.groupby.groups", lambda s, c, y: y * c),
@@ -381,10 +385,10 @@ def test_topn_and_groupby_move_a_counter_by_the_shapes_amount(
 def test_the_fallback_counters_are_published_before_any_call(served):
     assert served["published"][("executor.fallback.topn", ())] == 0
     assert served["published"][("executor.fallback.groupby", ())] == 0
-    # every launch of the sweep and the lattice is a dispatch as before
-    shards, n_counts, n_years = served["shape"]
+    # every launch of TopN's passes and the lattice is a dispatch as before
+    _, n_counts, n_years = served["shape"]
     assert served["after_mix"][("planner.dispatchCount", ())] >= \
-        2 * shards + 2 * n_years * n_counts
+        2 + 2 * n_years * n_counts
 
 
 @pytest.mark.parametrize("span, call", [

@@ -139,6 +139,15 @@ class Plan:
                                     reduce=None)
         return fn, self.leaf_shapes(leaves)
 
+    def topn_counts(self, pql, rows):
+        """(the one-program filtered TopN pass, shapes): the filter
+        tree's leaves, then ``rows`` candidate row stacks."""
+        leaves = []
+        filt_sig = self.planner._signature(self.idx, parse(pql).calls[0],
+                                           leaves, self.shards)
+        fn = self.planner._compiled_topn_counts(rows, filt_sig, len(leaves))
+        return fn, self.leaf_shapes(leaves) + [self.row] * rows
+
     def sum(self, pql):
         call = parse(pql).calls[0]
         depth = self.idx.field("v").bsi_group.bit_depth
@@ -242,6 +251,31 @@ def test_topn_filter_tree_compiles(plan1):
     fn, shapes = plan1.bitmap("Intersect(Row(g=2), Row(f=1))")
     c = fn.lower(*shapes).compile()
     assert c.memory_analysis().output_size_in_bytes == S_PAD * W * 4
+
+
+@pytest.mark.parametrize("chips, pql", [
+    (1, "Row(g=2)"),
+    (1, "Intersect(Row(g=2), Not(Row(f=1)))"),
+    (4, "Row(g=2)"),
+])
+def test_topn_counts_program_compiles(request, chips, pql):
+    """The route a filtered TopN takes when the candidate rows' stacks
+    fit (planner._topn_counts_stacked), at the rides index's shape: 8
+    ``passenger_count`` rows over the 1,024-shard bucket. What comes
+    back is the [8, 1024] int32 matrix; the rows are never stacked into
+    a 1 GiB cube, and a filter that is more than a leaf is held once
+    (one 128 MiB stack). On the mesh it is an SPMD program over the
+    ``shard`` axis: a quarter of every operand a chip, nothing gathered."""
+    plan = request.getfixturevalue(f"plan{chips}")
+    fn, shapes = plan.topn_counts(pql, 8)
+    c = fn.lower(*shapes).compile()
+    text = c.as_text()
+    assert "jit_topn_counts" in text
+    assert "all-gather" not in text and "all-reduce" not in text
+    ma = c.memory_analysis()
+    assert ma.argument_size_in_bytes == len(shapes) * S_PAD * W * 4 // chips
+    assert ma.output_size_in_bytes == 8 * S_PAD * 4 // chips
+    assert ma.temp_size_in_bytes <= (S_PAD * W * 4 + (8 << 20)) // chips
 
 
 def test_bsi_sum_fold_compiles(plan1):
