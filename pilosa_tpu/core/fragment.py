@@ -25,6 +25,7 @@ row dimension is explicit (it is the device batch axis).
 from __future__ import annotations
 
 import threading
+from contextlib import ExitStack
 from typing import Callable, Iterable
 
 import jax
@@ -39,6 +40,7 @@ from pilosa_tpu.config import (
 )
 from pilosa_tpu.core.hostrow import HostRow
 from pilosa_tpu.core.row import Row
+from pilosa_tpu.obs.tracing import start_span
 from pilosa_tpu.ops import bitops, bsi as bsi_ops, pallas_kernels
 
 # BSI row layout, reference fragment.go:87-93.
@@ -151,7 +153,8 @@ class Fragment:
                     self._col_row[pos] = row_id
                 self._invalidate()
                 if self.op_writer:
-                    self.op_writer("add", [row_id], [column_id])
+                    with start_span("wal.append"):
+                        self.op_writer("add", [row_id], [column_id])
             return changed
 
     def clear_bit(self, row_id: int, column_id: int) -> bool:
@@ -167,7 +170,8 @@ class Fragment:
                     del self._col_row[pos]
                 self._invalidate()
                 if self.op_writer:
-                    self.op_writer("remove", [row_id], [column_id])
+                    with start_span("wal.append"):
+                        self.op_writer("remove", [row_id], [column_id])
             return changed
 
     def contains(self, row_id: int, column_id: int) -> bool:
@@ -183,8 +187,11 @@ class Fragment:
             self._col_row = None
             self._invalidate()
             if self.op_writer:
-                cols = (hr.to_positions() + np.uint64(self.shard * SHARD_WIDTH))
-                self.op_writer("removeBatch", [row_id] * len(cols), cols.tolist())
+                with start_span("wal.append"):
+                    cols = (hr.to_positions()
+                            + np.uint64(self.shard * SHARD_WIDTH))
+                    self.op_writer("removeBatch", [row_id] * len(cols),
+                                   cols.tolist())
             return True
 
     def set_row(self, row: Row, row_id: int) -> bool:
@@ -196,51 +203,61 @@ class Fragment:
             self._col_row = None
             self._invalidate()
             if self.op_writer:
-                cols = bitops.words_to_positions(words) + np.uint64(self.shard * SHARD_WIDTH)
-                self.op_writer("setRow", [row_id], cols.tolist())
+                with start_span("wal.append"):
+                    cols = bitops.words_to_positions(words) + np.uint64(
+                        self.shard * SHARD_WIDTH)
+                    self.op_writer("setRow", [row_id], cols.tolist())
             return True
 
     def bulk_import(self, row_ids: Iterable[int], column_ids: Iterable[int],
                     clear: bool = False) -> int:
         """Batched set/clear (reference bulkImport fragment.go:1997).
-        Returns number of changed bits."""
-        with self._lock:
-            if not isinstance(row_ids, np.ndarray):
-                row_ids = np.asarray(list(row_ids), dtype=np.uint64)
-            row_ids = row_ids.astype(np.uint64, copy=False)
-            if not isinstance(column_ids, np.ndarray):
-                column_ids = np.asarray(list(column_ids), dtype=np.uint64)
-            column_ids = column_ids.astype(np.uint64, copy=False)
-            if len(row_ids) != len(column_ids):
-                raise ValueError("row/column length mismatch")
-            if len(row_ids) == 0:
-                return 0
-            local = column_ids - np.uint64(self.shard * SHARD_WIDTH)
-            if (local >= SHARD_WIDTH).any():
-                raise ValueError("column out of shard bounds")
-            changed = 0
-            # Vectorized by-row split: one stable sort + boundary scan
-            # (a per-row boolean mask would be O(rows * n)).
-            order = np.argsort(row_ids, kind="stable")
-            sorted_rows = row_ids[order]
-            sorted_local = local[order]
-            uniq, starts = np.unique(sorted_rows, return_index=True)
-            bounds = np.append(starts, len(sorted_rows))
-            for i, rid in enumerate(uniq.tolist()):
-                lo, hi = int(bounds[i]), int(bounds[i + 1])
-                hr = self.rows.get(int(rid))
-                if hr is None:
+        Returns number of changed bits.
+
+        The bulk paths take the lock inside ``import.merge`` (its wait is
+        part of the merge) and hold it through ``wal.append``, so the log
+        keeps the order of the mutations and the two spans do not nest."""
+        with ExitStack() as held:
+            with start_span("import.merge"):
+                held.enter_context(self._lock)
+                if not isinstance(row_ids, np.ndarray):
+                    row_ids = np.asarray(list(row_ids), dtype=np.uint64)
+                row_ids = row_ids.astype(np.uint64, copy=False)
+                if not isinstance(column_ids, np.ndarray):
+                    column_ids = np.asarray(list(column_ids),
+                                            dtype=np.uint64)
+                column_ids = column_ids.astype(np.uint64, copy=False)
+                if len(row_ids) != len(column_ids):
+                    raise ValueError("row/column length mismatch")
+                if len(row_ids) == 0:
+                    return 0
+                local = column_ids - np.uint64(self.shard * SHARD_WIDTH)
+                if (local >= SHARD_WIDTH).any():
+                    raise ValueError("column out of shard bounds")
+                changed = 0
+                # Vectorized by-row split: one stable sort + boundary scan
+                # (a per-row boolean mask would be O(rows * n)).
+                order = np.argsort(row_ids, kind="stable")
+                sorted_rows = row_ids[order]
+                sorted_local = local[order]
+                uniq, starts = np.unique(sorted_rows, return_index=True)
+                bounds = np.append(starts, len(sorted_rows))
+                for i, rid in enumerate(uniq.tolist()):
+                    lo, hi = int(bounds[i]), int(bounds[i + 1])
+                    hr = self.rows.get(int(rid))
+                    if hr is None:
+                        if clear:
+                            continue
+                        hr = self.rows[int(rid)] = HostRow()
                     if clear:
-                        continue
-                    hr = self.rows[int(rid)] = HostRow()
-                if clear:
-                    changed += hr.remove_many(sorted_local[lo:hi])
-                else:
-                    changed += hr.add_many(sorted_local[lo:hi])
-            if changed:
-                self._col_row = None
-                self._invalidate()
-                if self.op_writer:
+                        changed += hr.remove_many(sorted_local[lo:hi])
+                    else:
+                        changed += hr.add_many(sorted_local[lo:hi])
+                if changed:
+                    self._col_row = None
+                    self._invalidate()
+            if changed and self.op_writer:
+                with start_span("wal.append"):
                     self.op_writer("removeBatch" if clear else "addBatch",
                                    row_ids.tolist(), column_ids.tolist())
             return changed
@@ -252,38 +269,41 @@ class Fragment:
         importPositions fragment.go:2053). Boundary-scans row groups,
         dedupes each group's sorted positions with one diff pass, and
         hands them to HostRow without any further sort."""
-        with self._lock:
-            n = len(row_ids)
-            if n == 0:
-                return 0
-            row_ids = np.asarray(row_ids, dtype=np.int64)
-            local = np.asarray(local, dtype=np.uint32)
-            cut = np.flatnonzero(row_ids[1:] != row_ids[:-1]) + 1
-            bounds = np.concatenate(([0], cut, [n]))
-            changed = 0
-            for i in range(len(bounds) - 1):
-                lo, hi = int(bounds[i]), int(bounds[i + 1])
-                rid = int(row_ids[lo])
-                seg = local[lo:hi]
-                if hi - lo > 1:  # drop duplicate positions (sorted input)
-                    keep = np.empty(hi - lo, dtype=bool)
-                    keep[0] = True
-                    np.not_equal(seg[1:], seg[:-1], out=keep[1:])
-                    if not keep.all():
-                        seg = seg[keep]
-                hr = self.rows.get(rid)
-                if hr is None:
+        with ExitStack() as held:
+            with start_span("import.merge"):
+                held.enter_context(self._lock)
+                n = len(row_ids)
+                if n == 0:
+                    return 0
+                row_ids = np.asarray(row_ids, dtype=np.int64)
+                local = np.asarray(local, dtype=np.uint32)
+                cut = np.flatnonzero(row_ids[1:] != row_ids[:-1]) + 1
+                bounds = np.concatenate(([0], cut, [n]))
+                changed = 0
+                for i in range(len(bounds) - 1):
+                    lo, hi = int(bounds[i]), int(bounds[i + 1])
+                    rid = int(row_ids[lo])
+                    seg = local[lo:hi]
+                    if hi - lo > 1:  # drop duplicate positions (sorted input)
+                        keep = np.empty(hi - lo, dtype=bool)
+                        keep[0] = True
+                        np.not_equal(seg[1:], seg[:-1], out=keep[1:])
+                        if not keep.all():
+                            seg = seg[keep]
+                    hr = self.rows.get(rid)
+                    if hr is None:
+                        if clear:
+                            continue
+                        hr = self.rows[rid] = HostRow()
                     if clear:
-                        continue
-                    hr = self.rows[rid] = HostRow()
-                if clear:
-                    changed += hr.remove_many_sorted_unique(seg)
-                else:
-                    changed += hr.add_many_sorted_unique(seg)
-            if changed:
-                self._col_row = None
-                self._invalidate()
-                if self.op_writer:
+                        changed += hr.remove_many_sorted_unique(seg)
+                    else:
+                        changed += hr.add_many_sorted_unique(seg)
+                if changed:
+                    self._col_row = None
+                    self._invalidate()
+            if changed and self.op_writer:
+                with start_span("wal.append"):
                     base = np.uint64(self.shard * SHARD_WIDTH)
                     self.op_writer("removeBatch" if clear else "addBatch",
                                    row_ids.astype(np.uint64),
@@ -307,22 +327,25 @@ class Fragment:
         near-empty plane to positions there costs a scan and saves no
         memory."""
         from pilosa_tpu import native
-        with self._lock:
-            if bit_count is None:
-                bit_count = native.popcount_words(words)
-            if bit_count == 0:
-                return 0
-            hr = self.rows.get(row_id)
-            if hr is None or hr.n == 0:
-                self.rows[row_id] = HostRow.adopt_words(
-                    words, bit_count, prefer_dense=prefer_dense)
-                changed = bit_count
-            else:
-                changed = hr.merge_words(words)
-            if changed:
-                self._col_row = None
-                self._invalidate(bump_epoch=bump_epoch)
-                if self.op_writer:
+        with ExitStack() as held:
+            with start_span("import.merge"):
+                held.enter_context(self._lock)
+                if bit_count is None:
+                    bit_count = native.popcount_words(words)
+                if bit_count == 0:
+                    return 0
+                hr = self.rows.get(row_id)
+                if hr is None or hr.n == 0:
+                    self.rows[row_id] = HostRow.adopt_words(
+                        words, bit_count, prefer_dense=prefer_dense)
+                    changed = bit_count
+                else:
+                    changed = hr.merge_words(words)
+                if changed:
+                    self._col_row = None
+                    self._invalidate(bump_epoch=bump_epoch)
+            if changed and self.op_writer:
+                with start_span("wal.append"):
                     pos = native.words_to_positions(words)
                     base = np.uint64(self.shard * SHARD_WIDTH)
                     self.op_writer("addBatch",
@@ -357,8 +380,9 @@ class Fragment:
                 for p in lpos:
                     vec.pop(p, None)
                 if self.op_writer:
-                    self.op_writer("removeBatch", [rid] * len(lpos),
-                                   (stolen + base).tolist())
+                    with start_span("wal.append"):
+                        self.op_writer("removeBatch", [rid] * len(lpos),
+                                       (stolen + base).tolist())
             # Set the desired bits, grouped by row.
             by_row: dict[int, list[int]] = {}
             for pos, rid in desired.items():
@@ -372,8 +396,9 @@ class Fragment:
                 for p in lpos:
                     vec[p] = rid
                 if added and self.op_writer:
-                    self.op_writer("addBatch", [rid] * len(lpos),
-                                   [p + int(base) for p in lpos])
+                    with start_span("wal.append"):
+                        self.op_writer("addBatch", [rid] * len(lpos),
+                                       [p + int(base) for p in lpos])
             if changed:
                 self._invalidate()
             return changed
@@ -384,13 +409,19 @@ class Fragment:
         fragment (reference importRoaring fragment.go:2255 →
         ImportRoaringBits roaring.go:1511). Returns changed-bit count."""
         from pilosa_tpu import native
-        positions = native.decode_roaring(data)
-        if len(positions) == 0:
-            return 0
-        rows = (positions // np.uint64(SHARD_WIDTH)).astype(np.uint64)
-        cols = (positions % np.uint64(SHARD_WIDTH)).astype(np.uint64)
-        abs_cols = cols + np.uint64(self.shard * SHARD_WIDTH)
-        return self.bulk_import(rows.tolist(), abs_cols.tolist(), clear=clear)
+        with start_span("import.decode") as span:
+            positions = native.decode_roaring(data)
+            # ``import.bits`` goes where the span's counters go: the
+            # registry of the thread's outermost span (``http.request``).
+            if span.stats is not None:
+                span.stats.count("import.bits", len(positions))
+            if len(positions) == 0:
+                return 0
+            rows = (positions // np.uint64(SHARD_WIDTH)).astype(np.uint64)
+            cols = (positions % np.uint64(SHARD_WIDTH)).astype(np.uint64)
+            abs_cols = cols + np.uint64(self.shard * SHARD_WIDTH)
+            row_list, col_list = rows.tolist(), abs_cols.tolist()
+        return self.bulk_import(row_list, col_list, clear=clear)
 
     #: bit budget per streamed transfer chunk (~8 MB of positions):
     #: the resize migration streamer slices rows_snapshot into PTS1
@@ -976,11 +1007,13 @@ class Fragment:
             _add(clr_rows, clr_cols, BSI_OFFSET_BIT + i, ~on)
         base = np.uint64(self.shard * SHARD_WIDTH)
         if removed and clr_rows:
-            self.op_writer("removeBatch", np.concatenate(clr_rows),
-                           np.concatenate(clr_cols) + base)
+            with start_span("wal.append"):
+                self.op_writer("removeBatch", np.concatenate(clr_rows),
+                               np.concatenate(clr_cols) + base)
         if added and set_rows:
-            self.op_writer("addBatch", np.concatenate(set_rows),
-                           np.concatenate(set_cols) + base)
+            with start_span("wal.append"):
+                self.op_writer("addBatch", np.concatenate(set_rows),
+                               np.concatenate(set_cols) + base)
 
     def _filter_seg(self, filter_row: Row | None) -> jax.Array:
         if filter_row is None:

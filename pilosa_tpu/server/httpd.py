@@ -482,16 +482,19 @@ def _build_routes(api: API):
         return 200, {}
 
     def post_import_roaring(pv, params, body):
-        # remote=true marks a forwarded replica write: apply locally only.
-        if params.get("remote") == "true":
-            f = api.holder.field(pv["index"], pv["field"])
-            if f is None:
-                raise FieldNotFoundError()
-            f.import_roaring(int(pv["shard"]), body,
-                             clear=params.get("clear") == "true")
-        else:
-            api.import_roaring(pv["index"], pv["field"], int(pv["shard"]),
-                               body, clear=params.get("clear") == "true")
+        with start_span("import.roaring"):
+            # remote=true marks a forwarded replica write: apply locally
+            # only.
+            if params.get("remote") == "true":
+                f = api.holder.field(pv["index"], pv["field"])
+                if f is None:
+                    raise FieldNotFoundError()
+                f.import_roaring(int(pv["shard"]), body,
+                                 clear=params.get("clear") == "true")
+            else:
+                api.import_roaring(pv["index"], pv["field"],
+                                   int(pv["shard"]), body,
+                                   clear=params.get("clear") == "true")
         return 200, {}
 
     def post_query(pv, params, body):

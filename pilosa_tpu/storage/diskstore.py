@@ -303,9 +303,10 @@ class DiskStore:
                 return  # fragment GC'd; orphan writes must not recreate
                 # the WAL file (stale bits would replay on restart)
             if op == "setRow":
-                w.append("setRow", rows[:1], cols)
+                n = w.append("setRow", rows[:1], cols)
             else:
-                w.append(op, rows, cols)
+                n = w.append(op, rows, cols)
+            self.stats.count("wal.bytes", n)
             if w.op_n > self.max_op_n:
                 self._enqueue_snapshot(key)
         return op_writer
@@ -320,12 +321,6 @@ class DiskStore:
                     self._wal_path(key), fsync_appends=self.fsync_appends,
                     group_window=self.wal_group_window)
             return w
-
-    def wal_fsyncs(self) -> int:
-        """Total fsync() calls across every live WAL writer (the
-        group-commit amortization gauge)."""
-        with self._lock:
-            return sum(w.fsyncs for w in self._writers.values())
 
     def delete_fragment_files(self, key: tuple) -> None:
         """Remove a fragment's snapshot + WAL (holderCleaner's disk

@@ -67,7 +67,7 @@ class WalWriter:
             os.makedirs(d, exist_ok=True)
         self._f = open(path, "ab")
         self.op_n = 0
-        #: fsync() calls issued (bench: fsyncs per Mval imported).
+        #: fsync() calls issued (the group-commit tests read it).
         self.fsyncs = 0
         self._lock = threading.Lock()
         self._sync_cv = threading.Condition(self._lock)
@@ -75,7 +75,8 @@ class WalWriter:
         self._seq_synced = 0   # records covered by an fsync
         self._flusher_busy = False
 
-    def append(self, op: str, rows, cols) -> None:
+    def append(self, op: str, rows, cols) -> int:
+        """Write one record; returns its bytes, header and payload."""
         code = _OP_CODES[op]
         r = np.asarray(rows, dtype=np.uint64)
         c = np.asarray(cols, dtype=np.uint64)
@@ -101,6 +102,7 @@ class WalWriter:
                     self.fsyncs += 1
                     if my_seq > self._seq_synced:
                         self._seq_synced = my_seq
+        return _HEADER.size + len(payload)
 
     def _group_sync(self, my_seq: int) -> None:
         """Block until an fsync covers record ``my_seq``, becoming the
