@@ -16,6 +16,13 @@ resolves each query's future. Any number of copies pipeline inside one
 round-trip window, so N concurrent queries cost ~one round-trip of
 latency total instead of N.
 
+Every pull runs under the span ``transfer.pull`` and adds the host
+bytes it made to the counter ``transfer.bytes``: on a mesh a result lies
+a part on each device, and this is the way back from all of them. On
+the resolver the span is outermost; a wave stolen by its waiter is
+pulled inside that request's ``transfer.wait``, and one submitted after
+close on the submitting thread.
+
 Merging results into one stacked array before transfer was tried on an
 earlier machine and performed the same while costing a large XLA compile
 per wave shape, so this simpler design won; neither is measured on the
@@ -34,6 +41,7 @@ import numpy as np
 
 from pilosa_tpu.obs import profile as _profile
 from pilosa_tpu.obs.histogram import WIDTH_BOUNDS, LogHistogram
+from pilosa_tpu.obs.tracing import start_span
 
 _INLINE_MODES = ("on", "off", "auto")
 _default_inline = "auto"
@@ -75,7 +83,9 @@ class _StealFuture(Future):
 class TransferBatcher:
     """Pipelines many small device→host pulls behind one resolver."""
 
-    def __init__(self):
+    def __init__(self, stats=None):
+        #: the registry the pulls' span and byte counter go to, or None.
+        self.stats = stats
         self._queue: deque = deque()
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
@@ -131,7 +141,7 @@ class TransferBatcher:
             # synchronously instead of 500ing (handler threads can
             # outlive the HTTP listener).
             try:
-                fut.set_result(postproc(np.asarray(arr)))
+                fut.set_result(postproc(self._pull(arr)))
             except Exception as e:
                 fut.set_exception(e)
         return fut
@@ -190,7 +200,7 @@ class TransferBatcher:
             p.add_inline_steal()
         arr, _, post = entry
         try:
-            result = post(np.asarray(arr))
+            result = post(self._pull(arr))
         except Exception as e:
             if not fut.done():
                 fut.set_exception(e)
@@ -199,6 +209,16 @@ class TransferBatcher:
             fut.set_result(result)
 
     # -- resolver --------------------------------------------------------
+
+    def _pull(self, arr) -> np.ndarray:
+        """Every device->host copy of a result (its async copy started
+        at submit), on whichever thread resolves it: ``transfer.pull``,
+        ``transfer.bytes``."""
+        with start_span("transfer.pull", stats=self.stats):
+            host = np.asarray(arr)
+        if self.stats is not None:
+            self.stats.count("transfer.bytes", host.nbytes)
+        return host
 
     def _run(self) -> None:
         while True:
@@ -209,8 +229,7 @@ class TransferBatcher:
                     return
                 arr, fut, post = self._queue.popleft()
             try:
-                host = np.asarray(arr)
-                result = post(host)
+                result = post(self._pull(arr))
             except Exception as e:
                 if not fut.done():
                     fut.set_exception(e)

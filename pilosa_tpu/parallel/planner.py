@@ -72,7 +72,8 @@ _BITMAP_CALLS = frozenset(
 class MeshPlanner:
     """Shard-stacked SPMD execution of bitmap call trees."""
 
-    #: default device-memory budget for cached leaf stacks (bytes).
+    #: default budget for resident stacks, in bytes a device: four
+    #: devices hold four times what one does (parallel.stacks).
     DEFAULT_CACHE_BYTES = 4 << 30
     #: whether the store's workers upload a plan's stacks ahead of the
     #: request. Off for the distributed planner: its stack builds must
@@ -114,7 +115,7 @@ class MeshPlanner:
         #: cross-query transfer coalescing (parallel.batcher): every
         #: Count pull goes through it, so concurrent queries share one
         #: stacked device->host transfer per wave.
-        self.batcher = TransferBatcher()
+        self.batcher = TransferBatcher(stats=stats)
         #: tiny host-side filter cache for the two passes of a TopN
         #: that takes the per-fragment sweep (keyed by call text +
         #: shards + epoch; each pull is a link round-trip).
@@ -867,9 +868,16 @@ class MeshPlanner:
     def _stacks_fit(self, n_stacks: int, n_shards: int) -> bool:
         """Whether one call may hold ``n_stacks`` dense ``[S_pad, W]``
         stacks at once: it keeps strong references to all of them for
-        its whole length, so LRU eviction cannot make room under it."""
-        return (n_stacks * _residency.dense_nbytes(self._pad(n_shards))
+        its whole length, so LRU eviction cannot make room under it.
+        Counted on one device, as the budget is."""
+        return (n_stacks * self._dense_device_bytes(n_shards)
                 <= min(self.max_cache_bytes, 2 << 30))
+
+    def _dense_device_bytes(self, n_shards: int) -> int:
+        """Bytes one dense stack over ``n_shards`` places on each device
+        of the mesh: what the store charges it."""
+        return _residency.dense_nbytes(self._pad(n_shards)) \
+            // self.n_devices
 
     def _field_row_ids(self, idx: Index, field_name: str, view: str,
                        shards: ShardSet) -> np.ndarray:
@@ -1206,6 +1214,7 @@ class MeshPlanner:
 
     @property
     def max_cache_bytes(self) -> int:
+        """The stack store's budget, in bytes a device."""
         return self.stacks.budget_bytes
 
     @max_cache_bytes.setter

@@ -15,6 +15,12 @@ finds the upload landed (a plain hit) or in flight, and waits for it
 (`hits`) instead of building the same stack itself (`sync_misses`).
 The table of uploads in flight dedupes by key, so a wave of same-plan
 requests costs one upload a leaf.
+
+The budget is bytes a device. Each entry is charged what it places on
+the fullest device it spans, read from the array's addressable shards:
+a stack sharded over four chips is charged a quarter of its bytes, a
+replicated or single-device array all of them. On one device that is
+the array's whole size, as it always was.
 """
 
 from __future__ import annotations
@@ -47,11 +53,29 @@ class _Entry(NamedTuple):
     epoch: int
     gens: tuple
     arr: Any
+    #: the array's bytes over every device it spans.
     nbytes: int
+    #: the bytes it places on its fullest device: what the budget pays.
+    charge: int
+
+
+def device_charge(arr, nbytes: int) -> int:
+    """Bytes ``arr`` places on the fullest device it spans, from its
+    addressable shards; ``nbytes`` for an array that has none (a host
+    stand-in)."""
+    shards = getattr(arr, "addressable_shards", None)
+    if not shards:
+        return nbytes
+    held: dict = {}
+    for shard in shards:
+        held[shard.device] = held.get(shard.device, 0) \
+            + int(shard.data.nbytes)
+    return max(held.values())
 
 
 class StackStore:
-    """Budgeted LRU of device arrays, and the workers that fill it."""
+    """LRU of device arrays under a budget of bytes a device, and the
+    workers that fill it."""
 
     #: for a caller that is handed the store and imports nothing of it.
     key = StackKey
@@ -62,6 +86,7 @@ class StackStore:
 
     def __init__(self, budget_bytes: int, classes: tuple[str, ...],
                  stats=None, uploads_ahead: bool = True):
+        #: bytes a device (see the module's docstring).
         self.budget_bytes = budget_bytes
         self.stats = stats
         #: False: `schedule` takes nothing and every miss is built by
@@ -70,7 +95,9 @@ class StackStore:
         self.uploads_ahead = uploads_ahead
         self._lock = threading.Lock()
         self._entries: "OrderedDict[StackKey, _Entry]" = OrderedDict()
+        #: bytes over every device, and the charges against the budget.
         self._bytes = 0
+        self._charged = 0
         self._class_bytes = dict.fromkeys(classes, 0)
         #: lifetime counts: with the working set over the budget,
         #: uploads track requests instead of flatlining after warm-up.
@@ -188,9 +215,12 @@ class StackStore:
     def insert(self, key: StackKey, epoch: int, gens: tuple, arr,
                nbytes: int, *, count_upload: bool = True) -> None:
         """The one insertion and byte-accounting path of every class.
-        Insert first, evict after, never the last entry: an upload
-        overlaps the evictee's last use instead of queueing behind the
-        eviction (the overshoot is one stack)."""
+        ``nbytes`` is the array's size over every device (what an upload
+        moves); the budget is charged `device_charge`. Insert first,
+        evict after, never the last entry: an upload overlaps the
+        evictee's last use instead of queueing behind the eviction (the
+        overshoot is one stack)."""
+        charge = device_charge(arr, nbytes)
         with self._lock:
             if count_upload:
                 self._uploads += 1
@@ -198,10 +228,11 @@ class StackStore:
             old = self._entries.pop(key, None)
             if old is not None:
                 self._give_back(key, old)
-            self._entries[key] = _Entry(epoch, gens, arr, nbytes)
+            self._entries[key] = _Entry(epoch, gens, arr, nbytes, charge)
             self._bytes += nbytes
+            self._charged += charge
             self._class_bytes[key.klass] += nbytes
-            while (self._bytes > self.budget_bytes
+            while (self._charged > self.budget_bytes
                    and len(self._entries) > 1):
                 self._give_back(*self._entries.popitem(last=False))
                 self._evictions += 1
@@ -212,6 +243,7 @@ class StackStore:
 
     def _give_back(self, key: StackKey, entry: _Entry) -> None:
         self._bytes -= entry.nbytes
+        self._charged -= entry.charge
         self._class_bytes[key.klass] -= entry.nbytes
 
     def drop_index(self, index_name: str) -> None:
@@ -223,12 +255,16 @@ class StackStore:
         with self._lock:
             self._entries.clear()
             self._bytes = 0
+            self._charged = 0
             self._class_bytes = dict.fromkeys(self._class_bytes, 0)
 
     def snapshot(self) -> dict:
-        """Occupancy and churn."""
+        """Occupancy and churn. ``bytes`` is over every device;
+        ``fullest_device_bytes`` the charges that ``budget_bytes``, a
+        device's, bounds."""
         with self._lock:
             return {"bytes": self._bytes,
+                    "fullest_device_bytes": self._charged,
                     "budget_bytes": self.budget_bytes,
                     "entries": len(self._entries),
                     "evictions": self._evictions,

@@ -40,7 +40,6 @@ def run(shards: int, rows: int, bits: int, uploads: int, fit: int) -> dict:
     from pilosa_tpu import native
     from pilosa_tpu.config import SHARD_WIDTH, WORDS_PER_SHARD
     from pilosa_tpu.core import Holder
-    from pilosa_tpu.exec import residency
     from pilosa_tpu.obs.stats import MemoryStats
     from pilosa_tpu.parallel import MeshPlanner, make_mesh
 
@@ -60,7 +59,7 @@ def run(shards: int, rows: int, bits: int, uploads: int, fit: int) -> dict:
     stats = MemoryStats()
     planner = MeshPlanner(h, make_mesh(), stats=stats)
     s_pad = planner._pad(shards)
-    planner.max_cache_bytes = fit * residency.dense_nbytes(s_pad)
+    planner.max_cache_bytes = fit * planner._dense_device_bytes(shards)
 
     def rebuild(row: int) -> np.ndarray:
         want = np.zeros((s_pad, WORDS_PER_SHARD), dtype=np.uint32)

@@ -307,9 +307,7 @@ def test_planner_topn_matches_scalar(env, rng, q):
 def _sweep_only(planner, n_shards, stacks=1):
     """Leave the planner a budget of ``stacks`` dense stacks: a field of
     more candidate rows keeps the per-fragment sweep (`_stacks_fit`)."""
-    from pilosa_tpu.exec import residency
-    planner.max_cache_bytes = stacks * residency.dense_nbytes(
-        planner._pad(n_shards))
+    planner.max_cache_bytes = stacks * planner._dense_device_bytes(n_shards)
 
 
 def _routes(stats):
@@ -724,9 +722,11 @@ def test_stack_store_evicts_lru_and_accounts_bytes(mesh, rng, monkeypatch):
     for r in range(6):
         cols = rng.integers(0, total, 2000)
         f.import_bits(np.full(len(cols), r), cols)
-    # One leaf stack = S_pad(8) * W * 4 bytes; budget fits exactly 3.
+    # One leaf stack = S_pad(8) * W * 4 bytes, a device's share of it
+    # the budget's unit; the budget fits exactly 3.
     stack_bytes = 8 * (SHARD_WIDTH // 32) * 4
-    planner = MeshPlanner(h, mesh, max_cache_bytes=3 * stack_bytes)
+    share = stack_bytes // mesh.devices.size
+    planner = MeshPlanner(h, mesh, max_cache_bytes=3 * share)
     e = Executor(h, planner=planner, result_cache=False)
     shards = list(range(n_shards))
 
@@ -736,7 +736,7 @@ def test_stack_store_evicts_lru_and_accounts_bytes(mesh, rng, monkeypatch):
     st = planner.cache_stats()
     assert st["entries"] == 3
     assert st["bytes"] == 3 * stack_bytes          # exact accounting
-    assert st["bytes"] <= st["budget_bytes"]
+    assert st["fullest_device_bytes"] == 3 * share == st["budget_bytes"]
     assert st["evictions"] == 3                    # 6 leaves, 3 survived
     assert _stack_key_rows(planner) == [3, 4, 5]   # LRU order: oldest out
 
@@ -772,7 +772,8 @@ def test_stack_store_eviction_does_not_break_inflight_refs(mesh, rng,
         cols = rng.integers(0, total, 2000)
         f.import_bits(np.full(len(cols), r), cols)
     stack_bytes = 8 * (SHARD_WIDTH // 32) * 4
-    planner = MeshPlanner(h, mesh, max_cache_bytes=2 * stack_bytes)
+    planner = MeshPlanner(h, mesh, max_cache_bytes=2 * stack_bytes
+                          // mesh.devices.size)
     e = Executor(h, planner=planner, result_cache=False)
     shards = list(range(n_shards))
 

@@ -298,7 +298,7 @@ def test_oversubscribed_prefetch_no_sync_uploads(mesh, monkeypatch):
     rng = np.random.default_rng(5)
     for r in range(6):
         f.import_bits(np.full(2000, r), rng.integers(0, total, 2000))
-    stack_bytes = residency.dense_nbytes(8)
+    stack_bytes = residency.dense_nbytes(8) // mesh.devices.size
     planner = MeshPlanner(h, mesh, max_cache_bytes=3 * stack_bytes)
     e = Executor(h, planner=planner, result_cache=False)
     shards = list(range(n_shards))
