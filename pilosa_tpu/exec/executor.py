@@ -61,6 +61,7 @@ from pilosa_tpu.exec.result import (
     merge_row_ids,
     sort_pairs,
 )
+from pilosa_tpu.exec.topn import reduce_pairs
 from pilosa_tpu.pql import BETWEEN, NEQ, Call, Condition, Query, parse
 from pilosa_tpu.pql import ast as pql_ast
 from pilosa_tpu.qos.deadline import check_current as check_deadline
@@ -78,6 +79,18 @@ def _wrap_result(r):
     """Default finisher for execute_async's dispatch paths: a resolved
     scalar becomes the single-call results list."""
     return [r]
+
+
+def _min_threshold(c: Call) -> int:
+    """A TopN's per-shard count threshold; 0 means the default."""
+    threshold, _ = c.uint_arg("threshold")
+    return threshold or DEFAULT_MIN_THRESHOLD
+
+
+def _pairs(reduced: tuple[np.ndarray, np.ndarray]) -> list[Pair]:
+    """`topn.reduce_pairs`' (ids, sums) as result pairs."""
+    ids, sums = reduced
+    return [Pair(id=i, count=s) for i, s in zip(ids.tolist(), sums.tolist())]
 
 
 @dataclass
@@ -126,8 +139,11 @@ class Executor:
             # Calls that left the planner's one-dispatch path for the
             # per-shard interpreter, by query class. Published at 0 so
             # that a reader tells "never fell back" from "no such counter".
+            # With them, TopN's second passes read from the first pass's
+            # count matrix, with no launch of their own.
             for name in ("executor.fallback.topn",
-                         "executor.fallback.groupby"):
+                         "executor.fallback.groupby",
+                         "executor.topn.passesFromMatrix"):
                 self.stats.count(name, 0)
         #: query-string -> parsed Query. Parsed trees are shared across
         #: threads; every consumer clones before mutating
@@ -1203,38 +1219,57 @@ class Executor:
         ids_arg, _ = c.uint_slice_arg("ids")
         n, _ = c.uint_arg("n")
 
-        if self.planner is not None and self._topn_batch_fn(idx, c) is None:
+        passes: list[tuple] = []
+        batch = self._topn_batch_fn(idx, c, passes)
+        if self.planner is not None and batch is None:
             # once a call, both passes: it leaves the planner for the
             # per-shard path
             self.stats.count("executor.fallback.topn", 1)
-        pairs = self._top_n_shards(idx, c, shards, opt)
+        pairs = self._top_n_shards(idx, c, shards, opt, batch)
         if not pairs or ids_arg or opt.remote:
             return pairs
 
         # Pass 2: exact counts for the merged candidate ids.
-        other = c.clone()
-        other.args["ids"] = sorted(p.id for p in pairs)
-        trimmed = self._top_n_shards(idx, other, shards, opt)
+        ids = sorted(p.id for p in pairs)
+        if (len(passes) == 1 and passes[0][1].stacked
+                and set(passes[0][0]) == set(shards)):
+            # Pass 1 was one local program over every shard of the call,
+            # and its matrix holds every candidate's count in each: the
+            # recount is read from it, untruncated as explicit ids are.
+            wanted = np.asarray(ids, dtype=np.uint64)
+            trimmed = _pairs(reduce_pairs(
+                passes[0][1], 0, _min_threshold(c),
+                lambda distinct: np.isin(distinct, wanted)))
+            self.stats.count("executor.topn.passesFromMatrix", 1)
+        else:
+            other = c.clone()
+            other.args["ids"] = ids
+            trimmed = self._top_n_shards(idx, other, shards, opt,
+                                         self._topn_batch_fn(idx, other))
         if n and n < len(trimmed):
             trimmed = trimmed[:n]
         return trimmed
 
-    def _top_n_shards(self, idx: Index, c: Call, shards, opt) -> list[Pair]:
+    def _top_n_shards(self, idx: Index, c: Call, shards, opt,
+                      batch) -> list[Pair]:
         def reduce_fn(p, v):
             return merge_pairs(p or [], v)
 
         merged = self.map_reduce(
             idx, shards, c, opt,
             lambda shard: self._top_n_shard(idx, c, shard), reduce_fn,
-            local_batch_fn=self._topn_batch_fn(idx, c)) or []
+            local_batch_fn=batch) or []
         return sort_pairs(merged)
 
-    def _topn_batch_fn(self, idx: Index, c: Call):
+    def _topn_batch_fn(self, idx: Index, c: Call, passes: list | None = None):
         """Planner TopN: one sparse-aware streamed device program for ALL
-        local shards (planner.execute_topn_pairs) instead of a per-shard
-        loop, preserving per-shard filter/threshold/truncate semantics.
-        Returns None when the call needs the per-shard path (tanimoto
-        needs per-shard src counts; unplannable filter trees)."""
+        local shards (planner.execute_topn_counts) instead of a per-shard
+        loop, and one vectorised reduction of every shard's counts
+        (`topn.reduce_pairs`) preserving per-shard threshold, attribute
+        filter and truncation. Each batch appends its shards and counts
+        to ``passes``. Returns None when the call needs the per-shard
+        path (tanimoto needs per-shard src counts; unplannable filter
+        trees)."""
         if self.planner is None:
             return None
         field_name = c.args.get("_field")
@@ -1253,14 +1288,18 @@ class Executor:
         n, _ = c.uint_arg("n")
         if has_ids:
             n = 0  # explicit ids: no truncation (fragment.go:1575)
-        min_threshold, _ = c.uint_arg("threshold")
-        if min_threshold == 0:
-            min_threshold = DEFAULT_MIN_THRESHOLD
+        min_threshold = _min_threshold(c)
 
         attr_name = c.args.get("attrName")
         attr_values = c.args.get("attrValues")
-        allowed_attrs = set(attr_values) if (attr_name and attr_values) \
-            else None
+        admit = None
+        if attr_name and attr_values:
+            allowed_attrs = set(attr_values)
+            attrs = f.row_attr_store.attrs
+
+            def admit(distinct: np.ndarray) -> list[bool]:
+                return [attrs(rid).get(attr_name) in allowed_attrs
+                        for rid in distinct.tolist()]
 
         def batch(shs: list[int]) -> list[Pair]:
             # cache_type 'none' errors only if a fragment exists, exactly
@@ -1274,32 +1313,12 @@ class Executor:
                         f'cannot compute TopN(), field has no cache: '
                         f'"{field_name}"')
                 return []
-            per_shard = self.planner.execute_topn_counts(
+            counts = self.planner.execute_topn_counts(
                 idx, field_name, VIEW_STANDARD, list(shs), filter_call,
                 row_ids=[int(r) for r in row_ids] if has_ids else None)
-            acc: list[Pair] = []
-            for shard in sorted(per_shard):
-                # Arrives sorted (count desc, id asc); threshold is an
-                # order-preserving mask, then attr filter, then truncate
-                # — same order as _top_filter_pairs.
-                ids, counts = per_shard[shard]
-                keep = counts >= min_threshold
-                ids, counts = ids[keep], counts[keep]
-                if len(ids) == 0:
-                    continue
-                if allowed_attrs is None and n:
-                    ids, counts = ids[:n], counts[:n]
-                pairs: list[Pair] = []
-                for rid, cnt in zip(ids.tolist(), counts.tolist()):
-                    if allowed_attrs is not None:
-                        attrs = f.row_attr_store.attrs(rid)
-                        if attrs.get(attr_name) not in allowed_attrs:
-                            continue
-                    pairs.append(Pair(id=rid, count=cnt))
-                    if n and len(pairs) >= n:
-                        break
-                acc = merge_pairs(acc, pairs)
-            return acc
+            if passes is not None:
+                passes.append((shs, counts))
+            return _pairs(reduce_pairs(counts, n, min_threshold, admit))
 
         return batch
 
@@ -1315,7 +1334,6 @@ class Executor:
 
         attr_name = c.args.get("attrName")
         row_ids, has_ids = c.uint_slice_arg("ids")
-        min_threshold, _ = c.uint_arg("threshold")
         attr_values = c.args.get("attrValues")
         tanimoto, _ = c.uint_arg("tanimotoThreshold")
         if tanimoto > 100:
@@ -1332,8 +1350,7 @@ class Executor:
             return []
         if frag.cache_type == "none":
             raise QueryError(f'cannot compute TopN(), field has no cache: "{field_name}"')
-        if min_threshold == 0:
-            min_threshold = DEFAULT_MIN_THRESHOLD
+        min_threshold = _min_threshold(c)
 
         if has_ids:
             n = 0  # explicit ids: no truncation (fragment.go:1575)

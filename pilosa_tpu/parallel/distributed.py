@@ -53,6 +53,7 @@ from pilosa_tpu.core.shardset import as_shard_set
 from pilosa_tpu.core.view import VIEW_STANDARD
 from pilosa_tpu.errors import QueryError
 from pilosa_tpu.exec.executor import Executor
+from pilosa_tpu.exec.topn import TopNCounts
 from pilosa_tpu.parallel.mesh import SHARD_AXIS
 from pilosa_tpu.parallel.planner import MeshPlanner, _named
 from pilosa_tpu.pql import Call
@@ -315,7 +316,7 @@ class DistributedMeshPlanner(MeshPlanner):
         merged: dict[int, tuple] = {}
         for part in allgather_obj(local):
             merged.update(part)
-        return merged
+        return TopNCounts.from_shards(merged)
 
     # -- GroupBy ---------------------------------------------------------
 

@@ -48,6 +48,7 @@ from pilosa_tpu.errors import (
 from pilosa_tpu import native
 from pilosa_tpu.exec import fuse as _fuse
 from pilosa_tpu.exec import residency as _residency
+from pilosa_tpu.exec.topn import TopNCounts
 from pilosa_tpu.obs import profile as _profile
 from pilosa_tpu.obs.tracing import start_span
 from pilosa_tpu.obs.histogram import WIDTH_BOUNDS, LogHistogram
@@ -917,11 +918,11 @@ class MeshPlanner:
 
     def execute_topn_counts(self, idx: Index, field_name: str, view: str,
                             shards: list[int], filter_call: Call | None,
-                            row_ids=None) -> dict[int, tuple]:
-        """shard -> (ids, counts) arrays SORTED by count desc / id asc,
-        preserving per-fragment semantics (threshold filtering stays per
-        shard in the executor, matching executeTopNShards merge
-        semantics, executor.go:902)."""
+                            row_ids=None) -> TopNCounts:
+        """Every shard's (ids, counts), each shard's SORTED by count desc
+        / id asc, preserving per-fragment semantics (threshold filtering
+        stays per shard in the executor, matching executeTopNShards
+        merge semantics, executor.go:902)."""
         allowed = (np.asarray(sorted(set(int(r) for r in row_ids)),
                               dtype=np.uint64)
                    if row_ids is not None else None)
@@ -1012,18 +1013,18 @@ class MeshPlanner:
         for shard, ids, counts, _ in pending:
             order = np.lexsort((ids, -counts))
             out[shard] = (ids[order], counts[order])
-        return out
+        return TopNCounts.from_shards(out)
 
     def _topn_counts_stacked(self, idx: Index, field_name: str, view: str,
                              shards: ShardSet, filter_call: Call,
-                             cands: np.ndarray) -> dict[int, tuple]:
+                             cands: np.ndarray) -> TopNCounts:
         """One filtered pass as ONE program: the filter tree and the
         per-shard popcount of ``row AND filter`` for every candidate
         row, over the rows' resident dense stacks (fetched like any
         plan's leaves, so the store accounts for them, uploads them
         ahead and may evict them), whatever tier a fragment holds a row
-        in. A (row, shard) pair that counts 0 is left out: the executor
-        keeps counts of at least one."""
+        in. Every (row, shard) pair is an entry, those that count 0
+        too: the executor keeps counts of at least one."""
         r = len(cands)
         # the row ids are arguments, the row count a power of two: fields
         # of 5 to 8 rows, and every filter of one shape, share a program
@@ -1053,10 +1054,8 @@ class MeshPlanner:
         # counts keeps the candidates' ascending ids among equals
         order = np.argsort(-counts, axis=1, kind="stable")
         counts = np.take_along_axis(counts, order, axis=1)
-        ids = cands[order]
-        kept = np.count_nonzero(counts, axis=1).tolist()
-        return {shard: (ids[i, :k], counts[i, :k])
-                for i, (shard, k) in enumerate(zip(shards, kept)) if k}
+        return TopNCounts(np.repeat(np.asarray(shards, dtype=np.int64), r),
+                          cands[order].ravel(), counts.ravel(), stacked=True)
 
     def _compiled_topn_counts(self, r_pad: int, filt_sig: tuple,
                               n_filter: int) -> Callable:

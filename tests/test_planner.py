@@ -312,7 +312,8 @@ def _sweep_only(planner, n_shards, stacks=1):
 
 def _routes(stats):
     return (stats.counter_value("planner.topn.passesStacked"),
-            stats.counter_value("planner.topn.passesSwept"))
+            stats.counter_value("planner.topn.passesSwept"),
+            stats.counter_value("executor.topn.passesFromMatrix"))
 
 
 @pytest.fixture(scope="module")
@@ -363,11 +364,12 @@ def test_planner_filtered_topn_counts_the_resident_stacks(ranked, case):
     """A filtered pass is one program over the candidate rows' dense
     stacks and the filter tree, whatever tier a fragment holds a row
     in; a field whose stacks do not fit the budget keeps the sweep. Both
-    answer what the per-shard interpreter answers."""
+    answer what the per-shard interpreter answers. The second pass runs
+    unless ids were given or the first found nothing; after the one
+    program it is read from the first pass's matrix, with no launch."""
     h, plain, fast, stats = ranked
     q = STACKED_TOPN_CASES[case]
     (want,) = plain.execute("i", q)
-    # the second pass runs unless ids were given or the first found nothing
     passes = 2 if want and "ids=" not in q else 1
     budget = fast.planner.max_cache_bytes
     swept = case.startswith("over its budget")
@@ -383,8 +385,8 @@ def test_planner_filtered_topn_counts_the_resident_stacks(ranked, case):
     assert [(p.id, p.count) for p in got] == \
         [(p.id, p.count) for p in want], q
     assert bool(want) == (case not in EMPTY_TOPN_ANSWERS), q
-    stacked, swept_n = (b - a for a, b in zip(before, _routes(stats)))
-    assert (stacked, swept_n) == ((0, passes) if swept else (passes, 0))
+    moved = tuple(b - a for a, b in zip(before, _routes(stats)))
+    assert moved == ((0, passes, 0) if swept else (1, 0, passes - 1))
 
 
 @pytest.fixture(scope="module", params=[(8, "pow2"), (1, "pow2"), (1, "none")],
@@ -577,7 +579,7 @@ def test_planner_topn_program_keeps_its_operands_on_the_mesh(
 
     monkeypatch.setattr(planner.coalescer, "dispatch", spy)
     (got,) = fast.execute("i", "TopN(f, Row(g=1), n=2)")
-    assert len(seen) == 2  # one launch a pass
+    assert len(seen) == 1  # one launch: pass 2 reads pass 1's matrix
     for shardings in seen:
         assert len(shardings) == 3  # the filter's leaf, rows 1 and 2
         for sh in shardings:

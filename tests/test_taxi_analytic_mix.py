@@ -212,10 +212,10 @@ def test_the_new_metrics_are_on_the_line_with_the_shapes_counts(cut_run):
     assert {n for n in metrics if not n.startswith("import_")} == NEW_METRICS
     value = {name: m["value"] for name, m in metrics.items()}
     # passenger_count's eight dense stacks fit the planner's budget: one
-    # program a pass counts all of them, in each of TopN's two passes,
-    # whatever tier a fragment holds a row in; nothing is counted on the
-    # host
-    assert value["topn_launches_per_call"] == 2
+    # program counts all of them in TopN's first pass, whatever tier a
+    # fragment holds a row in, and the second pass reads its matrix;
+    # nothing is counted on the host
+    assert value["topn_launches_per_call"] == 1
     assert value["topn_host_tier_share"] == 0.0
     # one program a GroupBy counts every (year, passenger count) group
     assert value["groupby_launches_per_call"] == 1
@@ -358,19 +358,21 @@ def test_the_planners_answers_are_the_interpreters(served):
 
 
 @pytest.mark.parametrize("name, amount", [
-    # two passes, one program each over the four rows' dense stacks:
-    # every (row, fragment) pair counted on the device, none on the host
-    ("planner.topn.launches", lambda s, c, y: 2),
-    ("planner.topn.rowsDeviceTier", lambda s, c, y: 2 * s * c),
+    # one program over the four rows' dense stacks counts every (row,
+    # fragment) pair on the device, none on the host; the second pass
+    # reads its matrix
+    ("planner.topn.launches", lambda s, c, y: 1),
+    ("planner.topn.rowsDeviceTier", lambda s, c, y: s * c),
     ("planner.topn.rowsHostTier", lambda s, c, y: 0),
-    ("planner.topn.passesStacked", lambda s, c, y: 2),
+    ("planner.topn.passesStacked", lambda s, c, y: 1),
     ("planner.topn.passesSwept", lambda s, c, y: 0),
+    ("executor.topn.passesFromMatrix", lambda s, c, y: 1),
     # one program counts every group
     ("planner.groupby.launches", lambda s, c, y: 1),
     ("planner.groupby.groups", lambda s, c, y: y * c),
-    # one span a pass; the GroupBy's once
-    ("span.topn.filter.count", lambda s, c, y: 2),
-    ("span.topn.sweep.count", lambda s, c, y: 2),
+    # one span a counted pass; the GroupBy's once
+    ("span.topn.filter.count", lambda s, c, y: 1),
+    ("span.topn.sweep.count", lambda s, c, y: 1),
     ("span.groupby.lattice.count", lambda s, c, y: 1),
     # the mix's calls stay on the planner's path
     ("executor.fallback.topn", lambda s, c, y: 0),
@@ -385,8 +387,9 @@ def test_topn_and_groupby_move_a_counter_by_the_shapes_amount(
 def test_the_fallback_counters_are_published_before_any_call(served):
     assert served["published"][("executor.fallback.topn", ())] == 0
     assert served["published"][("executor.fallback.groupby", ())] == 0
-    # every launch of TopN's passes and the GroupBy is a dispatch
-    assert served["after_mix"][("planner.dispatchCount", ())] >= 2 + 1
+    assert served["published"][("executor.topn.passesFromMatrix", ())] == 0
+    # every launch of TopN's pass and the GroupBy is a dispatch
+    assert served["after_mix"][("planner.dispatchCount", ())] >= 1 + 1
 
 
 @pytest.mark.parametrize("span, call", [
