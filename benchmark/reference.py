@@ -9,18 +9,40 @@ passes over the words are many small numpy calls, which threads would run
 one at a time. Supported: ``Count`` over ``Row``/``Intersect``/``Union``/
 ``Difference``/``Xor``, filtered or plain ``TopN`` and ``GroupBy`` over
 ``Rows``. ``Not`` is left out: the roaring route records no existence.
+
+Writes (``Set``) are judged in real time, from the client's side. A
+``History`` holds every write of a run with the times its client sent it
+and got its answer. For a read sent at *s* and answered at *e*, M is every
+write acknowledged before *s* (it must be in the answer) and O every other
+write sent before *e* (acknowledged late or never: any subset of O may be
+in it). The admissible answers of a ``Count`` tree are its seeded answer
+plus, for each column that M or O writes, the tree's value on that
+column's bits after the writes less its value on the seeded bits; writes
+to different columns add independently, so the sums are built one column
+at a time over the subsets of that column's own writes in O, exactly. A
+``Set`` answers ``true`` exactly when its bit is absent from the seeded
+data and M; with a write of the same bit in O either answer passes.
+
+What ``run.py`` asks of a reference module (a traffic file names another
+by its ``"reference"`` key): ``Catalogue`` (``writes``, ``expected``,
+``tree``, ``bit``, ``leaves``, ``allowed``), ``History`` (``add``,
+``holds``, ``writes``), ``run_pass``, ``norm``, ``written_fields``.
 """
 
 from __future__ import annotations
 
+import bisect
+import functools
 import multiprocessing
+import operator
 import re
 from concurrent.futures import ProcessPoolExecutor
+from dataclasses import dataclass
 
 import numpy as np
 
 import dataset
-from traffic import structure_text
+from traffic import structure_text, structures
 
 _TOKEN = re.compile(r"\s*([A-Za-z_][A-Za-z0-9_]*|\{[A-Za-z_][A-Za-z0-9_]*\}"
                     r"|-?\d+|[(),=])")
@@ -171,18 +193,42 @@ def group_partials(groups: dict, rows: dict) -> dict:
     return out
 
 
+def seeded_bits(per_field: dict, lo: int, columns: np.ndarray) -> dict:
+    """``{column: frozenset of (field, row)}``: the rows whose seeded bits
+    hold each of ``columns``, in-shard positions ``per_field`` of the
+    shard that starts at column ``lo``."""
+    local = columns - lo
+    held = [set() for _ in local]
+    for field, per_row in per_field.items():
+        for r, pos in enumerate(per_row):
+            if not len(pos):
+                continue
+            at = np.minimum(np.searchsorted(pos, local), len(pos) - 1)
+            for j in np.flatnonzero(pos[at] == local).tolist():
+                held[j].add((field, r))
+    return {int(c): frozenset(h) for c, h in zip(columns.tolist(), held)}
+
+
 def shards_partials(groups_list: list, config: dict, seed: int,
-                    shards: list, keep: int | None):
+                    shards: list, keep: int | None,
+                    columns: np.ndarray | None = None):
     """A worker's share: the summed partials of each catalogue's groups
     over ``shards``, rows drawn from the seed, and apart from them shard
     ``keep``'s own partials of the first catalogue (the control leaves
-    that shard out)."""
+    that shard out), and the seeded bits of the written ``columns`` that
+    lie in these shards (``seeded_bits``)."""
     width = 1 << int(config["shard_width_exp"])
     totals = [None] * len(groups_list)
     kept = None
+    held = {}
     for shard in shards:
-        rows = dataset.pack_rows(dataset.shard_rows(config, seed, shard),
-                                 width)
+        per_field = dataset.shard_rows(config, seed, shard)
+        if columns is not None and len(columns):
+            mine = columns[(columns >= shard * width)
+                           & (columns < (shard + 1) * width)]
+            if len(mine):
+                held.update(seeded_bits(per_field, shard * width, mine))
+        rows = dataset.pack_rows(per_field, width)
         for i, groups in enumerate(groups_list):
             part = group_partials(groups, rows)
             if shard == keep and i == 0:
@@ -192,48 +238,234 @@ def shards_partials(groups_list: list, config: dict, seed: int,
             else:
                 for key in part:
                     totals[i][key] += part[key]
-    return totals, kept
+    return totals, kept, held
 
 
 def run_pass(catalogues: list, config: dict, seed: int, workers: int,
-             keep: int | None = None):
-    """Evaluate every catalogue over all shards of the configuration;
-    returns shard ``keep``'s partials of the first catalogue."""
+             keep: int | None = None, history: "History | None" = None):
+    """Evaluate every catalogue over all shards of the configuration, and
+    fill ``history``'s seeded bits of every written column; returns shard
+    ``keep``'s partials of the first catalogue."""
     n = int(config["shards"])
     shares = [list(range(w, n, workers)) for w in range(workers)]
     groups_list = [c.groups for c in catalogues]
+    columns = history.columns() if history is not None else None
     kept = None
     ctx = multiprocessing.get_context("spawn")
     with ProcessPoolExecutor(max_workers=workers, mp_context=ctx) as pool:
         futures = [pool.submit(shards_partials, groups_list, config, seed,
-                               share, keep) for share in shares if share]
+                               share, keep, columns)
+                   for share in shares if share]
         for fut in futures:
-            totals, k = fut.result()
+            totals, k, held = fut.result()
             for cat, total in zip(catalogues, totals):
                 cat.add(total)
             kept = k if k is not None else kept
+            if history is not None:
+                history.seeded.update(held)
     return kept
+
+
+def _int(token: str, values: dict) -> int:
+    """A number of one request: literal, or its ``{placeholder}``."""
+    return int(values[token[1:-1]]) if token.startswith("{") else int(token)
+
+
+def _holds(node, values: dict, held) -> bool:
+    """A bitmap-valued tree (or the ``Count`` around one) on ONE column
+    whose rows ``held`` (``{(field, row)}``) hold it; ``_bitmap``'s
+    semantics, bit by bit."""
+    _, name, args, kwargs = node
+    if name == "Row":
+        (field, token), = kwargs.items()
+        return (field, _int(token, values)) in held
+    parts = [_holds(a, values, held) for a in args]
+    if name == "Count":
+        return parts[0]
+    if name == "Intersect":
+        return all(parts)
+    if name == "Union":
+        return any(parts)
+    if name == "Xor":
+        return functools.reduce(operator.xor, parts)
+    if name == "Difference":
+        return parts[0] and not any(parts[1:])
+    raise PQLError(f"the reference has no bitmap call {name!r}")
+
+
+def _leaves(node, values: dict) -> list:
+    """``(field, row)`` of every ``Row`` the tree names."""
+    _, name, args, kwargs = node
+    if name == "Row":
+        (field, token), = kwargs.items()
+        return [(field, _int(token, values))]
+    return [leaf for a in args for leaf in _leaves(a, values)]
+
+
+def _submasks(mask: int):
+    sub = mask
+    while True:
+        yield sub
+        if sub == 0:
+            return
+        sub = (sub - 1) & mask
+
+
+@dataclass(frozen=True)
+class Bit:
+    """One written bit: ``Set(column, field=row)``."""
+    column: int
+    field: str
+    row: int
+
+    @property
+    def leaf(self) -> tuple:
+        return (self.field, self.row)
+
+
+class History:
+    """Every write of a run with its client's times (``add``), and the
+    seeded bits of the columns they write (``seeded``, filled by
+    ``run_pass``): what each read and write may answer (module doc)."""
+
+    def __init__(self):
+        #: ``(bit, t_send, t_recv, acknowledged)``
+        self.writes: list[tuple] = []
+        #: column -> frozenset of the (field, row) seeded there
+        self.seeded: dict[int, frozenset] = {}
+        self._index = None
+
+    def add(self, bit: Bit, t_send: float, t_recv: float,
+            acked: bool) -> None:
+        self.writes.append((bit, float(t_send), float(t_recv), bool(acked)))
+        self._index = None
+
+    def columns(self) -> np.ndarray:
+        return np.unique(np.asarray([w[0].column for w in self.writes],
+                                    dtype=np.int64))
+
+    def holds(self, bit: Bit) -> bool:
+        """Whether the seeded data already holds ``bit``."""
+        return bit.leaf in self.seeded[bit.column]
+
+    def _by(self) -> tuple[dict, dict]:
+        """The writes by (field, row) and by bit, built once."""
+        if self._index is None:
+            by_leaf, by_bit = {}, {}
+            for w in self.writes:
+                by_leaf.setdefault(w[0].leaf, []).append(w)
+                by_bit.setdefault(w[0], []).append(w)
+            self._index = (by_leaf, by_bit)
+        return self._index
+
+    def set_answers(self, bit: Bit, s: float, e: float) -> tuple:
+        """What a ``Set`` of ``bit`` sent at ``s``, answered at ``e``, may
+        answer; the write itself is in the history once."""
+        present, maybe, itself = self.holds(bit), False, False
+        for _, ts, tr, ok in self._by()[1].get(bit, ()):
+            if not itself and (ts, tr) == (s, e):
+                itself = True
+            elif ok and tr < s:
+                present = True
+            elif ts < e:
+                maybe = True
+        if present:
+            return (False,)
+        return (True, False) if maybe else (True,)
+
+    def count_answers(self, tree, values: dict, seeded: int,
+                      reads: list) -> list[tuple]:
+        """The admissible answers of ``Count`` tree ``tree`` with
+        ``values``, whose answer on the seeded data is ``seeded``, for
+        each read ``(s, e)``: one sweep in order of ``s`` over the writes
+        of the rows it names."""
+        if tree[1] != "Count":
+            raise PQLError(f"the reference judges {tree[1]!r} beside "
+                           f"writes in no way yet: Count trees only")
+        leaves = sorted(set(_leaves(tree, values)))
+        pos = {leaf: j for j, leaf in enumerate(leaves)}
+        ws = [w for leaf in leaves for w in self._by()[0].get(leaf, ())]
+        if not ws:
+            return [(seeded,)] * len(reads)
+        table = [int(_holds(tree, values,
+                            {lf for lf, j in pos.items() if m >> j & 1}))
+                 for m in range(1 << len(leaves))]
+        acked = sorted((w for w in ws if w[3]), key=lambda w: w[2])
+        by_send = sorted(ws, key=lambda w: w[1])
+        sends = [w[1] for w in by_send]
+        failed = [w for w in ws if not w[3]]
+        # An acknowledged write sent this long before s was answered
+        # before s: only the writes sent later can be in O.
+        slack = max((w[2] - w[1] for w in acked), default=0.0)
+        state: dict[int, int] = {}
+
+        def mask(column: int) -> int:
+            m = state.get(column)
+            if m is None:
+                held = self.seeded[column]
+                m = sum(1 << j for lf, j in pos.items() if lf in held)
+            return m
+
+        out = [None] * len(reads)
+        moved = k = 0
+        for r in sorted(range(len(reads)), key=lambda r: reads[r][0]):
+            s, e = reads[r]
+            while k < len(acked) and acked[k][2] < s:
+                bit = acked[k][0]
+                k += 1
+                old = mask(bit.column)
+                state[bit.column] = new = old | 1 << pos[bit.leaf]
+                moved += table[new] - table[old]
+            extra: dict[int, int] = {}
+            lo, hi = bisect.bisect_left(sends, s - slack), \
+                bisect.bisect_left(sends, e)
+            for bit, ts, tr, ok in by_send[lo:hi] + failed:
+                if ts < e and not (ok and tr < s):
+                    extra[bit.column] = extra.get(bit.column, 0) \
+                        | 1 << pos[bit.leaf]
+            sums = {0}
+            for column, more in extra.items():
+                st = mask(column)
+                deltas = {table[st | sub] - table[st]
+                          for sub in _submasks(more)}
+                sums = {a + d for a in sums for d in deltas}
+            out[r] = tuple(sorted(seeded + moved + d for d in sums))
+        return out
+
+
+def written_fields(templates) -> list[str]:
+    """The fields a mix's writes name, sorted."""
+    return sorted({f for ti, choices in structures(templates)
+                   if templates[ti].is_write()
+                   for f in parse(structure_text(templates[ti], choices))[3]})
 
 
 class Catalogue:
     """The distinct requests of a run, grouped by structure, with their
-    expected answers accumulated shard by shard."""
+    expected answers accumulated shard by shard. Writes are kept apart
+    (``bit``): no shard pass answers them, a ``History`` does."""
 
     def __init__(self, templates, requests):
         self.groups = {}
         self.totals = {}
+        #: group -> the parsed Set
+        self.writes = {}
+        #: group -> its template's value names
+        self.names = {}
         by_group = {}
         for r in requests:
             by_group.setdefault(r.group, {}).setdefault(r.values, None)
         for (ti, choices), vals in by_group.items():
             t = templates[ti]
-            if t.is_write():
-                raise PQLError(
-                    "a write inside the measured window needs a reference "
-                    "that tracks the written columns' membership under "
-                    "concurrent order; not built yet (PERF.md, Open "
-                    "questions: count-trees-writes)")
             tree = parse(structure_text(t, choices))
+            self.names[(ti, choices)] = t.value_names
+            if t.is_write():
+                if tree[1] != "Set" or len(tree[2]) != 1 \
+                        or len(tree[3]) != 1:
+                    raise PQLError(f"the reference takes Set(column, "
+                                   f"field=row) alone as a write: {t.pql!r}")
+                self.writes[(ti, choices)] = tree
+                continue
             keys = list(vals)
             arr = np.asarray(keys, dtype=np.int64).reshape(len(keys), -1)
             self.groups[(ti, choices)] = {
@@ -253,9 +485,9 @@ class Catalogue:
                 else self.totals[key] + part
 
     def expected(self, without: dict | None = None) -> dict:
-        """``{(group, values): answer}``; ``without`` takes one shard's
-        partials back out (the control: an answer that is no longer
-        exact)."""
+        """``{(group, values): answer}`` on the seeded data; ``without``
+        takes one shard's partials back out (the control: an answer that
+        is no longer exact)."""
         out = {}
         for key, g in self.groups.items():
             total = self.totals[key]
@@ -266,4 +498,42 @@ class Catalogue:
         return out
 
     def tree(self, request):
-        return self.groups[request.group]["tree"]
+        g = self.groups.get(request.group)
+        return g["tree"] if g is not None else self.writes[request.group]
+
+    def _values(self, request) -> dict:
+        return dict(zip(self.names[request.group], request.values))
+
+    def leaves(self, request) -> list:
+        """``(field, row)`` of every row a request names or writes."""
+        if request.group in self.writes:
+            return [self.bit(request).leaf]
+        return _leaves(self.groups[request.group]["tree"],
+                       self._values(request))
+
+    def bit(self, request) -> Bit:
+        """The bit a write request sets."""
+        _, _, (column,), kwargs = self.writes[request.group]
+        (field, row), = kwargs.items()
+        vals = self._values(request)
+        return Bit(_int(column, vals), field, _int(row, vals))
+
+    def allowed(self, history: History, records: list,
+                expected: dict) -> list[tuple]:
+        """What each of ``records``, ``(request, t_send, t_recv)`` of this
+        catalogue's requests, may answer beside ``history``'s writes;
+        ``expected`` is this catalogue's ``expected()``."""
+        out = [None] * len(records)
+        by_key = {}
+        for i, (req, s, e) in enumerate(records):
+            if req.group in self.writes:
+                out[i] = history.set_answers(self.bit(req), s, e)
+            else:
+                by_key.setdefault((req.group, req.values), []).append(i)
+        for key, idx in by_key.items():
+            answers = history.count_answers(
+                self.groups[key[0]]["tree"], self._values(records[idx[0]][0]),
+                expected[key], [records[i][1:] for i in idx])
+            for i, a in zip(idx, answers):
+                out[i] = a
+        return out

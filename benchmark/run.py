@@ -25,9 +25,11 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import http.client  # noqa: E402
+import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import socket  # noqa: E402
 import statistics  # noqa: E402
@@ -35,6 +37,7 @@ import subprocess  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
 import traceback  # noqa: E402
+from collections import Counter  # noqa: E402
 from concurrent.futures import ThreadPoolExecutor  # noqa: E402
 from dataclasses import dataclass  # noqa: E402
 
@@ -45,7 +48,6 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
 import dataset  # noqa: E402
-import reference  # noqa: E402
 import traffic as traffic_mod  # noqa: E402
 
 INDEX = "i"
@@ -261,10 +263,10 @@ def load_index(srv: Server, config: dict, seed: int) -> tuple[int, float]:
 
 
 def probe_plan(config: dict, seed: int):
-    """The write read-back's request: ``Count(Intersect(Row(fa=a),
-    Row(fb=b)))`` on the configuration's first two fields, and a seeded
-    column of ``fb=b`` that ``fa=a`` lacks, so that an acknowledged
-    ``Set(col, fa=a)`` moves the exact answer by one."""
+    """The write read-back's requests: ``Count(Intersect(Row(fa=a),
+    Row(fb=b)))`` on the configuration's first two fields, and
+    ``Set(col, fa=a)`` on a seeded column of ``fb=b`` that ``fa=a`` lacks,
+    so that the acknowledged write moves the seeded answer by one."""
     fa, fb = sorted(config["fields"])[:2]
     width = 1 << int(config["shard_width_exp"])
     rng = np.random.default_rng([seed, PROBE_STREAM])
@@ -275,15 +277,32 @@ def probe_plan(config: dict, seed: int):
     free = np.setdiff1d(per_field[fb][b], per_field[fa][a])
     need(len(free) > 0, "no column for the write read-back", (a, b, shard))
     col = shard * width + int(free[int(rng.integers(0, len(free)))])
-    spec = {"templates": [{
-        "share": 1.0,
-        "pql": f"Count(Intersect(Row({fa}={{a}}), Row({fb}={{b}})))",
-        "draw": {"a": {"row": fa}, "b": {"row": fb}}}]}
+    spec = {"templates": [
+        {"share": 0.5,
+         "pql": f"Count(Intersect(Row({fa}={{a}}), Row({fb}={{b}})))",
+         "draw": {"a": {"row": fa}, "b": {"row": fb}}},
+        {"share": 0.5, "pql": f"Set({{col}}, {fa}={{a}})",
+         "draw": {"col": {"column": "uniform"}, "a": {"row": fa}}}]}
     templates = traffic_mod.read_templates(spec)
-    t = templates[0]
+    count, write = templates
     req = traffic_mod.Request(0, (), (a, b),
-                              traffic_mod.render(t, {"a": a, "b": b}))
-    return templates, req, f"Set({col}, {fa}={a})"
+                              traffic_mod.render(count, {"a": a, "b": b}))
+    set_req = traffic_mod.Request(1, (), (col, a), traffic_mod.render(
+        write, {"col": col, "a": a}))
+    return templates, req, set_req
+
+
+def rows_plan(fields: list[str], config: dict):
+    """``Count(Row(f=r))`` of every row of each written field: the
+    read-back of every write of the run."""
+    spec = {"templates": [
+        {"share": 1.0 / len(fields), "pql": f"Count(Row({f}={{a}}))",
+         "draw": {"a": {"row": f}}} for f in fields]}
+    templates = traffic_mod.read_templates(spec) if fields else []
+    return templates, [
+        traffic_mod.Request(t.index, (), (r,), traffic_mod.render(t, {"a": r}))
+        for t, f in zip(templates, fields)
+        for r in range(int(config["fields"][f]["rows"]))]
 
 
 # ---------------------------------------------------------------------------
@@ -300,11 +319,13 @@ def fire(srv: Server, requests: list, cached: bool) -> list:
 
 
 def warm_up(srv: Server, config: dict, spec: dict, templates, picker,
-            seed: int) -> dict:
+            seed: int) -> tuple[dict, list]:
     """Every row once (so that what fits is resident), then every program
     structure of the mix at the widths its share of the clients can bring
     together, then the mix itself for a few seconds. Rows come from a
-    stream of their own: the window's requests are not rehearsed."""
+    stream of their own: the window's requests are not rehearsed. Returns
+    the phase's numbers and the records of the mix's writes, which the
+    window's reads have to see."""
     cached = not spec.get("noCache", False)
     clients = int(spec["clients"])
     t0 = time.perf_counter()
@@ -337,13 +358,18 @@ def warm_up(srv: Server, config: dict, spec: dict, templates, picker,
             waves += 1
     t_waves = time.perf_counter()
 
-    n_mix = run_clients(srv, [traffic_mod.draw_stream(
+    mix = [traffic_mod.draw_stream(
         templates, picker, n_columns, seed, WARMUP_STREAM + 1 + c,
-        int(spec["stream_length"])) for c in range(clients)],
-        float(spec.get("warmup_seconds", 0)), cached)["n"]
+        int(spec["stream_length"])) for c in range(clients)]
+    out = run_clients(srv, mix, float(spec.get("warmup_seconds", 0)), cached)
+    writes = []
+    for c, k, *rest in out["records"]:
+        if templates[mix[c][k].template].is_write():
+            writes.append((mix[c][k], *rest))
     return {"rows": n_rows, "rows_s": t_rows - t0, "waves": waves,
-            "waves_s": t_waves - t_rows, "mix_requests": n_mix,
-            "mix_s": time.perf_counter() - t_waves}
+            "waves_s": t_waves - t_rows, "mix_requests": out["n"],
+            "mix_writes": len(writes),
+            "mix_s": time.perf_counter() - t_waves}, writes
 
 
 def run_clients(srv: Server, streams: list, seconds: float, cached: bool,
@@ -368,13 +394,8 @@ def run_clients(srv: Server, streams: list, seconds: float, cached: bool,
         while time.time() < deadline[0]:
             if k and k % len(stream) == 0:
                 wrapped[c] += 1
-            req = stream[k % len(stream)]
-            t_send = time.time()
-            try:
-                ans, err = srv.query(req.pql, cached), None
-            except (BenchFailure, http.client.HTTPException, OSError) as e:
-                ans, err = None, repr(e)
-            recs.append((c, k % len(stream), t_send, time.time(), ans, err))
+            recs.append((c, k % len(stream), *timed_query(
+                srv, stream[k % len(stream)], cached)[1:]))
             k += 1
 
     threads = [threading.Thread(target=client, args=(c,), daemon=True)
@@ -458,36 +479,60 @@ def reduce_trace(xplane: str) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def judge(pairs: list, expected: dict, readback: dict,
-          dispatch_delta: int, who: str = "program") -> dict:
+def admits(allowed: tuple, got) -> bool:
+    """``got`` is one of the admissible answers, in type as in value (a
+    Set's ``true`` is no count of 1)."""
+    return any(got == a and type(got) is type(a) for a in allowed)
+
+
+def gap(got: int, allowed: tuple) -> int:
+    """How far a count lies from the nearest admissible one."""
+    return min(abs(got - a) for a in allowed)
+
+
+def judge(pairs: list, expected, readback: dict, dispatch_delta: int,
+          who: str = "program") -> dict:
     """Every number compared, beside its limit. ``pairs`` holds each
     request of the window with its answer in the comparable form (None:
-    it never came). All comparisons are exact: the configuration states
-    exact answers and acknowledged writes read back, so every limit is
-    0."""
+    it never came). ``expected`` is what the reference admits: where the
+    mix writes nothing, ``{(group, values): answer}``, one exact answer a
+    request; else a list of the admissible answers of each pair.
+    ``readback`` holds the read-back's four answers, what the reference
+    admits of each (``allowed``), and under ``rows`` each written row's
+    count read back after the window with its admissible counts. The
+    configuration states exact answers and acknowledged writes read back,
+    so every limit is 0."""
+    if isinstance(expected, dict):
+        expected = [(expected[(req.group, req.values)],) for req, _ in pairs]
+    pre, before_set, uncached, cached = readback["allowed"]
     wrong = missing = 0
     first_wrong = None
-    for req, got in pairs:
+    for (req, got), allowed in zip(pairs, expected):
         if got is None:
             missing += 1
             continue
-        want = expected[(req.group, req.values)]
-        if got != want:
+        if not admits(allowed, got):
             wrong += 1
             if first_wrong is None:
-                first_wrong = {"pql": req.pql, "got": got, "want": want}
-    want = readback["before"] + 1
+                first_wrong = {"pql": req.pql, "got": got,
+                               "want": allowed[0] if len(allowed) == 1
+                               else list(allowed)}
     checks = {
         "wrong_answers": {"value": wrong, "limit": 0},
         "missing_answers": {"value": missing, "limit": 0},
         "set_not_acknowledged": {
-            "value": 0 if readback["ack"] is True else 1, "limit": 0},
+            "value": 0 if admits(before_set, readback["ack"]) else 1,
+            "limit": 0},
         "readback_pre_gap": {
-            "value": abs(readback["pre"] - readback["before"]), "limit": 0},
+            "value": gap(readback["pre"], pre), "limit": 0},
         "readback_uncached_gap": {
-            "value": abs(readback["uncached"] - want), "limit": 0},
+            "value": gap(readback["uncached"], uncached), "limit": 0},
         "readback_cached_gap": {
-            "value": abs(readback["cached"] - want), "limit": 0},
+            "value": gap(readback["cached"], cached), "limit": 0},
+        "writes_not_read_back": {
+            "value": sum(1 for got, allowed in readback["rows"]
+                         if got is None or not admits(allowed, got)),
+            "limit": 0},
         "window_without_dispatch": {
             "value": 0 if dispatch_delta > 0 else 1, "limit": 0},
     }
@@ -496,25 +541,66 @@ def judge(pairs: list, expected: dict, readback: dict,
     return checks
 
 
+def explain_wrong(pairs: list, allowed: list, window: list, catalogue,
+                  history, most: int = 5) -> None:
+    """A phase line for each of the first ``most`` wrong answers of a mix
+    that writes: its time in flight, the writes to the rows it names
+    around it (times from its send; ``M`` acknowledged before it was
+    sent, ``O`` in flight), and how many answers to the same request the
+    reference admitted."""
+    admitted = Counter((req.group, req.values)
+                       for (req, got), a in zip(pairs, allowed)
+                       if got is not None and admits(a, got))
+    wrong = [(req, got, a, rec[1], rec[2])
+             for (req, got), a, rec in zip(pairs, allowed, window)
+             if got is not None and not admits(a, got)]
+    for req, got, a, s, e in wrong[:most]:
+        rows = set(catalogue.leaves(req))
+        say({"wrong_answer": req.pql, "got": got, "admitted": list(a),
+             "in_flight_s": e - s,
+             "writes_near": [
+                 [b.field, b.row, b.column, ts - s, tr - s,
+                  "M" if ok and tr < s else "O"]
+                 for b, ts, tr, ok in history.writes
+                 if b.leaf in rows and ts < e and tr > s - 1.0],
+             "same_request_admitted": admitted[(req.group, req.values)]})
+
+
 def passes(checks: dict) -> bool:
     return all(c["value"] <= c["limit"] for c in checks.values())
 
 
-def write_readback(srv: Server, probe_req, set_pql: str) -> dict:
+def timed_query(srv: Server, req, cached: bool) -> tuple:
+    """``(request, t_send, t_recv, answer, error)``, as a window's record
+    has them."""
+    t_send = time.time()
+    try:
+        ans, err = srv.query(req.pql, cached), None
+    except (BenchFailure, http.client.HTTPException, OSError) as e:
+        ans, err = None, repr(e)
+    return req, t_send, time.time(), ans, err
+
+
+def write_readback(srv: Server, probe_req, set_req) -> list:
     """One acknowledged Set, then the affected Count uncached and through
-    the result cache (which held the old answer): both must read it."""
-    pre = int(srv.query(probe_req.pql, cached=True))
-    ack = srv.query(set_pql, cached=False)
-    uncached = int(srv.query(probe_req.pql, cached=False))
-    cached = int(srv.query(probe_req.pql, cached=True))
-    return {"pre": pre, "ack": ack, "uncached": uncached, "cached": cached}
+    the result cache (which held the old answer): both must read it.
+    The four records, in order: the count before, the Set, the count
+    uncached, the count cached."""
+    out = [timed_query(srv, req, cached) for req, cached in (
+        (probe_req, True), (set_req, False), (probe_req, False),
+        (probe_req, True))]
+    need(all(r[4] is None for r in out), "a read-back query failed", out)
+    return out
 
 
-def stale_readback(before: int) -> dict:
+def stale_readback(readback: dict, seeded: int, rows: list) -> dict:
     """The control's: a node that acknowledges the write and goes on
-    serving the old answer."""
-    return {"before": before, "pre": before, "ack": True,
-            "uncached": before, "cached": before}
+    serving the seeded answers (``seeded`` the probe's, ``rows`` each
+    written row's count)."""
+    return dict(readback, pre=seeded, ack=True, uncached=seeded,
+                cached=seeded, rows=[
+                    (count, a) for count, (_, a) in zip(rows,
+                                                         readback["rows"])])
 
 
 # ---------------------------------------------------------------------------
@@ -618,10 +704,26 @@ class Plan:
     templates: list
     picker: object
     streams: list
+    ref: object
     probe_cat: object
     probe_req: object
-    set_pql: str
+    set_req: object
+    rows_cat: object
+    rows_reqs: list
     control_shard: int | None
+
+
+def reference_module(spec: dict):
+    """The plain reference the mix names (``"reference"``, a module of
+    this directory; ``reference.py`` where it names none)."""
+    name = spec.get("reference", "reference")
+    need(isinstance(name, str) and re.fullmatch(r"[A-Za-z_]\w*", name)
+         is not None and os.path.exists(os.path.join(HERE, name + ".py")),
+         "the mix's reference is no module of benchmark/", name)
+    mod = importlib.import_module(name)
+    need(os.path.dirname(os.path.abspath(mod.__file__)) == HERE,
+         "the mix's reference is no module of benchmark/", mod.__file__)
+    return mod
 
 
 def plan_run(args) -> Plan:
@@ -644,7 +746,10 @@ def plan_run(args) -> Plan:
         templates, picker, int(config["columns"]),
         int(spec.get("population_seed", seed)), c,
         int(spec["stream_length"])) for c in range(int(spec["clients"]))]
-    probe_templates, probe_req, set_pql = probe_plan(config, seed)
+    ref = reference_module(spec)
+    probe_templates, probe_req, set_req = probe_plan(config, seed)
+    rows_templates, rows_reqs = rows_plan(ref.written_fields(templates),
+                                          config)
     control_shard = None
     if args.control:
         control_shard = int(np.random.default_rng(
@@ -652,12 +757,14 @@ def plan_run(args) -> Plan:
     say({"cell": cell, "config": config_name, "traffic": traffic_name,
          "seed": seed, "seconds": float(args.seconds),
          "trace": int(args.trace), "clients": len(streams),
-         "stream_length": int(spec["stream_length"])})
+         "stream_length": int(spec["stream_length"]),
+         "reference": ref.__name__})
     return Plan(bench, cell, config, spec, load_json(HERE, "peaks.json"),
                 chips, seed, float(args.seconds), bool(int(args.trace)),
-                args.keep_trace, templates, picker, streams,
-                reference.Catalogue(probe_templates, [probe_req]),
-                probe_req, set_pql, control_shard)
+                args.keep_trace, templates, picker, streams, ref,
+                ref.Catalogue(probe_templates, [probe_req, set_req]),
+                probe_req, set_req, ref.Catalogue(rows_templates, rows_reqs),
+                rows_reqs, control_shard)
 
 
 def run(args, launcher: list[str] | None = None) -> dict:
@@ -694,7 +801,9 @@ def measure(plan: Plan, srv: Server) -> dict:
         plan.traced
     templates, streams, probe_req = plan.templates, plan.streams, \
         plan.probe_req
+    ref = plan.ref
     cached = not spec.get("noCache", False)
+    writes_mix = any(t.is_write() for t in templates)
 
     # ---- boot ----
     srv.wait_up()
@@ -726,8 +835,8 @@ def measure(plan: Plan, srv: Server) -> dict:
     t_boot_warm = time.perf_counter()
 
     # ---- the cell's warm-up ----
-    wu = warm_up(srv, config, spec, templates, plan.picker,
-                 int(spec.get("population_seed", seed)))
+    wu, warm_writes = warm_up(srv, config, spec, templates, plan.picker,
+                              int(spec.get("population_seed", seed)))
     dev0 = device_view(srv)
     say({"phase": "warmup", "boot_programs": warm.get("qos.warmupPrograms"),
          "wait_s": t_boot_warm - t_boot - load_s, **wu,
@@ -751,8 +860,10 @@ def measure(plan: Plan, srv: Server) -> dict:
     bounds = switch.finish(srv) if switch else None
     mem_peak = int(gauges.get("runtime.device_peak_bytes_in_use", 0))
 
-    # ---- an acknowledged write is read back, outside the timing ----
-    readback = write_readback(srv, probe_req, plan.set_pql)
+    # ---- every written row, and one acknowledged write, read back
+    # outside the timing ----
+    rows = [timed_query(srv, req, False) for req in plan.rows_reqs]
+    probe = write_readback(srv, probe_req, plan.set_req)
     xplane = find_xplane(srv.trace_dir) if traced else None
     if xplane and plan.keep_trace:
         os.makedirs(plan.keep_trace, exist_ok=True)
@@ -764,28 +875,51 @@ def measure(plan: Plan, srv: Server) -> dict:
 
     # ---- the plain reference, for every request the window sent ----
     records = win["records"]
-    catalogue = reference.Catalogue(
-        templates, [streams[r[0]][r[1]] for r in records])
+    window = [(streams[c][k], *rest) for c, k, *rest in records]
+    window_writes = [r for r in window if templates[r[0].template].is_write()]
+    catalogue = ref.Catalogue(
+        templates, [r[0] for r in window + warm_writes])
+    # Every write of the run, with its client's times: the warm-up's
+    # before the window, the window's, the read-back's after it.
+    history = ref.History()
+    for req, t_send, t_recv, _, err in warm_writes + window_writes:
+        history.add(catalogue.bit(req), t_send, t_recv, err is None)
+    history.add(plan.probe_cat.bit(plan.set_req), *probe[1][1:3], True)
     t_ref = time.perf_counter()
-    control_partials = reference.run_pass(
-        [catalogue, plan.probe_cat], config, seed, REFERENCE_WORKERS,
-        keep=plan.control_shard)
-    ref_s = time.perf_counter() - t_ref
+    control_partials = ref.run_pass(
+        [catalogue, plan.probe_cat, plan.rows_cat], config, seed,
+        REFERENCE_WORKERS, keep=plan.control_shard, history=history)
     expected = catalogue.expected()
-    readback["before"] = plan.probe_cat.expected()[
-        (probe_req.group, probe_req.values)]
-    say({"phase": "reference", "seconds": ref_s, "catalogue": len(catalogue),
-         "write_readback": dict(readback, set=plan.set_pql)})
+    probe_expected = plan.probe_cat.expected()
+    rows_expected = plan.rows_cat.expected()
+    # Where the mix writes nothing, each answer has one exact value.
+    allowed = catalogue.allowed(history, [r[:3] for r in window],
+                                expected) if writes_mix else expected
+    readback = {"pre": int(probe[0][3]), "ack": probe[1][3],
+                "uncached": int(probe[2][3]), "cached": int(probe[3][3]),
+                "allowed": plan.probe_cat.allowed(
+                    history, [r[:3] for r in probe], probe_expected)}
+    rows_allowed = plan.rows_cat.allowed(history, [r[:3] for r in rows],
+                                         rows_expected)
+    readback["rows"] = [
+        (None if err is not None or ans is None else int(ans), a)
+        for (_, _, _, ans, err), a in zip(rows, rows_allowed)]
+    ref_s = time.perf_counter() - t_ref
+    say({"phase": "reference", "module": ref.__name__, "seconds": ref_s,
+         "catalogue": len(catalogue), "writes": len(history.writes),
+         "write_readback": dict(readback, set=plan.set_req.pql,
+                                rows=len(rows))})
 
     # ---- numbers ----
     t0, t1 = win["t0"], win["t0"] + seconds
     dispatch_delta = c1.get("planner.dispatchCount", 0) - \
         c0.get("planner.dispatchCount", 0)
-    pairs = [(streams[c][k],
-              None if err is not None or ans is None
-              else reference.norm(catalogue.tree(streams[c][k]), ans))
-             for c, k, _, _, ans, err in records]
-    checks = judge(pairs, expected, readback, dispatch_delta)
+    pairs = [(req, None if err is not None or ans is None
+              else ref.norm(catalogue.tree(req), ans))
+             for req, _, _, ans, err in window]
+    checks = judge(pairs, allowed, readback, dispatch_delta)
+    if writes_mix:
+        explain_wrong(pairs, allowed, window, catalogue, history)
     correct = passes(checks)
     answered = [r for r in records if r[5] is None]
     failed = len(records) - len(answered)
@@ -793,12 +927,16 @@ def measure(plan: Plan, srv: Server) -> dict:
     in_window = sum(1 for r in answered if r[3] <= t1)
     say({"phase": "window", "requests": len(records), "failed": failed,
          "completed_in_window": in_window, "wrapped_streams": win["wrapped"],
+         "writes": len(window_writes),
+         "write_share": len(window_writes) / max(1, len(records)),
          "dispatch_delta": dispatch_delta,
          "coalesced_delta": c1.get("planner.dispatchCoalesced", 0)
          - c0.get("planner.dispatchCoalesced", 0),
          "evictions_delta": dev1["evictions"] - dev0["evictions"],
          "uploads_delta": dev1["uploads"] - dev0["uploads"],
          "compiles_delta": _compiles(dev1) - _compiles(dev0),
+         "compile_requests_delta": dev1["compileCache"]["requests"]
+         - dev0["compileCache"]["requests"],
          "resident_bytes": dev1["bytes"], "data_dir_bytes": disk})
     need(bool(lat_ms), "the window answered no request")
 
@@ -847,16 +985,32 @@ def measure(plan: Plan, srv: Server) -> dict:
                                "idle_gaps": trace["idle_gaps"]}
     if plan.control_shard is not None:
         # The control: the reference in the program's place, with two of
-        # the configuration's guarantees broken.
+        # the configuration's guarantees broken: every read answered from
+        # the seeded data, less a shard; every Set as if no write had come
+        # before it; the rows and the probe read back without the writes.
+        def stale(req, answers: dict):
+            if req.group in catalogue.writes:
+                return not history.holds(catalogue.bit(req))
+            return answers[(req.group, req.values)]
+
         ctl = catalogue.expected(without=control_partials)
         ctl_checks = judge(
-            [(req, ctl[(req.group, req.values)]) for req, _ in pairs],
-            expected,
-            stale_readback(readback["before"]), 1, who="control")
+            [(req, stale(req, ctl)) for req, _ in pairs], allowed,
+            stale_readback(readback, probe_expected[
+                (probe_req.group, probe_req.values)], [
+                    rows_expected[(r[0].group, r[0].values)] for r in rows]),
+            1, who="control")
         result["control"] = {
             "broken": f"shard {plan.control_shard} left out of every "
-                      "answer; the write served stale",
+                      f"answer; {'every' if writes_mix else 'the'} write "
+                      "served stale",
             "correct": passes(ctl_checks), "checks": ctl_checks}
+        if writes_mix:
+            # The same with no shard left out: the answers the window's
+            # writes alone make wrong.
+            result["control"]["stale_wrong_answers"] = sum(
+                1 for (req, _), a in zip(pairs, allowed)
+                if not admits(a, stale(req, expected)))
     result["checks"] = checks
     return result
 

@@ -6,6 +6,7 @@ files only. None of these is a measurement; they prove the flow and that
 import argparse
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -61,6 +62,100 @@ def test_control_comes_out_not_correct(rehearsal):
     assert control["checks"]["wrong_answers"]["value"] > 0
     assert control["checks"]["readback_uncached_gap"]["value"] == 1
     assert control["checks"]["readback_cached_gap"]["value"] == 1
+
+
+@pytest.fixture(scope="module")
+def writes_rehearsal():
+    rc, last, err = run_cli(ROOT, "--config", "rehearsal-4s", "--traffic",
+                            "count-trees-writes", "--seed", "3000000045",
+                            "--seconds", "3", "--trace", "0", "--control")
+    assert rc == 0, err[-3000:]
+    return last, err
+
+
+def test_writes_rehearsal_holds_the_writes_and_its_control_does_not(
+        writes_rehearsal):
+    """Sets inside the window: every write reads back and the program's
+    answers stand beside the writes acknowledged around them, but for the
+    program's known torn read (PERF.md, Open questions: a tree that names
+    one row twice may see a Set in flight in one of the two), which this
+    test lets stand and pins to that shape; the control, which serves
+    every write stale, fails the reads, the rows read back and the
+    probe."""
+    last, err = writes_rehearsal
+    assert last["failed"] == 0
+    checks = {k: c["value"] for k, c in last["checks"].items()}
+    wrong = checks.pop("wrong_answers")
+    assert all(v == 0 for v in checks.values()), checks
+    told = [json.loads(line)["wrong_answer"] for line in err.splitlines()
+            if line.startswith('{"wrong_answer": ')]
+    assert len(told) == min(wrong, 5)
+    for pql in told:
+        rows = re.findall(r"Row\((\w+=\d+)\)", pql)
+        assert len(rows) > len(set(rows)), pql
+    window = [json.loads(line) for line in err.splitlines()
+              if line.startswith('{"phase": "window"')][0]
+    assert window["writes"] > 0 and 0.02 < window["write_share"] < 0.09
+    assert window["wrapped_streams"] == 0
+    control = last["control"]
+    assert control["correct"] is False
+    assert control["stale_wrong_answers"] > 0
+    assert control["checks"]["writes_not_read_back"]["value"] > 0
+    assert control["checks"]["readback_uncached_gap"]["value"] == 1
+
+
+@pytest.mark.parametrize("fault, failing", [
+    ("answer", ["wrong_answers"]),
+    ("write", ["writes_not_read_back", "wrong_answers"]),
+])
+def test_a_fault_in_the_timed_path_fails_the_writes_mix(fault, failing,
+                                                        monkeypatch, capsys):
+    """The faulty server under the writes mix: an answer altered where
+    the API produces it; a Set acknowledged and not applied, so that the
+    window's later reads and the rows read back lack it."""
+    sys.path.insert(0, BENCH)
+    import run as bench_run
+
+    monkeypatch.setenv("BENCH_FAULT", fault)
+    args = argparse.Namespace(
+        workload="", config="rehearsal-4s", traffic="count-trees-writes",
+        seed=78, seconds=3.0, trace=0, control=False, keep_trace="")
+    result = bench_run.run(args, launcher=[FAULTY])
+    assert result["correct"] is False
+    for name in failing:
+        assert result["checks"][name]["value"] > 0, name
+    # each wrong answer is told with the writes around it
+    assert '"wrong_answer": ' in capsys.readouterr().err
+
+
+def test_the_mix_names_its_reference(tmp_path):
+    """A mix that names another module as its reference runs on it: a
+    copy of ``reference.py`` under another name, new files only."""
+    shutil.copytree(BENCH, tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__", ".tmp"))
+    for name in ("pilosa_tpu", "native"):
+        os.symlink(os.path.join(ROOT, name), tmp_path / name)
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copy(os.path.join(BENCH, "reference.py"),
+                tmp_path / "benchmark" / "reference_copy.py")
+    with open(os.path.join(BENCH, "traffic", "count-trees.json")) as f:
+        mix = json.load(f)
+    mix["reference"] = "reference_copy"
+    (tmp_path / "benchmark/traffic/copied-reference.json").write_text(
+        json.dumps(mix))
+    rc, last, err = run_cli(str(tmp_path), "--config", "rehearsal-4s",
+                            "--traffic", "copied-reference", "--seed", "9",
+                            "--seconds", "2", "--trace", "0")
+    assert rc == 0, err[-3000:]
+    assert last["correct"] is True, last["checks"]
+    assert '"reference": "reference_copy"' in err
+    mix["reference"] = "json"  # a module, but none of benchmark/'s
+    (tmp_path / "benchmark/traffic/copied-reference.json").write_text(
+        json.dumps(mix))
+    rc, last, err = run_cli(str(tmp_path), "--config", "rehearsal-4s",
+                            "--traffic", "copied-reference", "--seed", "9",
+                            "--seconds", "2", "--trace", "0")
+    assert rc != 0 and last is None and "no module of benchmark/" in err
 
 
 @pytest.mark.parametrize("fault, failing", [

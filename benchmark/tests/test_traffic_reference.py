@@ -51,9 +51,14 @@ def test_reader_understands_topn_groupby_and_set():
     assert ts[1].is_write() and all(0 <= r.values[0] < 4 * WIDTH
                                     for r in sets)
     assert reference.parse(sets[0].pql)[1] == "Set"
-    # A write inside the window has no reference yet: said, not guessed.
-    with pytest.raises(reference.PQLError, match="write inside"):
-        reference.Catalogue(ts, reqs)
+    # The catalogue takes the write: kept apart from the reads' groups,
+    # as the bit that a History judges it by.
+    cat = reference.Catalogue(ts, reqs)
+    assert list(cat.writes) == [(1, ())] and (1, ()) not in cat.groups
+    col, a = sets[0].values
+    assert cat.bit(sets[0]) == reference.Bit(col, "f", a)
+    assert cat.tree(sets[0])[1] == "Set"
+    assert reference.written_fields(ts) == ["f"]
 
 
 def test_open_loop_and_bad_placeholders_are_refused():
