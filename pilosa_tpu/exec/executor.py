@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import threading
 from collections import OrderedDict
-from concurrent.futures import Future
 from dataclasses import dataclass, field as dc_field, replace
 from typing import Any, Callable, Iterable
 
@@ -75,12 +74,6 @@ _BITMAP_CALLS = frozenset(
     {"Row", "Range", "Difference", "Intersect", "Union", "Xor", "Not", "Shift"})
 
 
-def _wrap_result(r):
-    """Default finisher for execute_async's dispatch paths: a resolved
-    scalar becomes the single-call results list."""
-    return [r]
-
-
 def _min_threshold(c: Call) -> int:
     """A TopN's per-shard count threshold; 0 means the default."""
     threshold, _ = c.uint_arg("threshold")
@@ -107,13 +100,8 @@ class ExecOptions:
 class Executor:
     """Reference executor (executor.go:72)."""
 
-    #: bounded sizes for the per-executor caches.
+    #: bounded size of the parse cache.
     PARSE_CACHE_SIZE = 512
-    #: prepared entries hold references to leaf stacks (device arrays),
-    #: so the bound stays small and stale entries are dropped eagerly —
-    #: HBM budgeting lives in the planner's stack cache, and a prepared
-    #: entry must never out-pin an eviction there for long.
-    PREPARED_CACHE_SIZE = 32
 
     def __init__(self, holder: Holder, cluster=None, node_id: str | None = None,
                  planner=None, stats=None, result_cache: bool = True):
@@ -149,6 +137,7 @@ class Executor:
         #: threads; every consumer clones before mutating
         #: (_translate_call clones; Options copies opt).
         self._parse_cache: "OrderedDict[str, Query]" = OrderedDict()
+        self._parse_lock = threading.Lock()
         #: plan-signature keyed result cache (pilosa_tpu.cache): entries
         #: stamp the (schema epoch, max shard epoch over the plan's
         #: shards, remote shard-epoch rows) they were computed under and
@@ -171,15 +160,6 @@ class Executor:
         #: index-dirty broadcasts; the cross-node half of cache stamps.
         from pilosa_tpu.cache import RemoteEpochTable
         self.remote_epochs = RemoteEpochTable()
-        self._prepared_lock = threading.Lock()
-        #: (index, query text) -> (instance_id, schema_epoch, data epoch,
-        #: shards, jitted fn, leaf device arrays, result-cache key): the
-        #: prepared-query dispatch path (execute_async). Unlike the
-        #: result cache this caches the PROGRAM, not the answer — the
-        #: device still runs every query; epochs gate staleness, and the
-        #: arrays are shared references into the planner's budgeted
-        #: stack cache (no extra HBM pinned).
-        self._prepared: "OrderedDict[tuple, tuple]" = OrderedDict()
 
     def _planner_for(self, c: Call, opt: "ExecOptions"):
         if self.planner is None:
@@ -289,209 +269,14 @@ class Executor:
         from pilosa_tpu.cache.signature import cache_key
         return cache_key(idx, query, shards, opt)
 
-    def _exec_stamp(self, idx: Index, shards: list[int]) -> tuple:
-        """Pre-dispatch freshness stamp for the prepared/async paths."""
-        return (idx.schema_epoch.value, idx.epoch.max_shard_epoch(shards),
-                self.remote_epochs.rows_for(idx.name, shards))
-
-    def execute_async(self, index_name: str, query: Query | str,
-                      shards: Iterable[int] | None = None,
-                      opt: ExecOptions | None = None,
-                      cache: bool = True) -> "Future[list[Any]]":
-        """Non-blocking submission; resolves to ``execute(...)``'s list.
-
-        Single plannable ``Count(...)`` queries on a standalone node
-        dispatch their device program immediately and resolve when their
-        TransferBatcher wave lands — so ONE submitting thread can keep
-        hundreds of queries in flight over the device link. Anything else
-        (writes, cluster fan-out, host-side calls) executes synchronously
-        before the future resolves, which keeps the API uniform.
-        """
-        fut: Future = Future()
-        opt = opt or ExecOptions()
-        if not opt.remote:
-            _fuse.reset_fused_steps()
-        raw = query if isinstance(query, str) else None
-        if shards is not None and not isinstance(shards, list):
-            shards = list(shards)  # one materialization; never consume
-            # a caller's iterator twice across validate + execute.
-        fast = None
-        if (self.cluster is None and self.planner is not None
-                and not opt.remote and raw is not None):
-            # Prepared-query fast path: a repeated (index, text) pair
-            # whose epochs stand still re-dispatches its cached device
-            # program directly — no parse, clone, translate, plan-key
-            # hash, or leaf fetch per query (the reference's per-query
-            # host cost lives in executor.go:2561-2608; here the whole
-            # prepared path is a dict hit plus the jax dispatch).
-            e = self._prepared.get((index_name, raw))
-            if e is not None:
-                idx = self.holder.index(index_name)
-                stale = (idx is None or e[0] != idx.instance_id
-                         or e[1] != idx.schema_epoch.value
-                         or e[2] != idx.epoch.value)
-                if stale:
-                    # Drop device-array references the moment an entry
-                    # goes stale (don't wait for LRU churn).
-                    with self._prepared_lock:
-                        if self._prepared.get((index_name, raw)) is e:
-                            del self._prepared[(index_name, raw)]
-                    e = None
-                if (e is not None
-                        and ((shards is None and e[8])
-                             or (shards is not None and shards == e[3]))):
-                    (_, _, epoch, pshards, fn, arrays, rkey, post, _,
-                     steps) = e
-                    with self._prepared_lock:
-                        if (index_name, raw) in self._prepared:
-                            self._prepared.move_to_end((index_name, raw))
-                    cacheable = cache and self.result_cache is not None
-                    if cacheable:
-                        # Stamp + tenant captured NOW: the store runs on
-                        # the batcher thread, which has neither this
-                        # request's contextvars nor pre-dispatch epochs.
-                        stamp = self._exec_stamp(idx, pshards)
-                        tenant = current_tenant()
-                        hit = self.result_cache.get(tenant, rkey, stamp)
-                        if hit is not None:
-                            fut.set_result(hit)
-                            return fut
-                    try:
-                        if cacheable:
-                            # Store via the batcher callback; closure
-                            # only on the cacheable path.
-                            def post(host, _k=rkey, _s=stamp,  # noqa: E731
-                                     _t=tenant, _p=post):
-                                results = _p(host)
-                                self.result_cache.put(_t, _k, _s, results)
-                                return results
-                        _fuse.add_fused_steps(steps)
-                        # Return the dispatch future DIRECTLY: a second
-                        # Future + callback chain costs more than the
-                        # whole remaining fast path on a slow host. The
-                        # coalescer is the launch choke point — repeated
-                        # prepared queries are exactly the same-plan
-                        # waves it batches.
-                        return self.planner.dispatch_count(fn, arrays,
-                                                           post)
-                    except Exception as exc:
-                        fut.set_exception(exc)
-                        return fut
-        if (self.cluster is None and self.planner is not None
-                and not opt.remote):
-            q = self._parse_cached(raw) if raw is not None else query
-            if (len(q.calls) == 1 and q.calls[0].name == "Count"
-                    and len(q.calls[0].children) == 1):
-                idx = self.holder.index(index_name)
-                if idx is not None and self.planner.supports(
-                        q.calls[0].children[0]):
-                    fast = (q, idx)
-            elif (len(q.calls) == 1
-                  and q.calls[0].name in ("Sum", "Min", "Max")):
-                # BSI aggregates dispatch async too: device program
-                # enqueued now, base fold applied when the batcher wave
-                # lands — same shape as the Count path below.
-                idx = self.holder.index(index_name)
-                if idx is not None and self.planner.supports_aggregate(
-                        idx, q.calls[0]):
-                    fast = (q, idx)
-        if fast is None:
-            try:
-                fut.set_result(self.execute(index_name, query, shards, opt,
-                                            cache=cache))
-            except Exception as e:
-                fut.set_exception(e)
-            return fut
-
-        q, idx = fast
-        try:
-            shards_obj = shards
-            shards = (sorted(idx.available_shards()) if shards is None
-                      else list(shards))
-            epoch = idx.epoch.value
-            key = self._cache_key(idx, q, shards, opt) \
-                if raw is not None else None
-            cacheable = (cache and self.result_cache is not None
-                         and raw is not None)
-            stamp = self._exec_stamp(idx, shards) if cacheable else None
-            tenant = current_tenant()
-            if cacheable:
-                hit = self.result_cache.get(tenant, key, stamp)
-                if hit is not None:
-                    fut.set_result(hit)
-                    return fut
-            call = self._translate_call(idx, q.calls[0])
-            finish = _wrap_result  # Count: resolve to [int]
-            if call.name in ("Sum", "Min", "Max"):
-                field_name, _ = call.string_arg("field")
-                base = idx.field(field_name).bsi_group.base
-                name = call.name
-
-                def finish(pair, _b=base, _n=name):  # noqa: F811
-                    total, cnt = pair
-                    if cnt == 0:
-                        return [ValCount()]
-                    if _n == "Sum":
-                        return [ValCount(total + cnt * _b, cnt)]
-                    return [ValCount(total + _b, cnt)]
-
-                if name == "Sum":
-                    inner = self.planner.dispatch_sum(idx, call, shards)
-                else:
-                    inner = self.planner.dispatch_min_max(
-                        idx, call, shards, name == "Min")
-            elif shards:
-                fn, arrays = self.planner.prepare_count(
-                    idx, call.children[0], shards)
-                steps = _fuse.call_steps(call.children[0]) + 1
-                if raw is not None:
-                    sum_host = self.planner._sum_host
-                    with self._prepared_lock:
-                        # `shards` is OUR copy — never the caller's
-                        # mutable list, which could change under an
-                        # identity check. Final flag: prepared from
-                        # shards=None (the full available set at this
-                        # epoch) — only such entries may serve later
-                        # shards=None callers; a subset program must
-                        # never answer a full query.
-                        self._prepared[(index_name, raw)] = (
-                            idx.instance_id, idx.schema_epoch.value,
-                            epoch, shards, fn, arrays, key,
-                            lambda host, _s=sum_host: [_s(host)],
-                            shards_obj is None, steps)
-                        while len(self._prepared) > self.PREPARED_CACHE_SIZE:
-                            self._prepared.popitem(last=False)
-                _fuse.add_fused_steps(steps)
-                inner = self.planner.dispatch_count(fn, arrays)
-            else:
-                inner = self.planner.execute_count_async(
-                    idx, call.children[0], shards)
-        except Exception as e:
-            fut.set_exception(e)
-            return fut
-
-        def _done(f):
-            try:
-                results = finish(f.result())
-            except Exception as e:
-                fut.set_exception(e)
-                return
-            if cacheable:
-                # stamp/tenant captured pre-dispatch (batcher thread).
-                self.result_cache.put(tenant, key, stamp, results)
-            fut.set_result(results)
-
-        inner.add_done_callback(_done)
-        return fut
-
     def _parse_cached(self, raw: str) -> Query:
-        with self._prepared_lock:
+        with self._parse_lock:
             q = self._parse_cache.get(raw)
             if q is not None:
                 self._parse_cache.move_to_end(raw)
                 return q
         q = parse(raw)
-        with self._prepared_lock:
+        with self._parse_lock:
             self._parse_cache[raw] = q
             while len(self._parse_cache) > self.PARSE_CACHE_SIZE:
                 self._parse_cache.popitem(last=False)
@@ -699,64 +484,85 @@ class Executor:
                     shards: list[int]) -> list[Row]:
         """Evaluate each replaced subtree host-side over ``shards`` —
         the same per-shard interpreter the full fallback would have run,
-        but only for the unplannable fraction of the tree."""
+        but only for the unplannable fraction of the tree. The subtrees
+        share one shard's reads: a row two of them name is read once."""
+        read: dict[int, dict] = {shard: {} for shard in shards}
         rows = []
         for cc in const_calls:
             segs: dict[int, Any] = {}
             for shard in shards:
-                r = self._bitmap_call_shard(idx, cc, shard)
+                r = self._bitmap_call_shard(idx, cc, shard, read[shard])
                 segs.update(r.segments)
             rows.append(Row(segs))
         return rows
 
-    def _bitmap_call_shard(self, idx: Index, c: Call, shard: int) -> Row:
-        """Reference executeBitmapCallShard (executor.go:659)."""
+    def _bitmap_call_shard(self, idx: Index, c: Call, shard: int,
+                           rows: dict | None = None) -> Row:
+        """Reference executeBitmapCallShard (executor.go:659). ``rows``
+        holds every fragment row this evaluation has read, by (field,
+        view, row id), and is made here when the caller starts one: a
+        row the call names twice is read once, so the call sees one
+        state of it, whatever a concurrent write does."""
+        if rows is None:
+            rows = {}
         name = c.name
         if name in ("Row", "Range"):
-            return self._row_shard(idx, c, shard)
+            return self._row_shard(idx, c, shard, rows)
         if name == "Difference":
-            return self._nary_shard(idx, c, shard, "difference")
+            return self._nary_shard(idx, c, shard, "difference", rows)
         if name == "Intersect":
-            return self._nary_shard(idx, c, shard, "intersect")
+            return self._nary_shard(idx, c, shard, "intersect", rows)
         if name == "Union":
-            return self._nary_shard(idx, c, shard, "union")
+            return self._nary_shard(idx, c, shard, "union", rows)
         if name == "Xor":
-            return self._nary_shard(idx, c, shard, "xor")
+            return self._nary_shard(idx, c, shard, "xor", rows)
         if name == "Not":
-            return self._not_shard(idx, c, shard)
+            return self._not_shard(idx, c, shard, rows)
         if name == "Shift":
-            return self._shift_shard(idx, c, shard)
+            return self._shift_shard(idx, c, shard, rows)
         raise QueryError(f"unknown call: {name}")
 
-    def _nary_shard(self, idx: Index, c: Call, shard: int, op: str) -> Row:
+    def _fragment_row(self, idx: Index, field_name: str, view: str,
+                      row_id: int, shard: int, rows: dict) -> Row:
+        """One row of one shard's fragment, read once an evaluation."""
+        key = (field_name, view, row_id)
+        row = rows.get(key)
+        if row is None:
+            frag = self.holder.fragment(idx.name, field_name, view, shard)
+            row = rows[key] = frag.row(row_id) if frag else Row()
+        return row
+
+    def _nary_shard(self, idx: Index, c: Call, shard: int, op: str,
+                    rows: dict) -> Row:
         if not c.children:
             raise QueryError(f"empty {c.name} query is currently not supported")
-        rows = [self._bitmap_call_shard(idx, ch, shard) for ch in c.children]
-        acc = rows[0]
-        for r in rows[1:]:
+        kids = [self._bitmap_call_shard(idx, ch, shard, rows)
+                for ch in c.children]
+        acc = kids[0]
+        for r in kids[1:]:
             acc = getattr(acc, op)(r)
         return acc
 
-    def _not_shard(self, idx: Index, c: Call, shard: int) -> Row:
+    def _not_shard(self, idx: Index, c: Call, shard: int, rows: dict) -> Row:
         if len(c.children) != 1:
             raise QueryError("Not() requires a single row input")
         if idx.existence_field() is None:
             raise QueryError(
                 f"index does not support existence tracking: {idx.name}")
-        frag = self.holder.fragment(idx.name, idx.existence_field().name,
-                                    VIEW_STANDARD, shard)
-        existence = frag.row(0) if frag else Row()
-        row = self._bitmap_call_shard(idx, c.children[0], shard)
+        existence = self._fragment_row(idx, idx.existence_field().name,
+                                       VIEW_STANDARD, 0, shard, rows)
+        row = self._bitmap_call_shard(idx, c.children[0], shard, rows)
         return existence.difference(row)
 
-    def _shift_shard(self, idx: Index, c: Call, shard: int) -> Row:
+    def _shift_shard(self, idx: Index, c: Call, shard: int,
+                     rows: dict) -> Row:
         n, _ = c.int_arg("n")
         if len(c.children) != 1:
             raise QueryError("Shift() requires a single row input")
-        row = self._bitmap_call_shard(idx, c.children[0], shard)
+        row = self._bitmap_call_shard(idx, c.children[0], shard, rows)
         return row.shift(n)
 
-    def _row_shard(self, idx: Index, c: Call, shard: int) -> Row:
+    def _row_shard(self, idx: Index, c: Call, shard: int, rows: dict) -> Row:
         """Reference executeRowShard (executor.go:1441)."""
         if c.has_condition_arg():
             return self._row_bsi_shard(idx, c, shard)
@@ -780,8 +586,8 @@ class Executor:
             to_time = tq.parse_time(c.args["to"])
 
         if c.name == "Row" and from_time is None and to_time is None:
-            frag = self.holder.fragment(idx.name, field_name, VIEW_STANDARD, shard)
-            return frag.row(row_id) if frag else Row()
+            return self._fragment_row(idx, field_name, VIEW_STANDARD, row_id,
+                                      shard, rows)
 
         q = f.time_quantum()
         if not q:
@@ -795,9 +601,8 @@ class Executor:
         out = Row()
         for view_name in tq.views_by_time_range(VIEW_STANDARD, from_time,
                                                 to_time, q):
-            frag = self.holder.fragment(idx.name, field_name, view_name, shard)
-            if frag is not None:
-                out = out.union(frag.row(row_id))
+            out = out.union(self._fragment_row(idx, field_name, view_name,
+                                               row_id, shard, rows))
         return out
 
     def _row_bsi_shard(self, idx: Index, c: Call, shard: int) -> Row:

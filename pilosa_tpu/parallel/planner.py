@@ -225,34 +225,21 @@ class MeshPlanner:
     def execute_count(self, idx: Index, c: Call, shards: list[int],
                       const_rows: list | None = None) -> int:
         """Count(tree) as one device program (per-shard popcounts,
-        summed on the host); the result transfer rides the shared
-        batcher wave."""
-        return self._wait(self.execute_count_async(
-            idx, c, shards, const_rows=const_rows))
-
-    def execute_count_async(self, idx: Index, c: Call, shards: list[int],
-                            const_rows: list | None = None):
-        """Dispatch Count(tree) and return a Future[int]. The device
-        program is enqueued immediately; the per-shard popcounts are
-        pulled through the TransferBatcher, so any number of concurrent
-        counts share one stacked device->host transfer per wave (see
-        parallel.batcher)."""
-        from concurrent.futures import Future
+        summed on the host); the per-shard popcounts are pulled through
+        the TransferBatcher, so concurrent counts share one stacked
+        device->host transfer a wave (see parallel.batcher)."""
         if not shards:
-            fut: Future = Future()
-            fut.set_result(0)
-            return fut
+            return 0
         fn, arrays = self.prepare_count(idx, c, shards,
                                         const_rows=const_rows)
         _fuse.add_fused_steps(_fuse.call_steps(c) + 1)
-        return self.dispatch_count(fn, arrays)
+        return self._wait(self.dispatch_count(fn, arrays))
 
     def prepare_count(self, idx: Index, c: Call, shards: list[int],
                       const_rows: list | None = None):
         """Resolve Count(tree) to its (jitted fn, leaf device arrays)
-        without dispatching — the executor's prepared-query fast path
-        caches the pair and re-dispatches with zero per-query planning
-        as long as the index epochs stand still."""
+        without dispatching: the plan through the prepared-plan cache,
+        the leaves fetched for this call (`_fetch_leaves`)."""
         # Const-leaf plans (partial fusion of a mixed tree) bypass the
         # text-keyed plan cache: their __const__ slots print identically
         # while holding per-query host rows. The structural _fn_cache
@@ -323,14 +310,19 @@ class MeshPlanner:
 
     def _fetch_leaves(self, idx: Index, leaves: list, shards: tuple,
                       const_rows: list | None = None) -> list:
-        """A plan's leaf arrays: residency as the request sees it (~0
-        when every stack is resident; waits and synchronous builds
-        otherwise)."""
+        """A call's leaf arrays, slot for slot: each distinct descriptor
+        is fetched once and its array handed to every slot that names
+        it, so a row the call names twice is one state of that row,
+        whatever a concurrent write does between two fetches. Residency
+        as the request sees it (~0 when every stack is resident; waits
+        and synchronous builds otherwise)."""
         with start_span("stack.fetch", stats=self.stats):
-            self._prefetch_leaves(idx, leaves, shards)
-            return [self._fetch_leaf(idx, leaf, shards,
-                                     const_rows=const_rows)
-                    for leaf in leaves]
+            distinct = dict.fromkeys(leaves)
+            self._prefetch_leaves(idx, distinct, shards)
+            for leaf in distinct:
+                distinct[leaf] = self._fetch_leaf(idx, leaf, shards,
+                                                  const_rows=const_rows)
+            return [distinct[leaf] for leaf in leaves]
 
     def _wait(self, fut):
         """Block the request's thread on a dispatch future: device run,
@@ -375,11 +367,11 @@ class MeshPlanner:
         # immune to int32 overflow past ~2k full shards.
         return int(host.astype(np.int64).sum())
 
-    def dispatch_count(self, fn, arrays, post=None):
+    def dispatch_count(self, fn, arrays):
         """Enqueue a prepared count's device program; Future[int].
         Routed through the coalescer so concurrent dispatches of the
         same plan signature share one launch."""
-        return self.coalescer.dispatch(fn, arrays, post or self._sum_host)
+        return self.coalescer.dispatch(fn, arrays, self._sum_host)
 
     # -- launch accounting / program registry --------------------------
 
@@ -450,21 +442,29 @@ class MeshPlanner:
                 self._vmap_cache[full_sig] = vfn
         return vfn
 
-    def _tree_stack(self, idx: Index, c: Call, shards: list[int],
-                    const_rows: list | None = None) -> jax.Array:
-        """Evaluate a bitmap tree to its stacked [S_pad, W] device array."""
+    def _tree_plan(self, idx: Index, c: Call,
+                   shards: ShardSet) -> tuple[list, Callable]:
+        """A bitmap tree's leaf descriptors and the program that
+        evaluates it to its stacked [S_pad, W] device array."""
         leaves: list[tuple] = []
-        shards = self._shards(shards)
         with start_span("plan.prepare", stats=self.stats):
             sig = self._signature(idx, c, leaves, shards)
-            fn = self._compiled(("row",) + sig, sig, len(leaves),
-                                reduce=None)
-        arrays = self._fetch_leaves(idx, leaves, shards,
-                                    const_rows=const_rows)
+            return leaves, self._compiled(("row",) + sig, sig, len(leaves),
+                                          reduce=None)
+
+    def _run_tree(self, c: Call, fn: Callable, arrays: list) -> jax.Array:
         out = fn(*arrays)
         self._record_dispatch(1)
         _fuse.add_fused_steps(_fuse.call_steps(c))
         return out
+
+    def _tree_stack(self, idx: Index, c: Call, shards: list[int],
+                    const_rows: list | None = None) -> jax.Array:
+        """Evaluate a bitmap tree to its stacked [S_pad, W] device array."""
+        shards = self._shards(shards)
+        leaves, fn = self._tree_plan(idx, c, shards)
+        return self._run_tree(c, fn, self._fetch_leaves(
+            idx, leaves, shards, const_rows=const_rows))
 
     def execute_bitmap(self, idx: Index, c: Call, shards: list[int],
                        const_rows: list | None = None) -> Row:
@@ -505,10 +505,14 @@ class MeshPlanner:
         f = idx.field(field_name)
         depth = f.bsi_group.bit_depth
         shards = self._shards(shards)
-        exists, sign, bits = self._fetch_leaf(
-            idx, ("bsi", field_name, depth), shards)
+        # the field's planes and the filter's leaves in one fetch: a
+        # filter on the field itself reads the same state of its planes
+        leaves, fn = (self._tree_plan(idx, c.children[0], shards)
+                      if c.children else ([], None))
+        (exists, sign, bits), *arrays = self._fetch_leaves(
+            idx, [("bsi", field_name, depth)] + leaves, shards)
         if c.children:
-            filt = self._tree_stack(idx, c.children[0], shards)
+            filt = self._run_tree(c.children[0], fn, arrays)
         else:
             filt = _jit_full_like(exists)
             self._record_dispatch(1)
@@ -572,12 +576,6 @@ class MeshPlanner:
         self._register_fn(fn, full_sig, program)
         return fn
 
-    def execute_sum(self, idx: Index, c: Call, shards: list[int]):
-        """Global (sum-of-base-offsets, count) in one device program; the
-        executor applies the BSI base (reference fragment.sum :1111 under
-        executeSum :406)."""
-        return self._wait(self.dispatch_sum(idx, c, shards))
-
     def _fuse_agg_ok(self, c: Call) -> bool:
         """Fused-aggregate gate. Unfiltered aggregates fuse everywhere:
         with the plane cube cached, one program is strictly cheaper than
@@ -596,17 +594,13 @@ class MeshPlanner:
             return True
         return jax.default_backend() != "cpu"
 
-    def dispatch_sum(self, idx: Index, c: Call, shards: list[int]):
-        """Async Sum: enqueue the device program and return a
-        Future[(total, count)]. The host fold runs on the batcher's
-        resolver thread when the transfer wave lands, so the calling
-        thread is free to plan/reduce other work — the executor syncs
-        only at result materialization."""
-        from concurrent.futures import Future
+    def execute_sum(self, idx: Index, c: Call, shards: list[int]):
+        """Global (sum-of-base-offsets, count) in one device program; the
+        executor applies the BSI base (reference fragment.sum :1111 under
+        executeSum :406). The host fold runs on the batcher's resolver
+        thread when the transfer wave lands."""
         if not shards:
-            fut: Future = Future()
-            fut.set_result((0, 0))
-            return fut
+            return (0, 0)
         if self._fuse_agg_ok(c):
             # Fused: filter tree + plane stack + sum kernel trace into
             # ONE jitted program; the host fold rides the coalescer's
@@ -624,7 +618,8 @@ class MeshPlanner:
                             for i in range(depth))
                 return total, count
 
-            return self.coalescer.dispatch(fn, arrays, fold_fused)
+            return self._wait(self.coalescer.dispatch(fn, arrays,
+                                                      fold_fused))
         _, exists, sign, stack, filt, depth = self._bsi_inputs(idx, c, shards)
         cnt, pos, neg = self._replicate_small(
             *bsi_ops.sum_counts(exists, sign, stack, filt, depth))
@@ -642,25 +637,16 @@ class MeshPlanner:
                         for i in range(depth))
             return total, count
 
-        return self.batcher.submit(cnt, fold)
+        return self._wait(self.batcher.submit(cnt, fold))
 
     def execute_min_max(self, idx: Index, c: Call, shards: list[int],
                         is_min: bool):
         """Global (value, count) pre-base: every shard's extremum computed
         in one stacked program (the shape-polymorphic bit-serial descent of
-        ops.bsi), host-folded with the reference's smaller/larger rule."""
-        return self._wait(self.dispatch_min_max(idx, c, shards, is_min))
-
-    def dispatch_min_max(self, idx: Index, c: Call, shards: list[int],
-                         is_min: bool):
-        """Async Min/Max: Future[(value, count)] pre-base; like
-        dispatch_sum, the per-shard fold rides the batcher's resolver
-        thread instead of blocking the dispatching thread."""
-        from concurrent.futures import Future
+        ops.bsi), host-folded with the reference's smaller/larger rule on
+        the batcher's resolver thread."""
         if not shards:
-            fut: Future = Future()
-            fut.set_result((0, 0))
-            return fut
+            return (0, 0)
         shards = self._shards(shards)
         n_shards = len(shards)
         if self._fuse_agg_ok(c):
@@ -673,7 +659,8 @@ class MeshPlanner:
                 return _fold_min_max(np.asarray(cc), np.asarray(ac),
                                      av, bv, n_shards, is_min)
 
-            return self.coalescer.dispatch(fn, arrays, fold_fused)
+            return self._wait(self.coalescer.dispatch(fn, arrays,
+                                                      fold_fused))
         _, exists, sign, stack, filt, depth = self._bsi_inputs(idx, c, shards)
         cons_cnt, alt_cnt, a, b = _agg_min_max(exists, sign, stack, filt,
                                                depth, is_min)
@@ -689,7 +676,7 @@ class MeshPlanner:
             return _fold_min_max(cons_host, np.asarray(alt_cnt), a, b,
                                  n_shards, is_min)
 
-        return self.batcher.submit(cons_cnt, fold)
+        return self._wait(self.batcher.submit(cons_cnt, fold))
 
     # ------------------------------------------------------------------
     # approximate analytics (pilosa_tpu.sketch): Count(Distinct) as ONE
@@ -1038,15 +1025,16 @@ class MeshPlanner:
 
             leaves, fn = self._plan_cached(
                 idx, f"topn{r_pad}:{filter_call}", shards, build)
-            arrays = self._fetch_leaves(idx, leaves, shards)
         with start_span("topn.sweep", stats=self.stats):
-            rows = self._fetch_leaves(
-                idx, [("row", field_name, view, rid)
-                      for rid in cands.tolist()], shards)
+            # one fetch of the filter's leaves and the candidate rows: a
+            # row that is both is one state of it
+            arrays = self._fetch_leaves(
+                idx, leaves + [("row", field_name, view, rid)
+                               for rid in cands.tolist()], shards)
             # the padding slots count the last row again (no stack is
             # made or moved for them); their counts are cut off below
-            rows += rows[-1:] * (r_pad - r)
-            fut = self.coalescer.dispatch(fn, arrays + rows, np.asarray)
+            arrays += arrays[-1:] * (r_pad - r)
+            fut = self.coalescer.dispatch(fn, arrays, np.asarray)
         _fuse.add_fused_steps(_fuse.call_steps(filter_call) + 1)
         self._count_topn_pass(True, 1, r * len(shards), 0)
         counts = self._wait(fut)[:r, :len(shards)].T.astype(np.int64)

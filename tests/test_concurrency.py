@@ -10,10 +10,13 @@ hammer exactly that interleaving.
 import json
 import threading
 import urllib.request
+from concurrent.futures import ThreadPoolExecutor
 
+import jax
 import numpy as np
 import pytest
 
+from pilosa_tpu.config import SHARD_WIDTH
 from pilosa_tpu.core import Holder
 from pilosa_tpu.exec import Executor
 from pilosa_tpu.parallel import MeshPlanner, make_mesh
@@ -136,6 +139,9 @@ def test_result_cache_write_queries_not_cached():
 
 
 def test_execute_async_matches_sync():
+    """Threads calling execute get what serial calls get: concurrent
+    Counts of one plan share launches through the coalescer, and a
+    query the planner does not answer whole runs beside them."""
     h = Holder()
     idx = h.create_index("i")
     f = idx.create_field("f")
@@ -144,14 +150,14 @@ def test_execute_async_matches_sync():
                   np.arange(50, dtype=np.uint64) * 3)
     g.import_bits(np.full(80, 2, dtype=np.uint64),
                   np.arange(80, dtype=np.uint64) * 2)
-    ex = Executor(h, planner=MeshPlanner(h, make_mesh()))
+    ex = Executor(h, planner=MeshPlanner(h, make_mesh(jax.devices()[:1])))
     q = "Count(Intersect(Row(f=1), Row(g=2)))"
-    want = ex.execute("i", q)
-    futs = [ex.execute_async("i", q, cache=False) for _ in range(40)]
-    assert all(fut.result() == want for fut in futs)
-    # Non-fast-path query still resolves through the future.
-    fut = ex.execute_async("i", "TopN(f, n=2)")
-    assert fut.result() == ex.execute("i", "TopN(f, n=2)")
+    topn = "TopN(f, n=2)"
+    want = {q: ex.execute("i", q), topn: ex.execute("i", topn)}
+    with ThreadPoolExecutor(8) as pool:
+        got = list(pool.map(lambda t: (t, ex.execute("i", t, cache=False)),
+                            [q] * 40 + [topn] * 4))
+    assert all(res == want[t] for t, res in got)
 
 
 def test_batcher_mixed_shapes():
@@ -279,37 +285,66 @@ def test_cluster_coordinator_cache_invalidated_by_owner_write():
     assert lc.query("i", "Count(Row(f=1))") == [2]  # no stale cache
 
 
-def test_plan_cache_invalidated_by_write():
-    """Prepared plans (fn + leaf arrays) must die on writes: the leaf
-    arrays embed data, so serving them past a mutation would be a stale
-    read even though the device re-executes."""
+_SW = SHARD_WIDTH
+_FIVE = [0, 1, _SW, _SW + 1, 2 * _SW]
+
+
+@pytest.mark.parametrize("bits,query,steps", [
+    ({"f": {1: [0, 5]}, "g": {2: [0, 9]}},
+     "Count(Intersect(Row(f=1), Row(g=2)))",
+     [(None, 1), (None, 1), "Set(5, g=2)", (None, 2)]),
+    ({"f": {1: [1]}}, "Count(Row(f=1))",
+     [(None, 1), (None, 1), "Set(2, f=1)", (None, 2)]),
+    ({"f": {1: _FIVE}, "g": {2: _FIVE}},
+     "Count(Intersect(Row(f=1), Row(g=2)))",
+     [(None, 5), (None, 5), f"Set({3 * _SW}, f=1)", f"Set({3 * _SW}, g=2)",
+      (None, 6), ([0], 2)]),
+    ({"f": {1: [0, _SW, 2 * _SW]}}, "Count(Row(f=1))",
+     [([0], 1), (None, 3), (None, 3)]),
+], ids=["intersect", "recount", "new_shard", "shard_subset_then_full"])
+def test_plan_cache_invalidated_by_write(bits, query, steps):
+    """Prepared plans (program + leaf descriptors) must not answer past
+    a write, nor for another shard set: the leaves are fetched anew
+    every call, and the plan cache is keyed by the shards. A step is a
+    write, or (shards, count) with None for the index's own shards."""
     h = Holder()
     idx = h.create_index("i")
-    f = idx.create_field("f")
-    g = idx.create_field("g")
-    f.import_bits([1, 1], [0, 5])
-    g.import_bits([2, 2], [0, 9])
-    planner = MeshPlanner(h, make_mesh())
-    ex = Executor(h, planner=planner)
-    q = "Count(Intersect(Row(f=1), Row(g=2)))"
-    assert ex.execute("i", q, cache=False) == [1]
-    assert ex.execute("i", q, cache=False) == [1]   # plan-cache hit
-    g.set_bit(2, 5)
-    assert ex.execute("i", q, cache=False) == [2]   # plan rebuilt
+    for name, rows in bits.items():
+        fld = idx.create_field(name)
+        for row, cols in rows.items():
+            fld.import_bits([row] * len(cols), cols)
+    ex = Executor(h, planner=MeshPlanner(h, make_mesh()))
+    for step in steps:
+        if isinstance(step, str):
+            ex.execute("i", step)
+            continue
+        shards, want = step
+        assert ex.execute("i", query, shards=shards, cache=False) == [want]
 
 
-def test_plan_cache_invalidated_by_schema_change():
-    """Prepared plans bake BSI structure (bit depth, base folds): field
-    recreate AND in-place bit-depth growth must both miss the cache."""
+@pytest.mark.parametrize("change", ["bsi_depth", "index_recreate"])
+def test_plan_cache_invalidated_by_schema_change(change):
+    """Prepared plans bake BSI structure (bit depth, base folds) and the
+    index's identity: a field recreated, its bit depth grown in place,
+    or the index deleted and made again must all miss the cache."""
     from pilosa_tpu.core import FieldOptions
     from pilosa_tpu.core.field import FIELD_TYPE_INT
     h = Holder()
     idx = h.create_index("i")
+    planner = MeshPlanner(h, make_mesh())
+    ex = Executor(h, planner=planner)
+    if change == "index_recreate":
+        idx.create_field("f").import_bits([1, 1], [0, _SW])
+        q = "Count(Row(f=1))"
+        assert ex.execute("i", q, cache=False) == [2]
+        assert ex.execute("i", q, cache=False) == [2]
+        h.delete_index("i")
+        h.create_index("i").create_field("f")
+        assert ex.execute("i", q, cache=False) == [0]
+        return
     opts = FieldOptions(type=FIELD_TYPE_INT, min=0, max=7)
     v = idx.create_field("v", opts)
     v.import_values([1, 2], [5, 6])
-    planner = MeshPlanner(h, make_mesh())
-    ex = Executor(h, planner=planner)
     q = "Count(Row(v > 4))"
     assert ex.execute("i", q, cache=False) == [2]
     # Recreate with a much wider range (deeper BSI).
@@ -534,9 +569,8 @@ def test_mixed_workload_soak(rng):
         last = 0
         while not stop.is_set():
             try:
-                futs = [ex.execute_async("soak", "Count(Row(f=1))",
-                                         cache=False) for _ in range(8)]
-                vals = [f.result()[0] for f in futs]
+                vals = [ex.execute("soak", "Count(Row(f=1))",
+                                   cache=False)[0] for _ in range(8)]
                 ex.execute("soak", "TopN(f, n=3)")
                 ex.execute("soak", "Count(Row(f=1))")  # cached path
             except Exception as e:

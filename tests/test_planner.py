@@ -592,80 +592,6 @@ def test_planner_topn_program_keeps_its_operands_on_the_mesh(
         [(1, len(cols[::2])), (2, len(cols[::2]))]
 
 
-def test_prepared_count_fast_path_invalidation(mesh):
-    """execute_async's prepared-query cache must never serve stale
-    programs: a write (data epoch), a schema change, and a different
-    shards list each force a correct re-plan."""
-    h = Holder()
-    idx = h.create_index("prep")
-    f = idx.create_field("f")
-    g = idx.create_field("g")
-    cols = [0, 1, SHARD_WIDTH, SHARD_WIDTH + 1, 2 * SHARD_WIDTH]
-    for c in cols:
-        f.import_bits([1], [c])
-        g.import_bits([2], [c])
-    ex = Executor(h, planner=MeshPlanner(h, mesh))
-    q = "Count(Intersect(Row(f=1), Row(g=2)))"
-
-    assert ex.execute_async("prep", q, cache=False).result() == [5]
-    # Second call rides the prepared entry.
-    assert ("prep", q) in ex._prepared
-    assert ex.execute_async("prep", q, cache=False).result() == [5]
-
-    # Data write: epoch bump -> re-plan, new bit visible.
-    ex.execute("prep", f"Set({3 * SHARD_WIDTH}, f=1)")
-    ex.execute("prep", f"Set({3 * SHARD_WIDTH}, g=2)")
-    assert ex.execute_async("prep", q, cache=False).result() == [6]
-
-    # Explicit shards subset: prepared full-range entry must not serve.
-    assert ex.execute_async("prep", q, shards=[0],
-                            cache=False).result() == [2]
-
-    # Schema change: delete/recreate the index -> instance_id differs.
-    h.delete_index("prep")
-    idx = h.create_index("prep")
-    idx.create_field("f")
-    idx.create_field("g")
-    assert ex.execute_async("prep", q, cache=False).result() == [0]
-
-
-def test_prepared_entry_dropped_when_stale(mesh):
-    """Stale prepared entries release their device-array references
-    immediately (HBM pinning guard)."""
-    h = Holder()
-    idx = h.create_index("prep2")
-    idx.create_field("f")
-    ex = Executor(h, planner=MeshPlanner(h, mesh))
-    ex.execute("prep2", "Set(1, f=1)")
-    q = "Count(Row(f=1))"
-    assert ex.execute_async("prep2", q, cache=False).result() == [1]
-    assert ("prep2", q) in ex._prepared
-    ex.execute("prep2", "Set(2, f=1)")  # bump epoch
-    # Next async call sees the stale entry, drops it, re-plans.
-    assert ex.execute_async("prep2", q, cache=False).result() == [2]
-    e = ex._prepared.get(("prep2", q))
-    assert e is not None and e[2] == idx.epoch.value
-
-
-def test_prepared_subset_never_serves_full_query(mesh):
-    """A prepared entry built for an explicit shards subset must NOT
-    answer a later shards=None (full index) query."""
-    h = Holder()
-    idx = h.create_index("prep3")
-    idx.create_field("f")
-    ex = Executor(h, planner=MeshPlanner(h, mesh))
-    for c in (0, SHARD_WIDTH, 2 * SHARD_WIDTH):
-        ex.execute("prep3", f"Set({c}, f=1)")
-    q = "Count(Row(f=1))"
-    # Prime the prepared cache with a SUBSET program.
-    assert ex.execute_async("prep3", q, shards=[0],
-                            cache=False).result() == [1]
-    # Full query must re-plan, not ride the subset entry.
-    assert ex.execute_async("prep3", q, cache=False).result() == [3]
-    # And a full-prepared entry keeps serving full queries.
-    assert ex.execute_async("prep3", q, cache=False).result() == [3]
-
-
 def test_shift_full_range_device_vs_oracle(mesh):
     """VERDICT r4 #8: Shift supports ANY 0 <= n <= SHARD_WIDTH on
     device. Property-check the planner path against a positions oracle
